@@ -196,6 +196,10 @@ def main():
     # sentinel widens its noise band to the recorded spread
     repeats = int(os.environ.get("BENCH_REPEATS", 1))
     spread_out = {}
+    # phases after HIGGS keep going past a failure so one crash cannot
+    # lose the rest of the round, but the failure is recorded in the last
+    # line and the process exits non-zero
+    failed = []
 
     X, y = make_higgs_like(n_rows)
     t_bin0 = time.time()
@@ -286,6 +290,7 @@ def main():
             _copy_spread(spread_out, ltr_spread, value="ranking_value",
                          vs_baseline="ranking_vs_baseline")
         except Exception as exc:
+            failed.append("MS-LTR")
             print("# MS-LTR phase failed: %r" % exc, file=sys.stderr)
     if ltr is not None:
         result["ranking_value"] = ltr["value"]
@@ -313,6 +318,7 @@ def main():
                          level_value="expo_level_value",
                          level_vs_baseline="expo_level_vs_baseline")
         except Exception as exc:
+            failed.append("expo")
             print("# expo phase failed: %r" % exc, file=sys.stderr)
     if expo is not None:
         result["expo_value"] = expo["value"]
@@ -365,6 +371,7 @@ def main():
                          value="allstate_value",
                          vs_baseline="allstate_vs_baseline")
         except Exception as exc:
+            failed.append("allstate")
             print("# allstate phase failed: %r" % exc, file=sys.stderr)
     if allst is not None:
         result["allstate_value"] = allst["value"]
@@ -392,6 +399,7 @@ def main():
             _copy_spread(spread_out, yah_spread, value="yahoo_value",
                          vs_baseline="yahoo_vs_baseline")
         except Exception as exc:
+            failed.append("yahoo")
             print("# yahoo phase failed: %r" % exc, file=sys.stderr)
     if yah is not None:
         result["yahoo_value"] = yah["value"]
@@ -415,6 +423,7 @@ def main():
             _copy_spread(spread_out, vote_spread, value="voting_value",
                          vs_baseline="voting_vs_baseline")
         except Exception as exc:
+            failed.append("voting")
             print("# voting phase failed: %r" % exc, file=sys.stderr)
     if vote is not None:
         result["voting_value"] = vote["value"]
@@ -446,6 +455,7 @@ def main():
                          overhead_frac="checkpoint_overhead_frac",
                          write_s="checkpoint_write_s")
         except Exception as exc:
+            failed.append("checkpoint")
             print("# checkpoint phase failed: %r" % exc, file=sys.stderr)
     if ckpt is not None:
         result["checkpoint_overhead_frac"] = ckpt["overhead_frac"]
@@ -488,6 +498,7 @@ def main():
                 "poisson.p99": "predict_p99",
                 "poisson.qdepth_mean": "predict_qdepth"})
         except Exception as exc:
+            failed.append("predict")
             print("# predict phase failed: %r" % exc, file=sys.stderr)
     if pred is not None:
         result["predict_value"] = pred["higgs"]["value"]
@@ -532,6 +543,7 @@ def main():
                          vs_sync="serving_vs_sync",
                          deadline_miss_frac="serving_deadline_miss_frac")
         except Exception as exc:
+            failed.append("serving")
             print("# serving phase failed: %r" % exc, file=sys.stderr)
     if serv is not None:
         result["serving_rps"] = serv["rps"]
@@ -564,6 +576,7 @@ def main():
             _copy_spread(spread_out, swp_spread,
                          models_per_sec="models_per_sec")
         except Exception as exc:
+            failed.append("sweep")
             print("# sweep phase failed: %r" % exc, file=sys.stderr)
     if swp is not None:
         result["models_per_sec"] = swp["models_per_sec"]
@@ -583,6 +596,8 @@ def main():
     # instead of bare numbers; the perf sentinel keys its lineages and
     # noise bands off this block
     result["meta"] = build_meta(repeats=repeats, spread=spread_out)
+    if failed:
+        result["failed_phases"] = failed
     print(json.dumps(result), flush=True)
     # full per-phase telemetry snapshot (category totals + per-scope table)
     # so BENCH_*.json rounds can archive WHERE the time went
@@ -596,6 +611,10 @@ def main():
         except OSError as exc:
             print("# could not write %s: %r" % (phases_out, exc),
                   file=sys.stderr)
+    if failed:
+        print("# failed phases: %s" % ", ".join(failed), file=sys.stderr)
+        return 1
+    return 0
 
 
 # MS-LTR anchor: 2.27M rows x 137 features, lambdarank, 500 iters in
@@ -1201,4 +1220,4 @@ def run_voting():
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

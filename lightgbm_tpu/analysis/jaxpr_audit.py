@@ -24,8 +24,7 @@ device execution, runs fine on CPU) and asserts invariants on the IR:
 * **the serve ladder bound holds analytically** — every batch size in
   [1, max_batch] maps into at most ceil(log2(max/min)) + 1 buckets.
 
-Each audit returns an :class:`AuditResult`; audits that need pallas
-report ``skipped`` on builds without it instead of failing the gate.
+Each audit returns an :class:`AuditResult`.
 
 The traversal layer lives in :mod:`dataflow` since PR 13: one shared
 walk covers every sub-jaxpr carrier (``pjit``, ``scan``, ``while``,
@@ -133,10 +132,6 @@ def _audit_jaxpr(name: str, closed, forbid_f64: bool = True,
                        detail="; ".join(problems))
 
 
-def _skip(name: str, why: str) -> AuditResult:
-    return AuditResult(name=name, ok=True, detail=why, skipped=True)
-
-
 # ---------------------------------------------------------------------------
 # individual audits
 # ---------------------------------------------------------------------------
@@ -144,10 +139,7 @@ def _skip(name: str, why: str) -> AuditResult:
 def audit_hist_window() -> AuditResult:
     """Both histogram kernel variants (radix W=256, one-hot W<=64) trace
     f64-free with f32 gradients."""
-    from ..ops.pallas_compat import HAS_PALLAS
     name = "hist_window_f32"
-    if not HAS_PALLAS:
-        return _skip(name, "pallas unavailable")
     from ..ops.pallas_histogram import hist_window
     problems = []
     for w, G, C in ((256, 3, 1024), (64, 5, 512)):
@@ -164,10 +156,7 @@ def audit_hist_window() -> AuditResult:
 
 
 def audit_scan_pair() -> AuditResult:
-    from ..ops.pallas_compat import HAS_PALLAS
     name = "scan_pair_f32"
-    if not HAS_PALLAS:
-        return _skip(name, "pallas unavailable")
     from ..ops.pallas_scan import scan_pair
     Fp, Wp = 8, 128
     f32 = jnp.float32
@@ -184,10 +173,7 @@ def audit_scan_pair() -> AuditResult:
 
 
 def audit_scan_blocks() -> AuditResult:
-    from ..ops.pallas_compat import HAS_PALLAS
     name = "scan_blocks_f32"
-    if not HAS_PALLAS:
-        return _skip(name, "pallas unavailable")
     from ..ops.pallas_scan import BM_ROWS, scan_blocks
     Gp, Wp = 8, 128
     f32 = jnp.float32
@@ -204,10 +190,7 @@ def audit_persist_split_pass() -> AuditResult:
     """The Mosaic split_pass on a toy payload geometry: f64-free, and
     the payload must be donated (input_output_aliases) — the in-place
     partition contract."""
-    from ..ops.pallas_compat import HAS_PALLAS
     name = "persist_split_pass"
-    if not HAS_PALLAS:
-        return _skip(name, "pallas unavailable")
     from ..ops.pallas_grow import make_split_pass
     WPA, NP, G, nbw = 8, 1024, 2, 2
     plan = ((0, 0, 255), (1, 0, 255))
@@ -239,10 +222,7 @@ def audit_persist_level_pass() -> AuditResult:
     :func:`audit_persist_split_pass` — the level path batches S leaves
     per launch, so a silent widening or alias loss costs S× more than
     on the per-split path."""
-    from ..ops.pallas_compat import HAS_PALLAS
     name = "persist_level_pass"
-    if not HAS_PALLAS:
-        return _skip(name, "pallas unavailable")
     from ..ops.pallas_grow import make_level_pass, make_level_seg_hist
     from ..ops.pallas_scan import scan_pair
     WPA, NP, G, nbw = 8, 1024, 2, 2

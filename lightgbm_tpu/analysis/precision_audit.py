@@ -37,7 +37,7 @@ import jax.numpy as jnp
 from ..telemetry import events as telemetry
 from . import dataflow
 from .config import GraftlintConfig
-from .jaxpr_audit import AuditResult, _skip, _toy_ensemble
+from .jaxpr_audit import AuditResult, _toy_ensemble
 
 C_NARROW = "analysis::narrowing_sites"
 
@@ -231,11 +231,9 @@ def _violations(name: str, closed, ranges, blessed
 
 
 def _programs(include_seeded: bool) -> List[Tuple]:
-    from ..ops.pallas_compat import HAS_PALLAS
     progs: List[Tuple] = []
-    if HAS_PALLAS:
-        progs += _memo("hist_prologue", _hist_prologue)
-        progs += _memo("scan_pair", _scan_pair_program)
+    progs += _memo("hist_prologue", _hist_prologue)
+    progs += _memo("scan_pair", _scan_pair_program)
     progs += _memo("predict", _predict_program)
     progs += _memo("fused_grads", _fused_grad_programs)
     if include_seeded:
@@ -248,7 +246,6 @@ def compute_artifact(config: Optional[GraftlintConfig] = None) -> dict:
     the --json payload builder."""
     include_seeded = os.environ.get(SEED_TIE_FLIP_ENV, "") \
         not in ("", "0")
-    from ..ops.pallas_compat import HAS_PALLAS
     violations: List[str] = []
     n_sites = 0
     names = []
@@ -258,7 +255,7 @@ def compute_artifact(config: Optional[GraftlintConfig] = None) -> dict:
         n_sites += n
         names.append(name)
     return {"programs": names, "violations": violations,
-            "narrowing_sites": n_sites, "pallas": HAS_PALLAS,
+            "narrowing_sites": n_sites,
             "seeded": include_seeded}
 
 
@@ -271,8 +268,6 @@ def run(config: Optional[GraftlintConfig] = None,
     except Exception as e:      # pragma: no cover - defensive
         return [AuditResult(name=name, ok=False,
                             detail="auditor raised: %r" % e)]
-    if not art["programs"]:
-        return [_skip(name, "pallas unavailable")]
     telemetry.count(C_NARROW, art["narrowing_sites"],
                     category="analysis")
     ok_detail = ("%d narrowing site(s) across %d program(s), all "

@@ -269,15 +269,7 @@ def _resolve_profile(config: Optional[GraftlintConfig]) -> DeviceProfile:
     name = getattr(config, "audit_device", "v5e")
     if name != "auto":
         return get_profile(name)
-    profile = detect_profile()
-    if profile.name == "cpu":
-        # detect_profile's "cpu" entry exists for honest bench-round
-        # meta/roofline on accelerator-less boxes; budgeting the Pallas
-        # kernel fleet against a 16MB host envelope is meaningless —
-        # "auto" on CPU keeps auditing against the TPU tuning target,
-        # the pre-"cpu"-profile contract
-        return get_profile("v5e")
-    return profile
+    return detect_profile()
 
 
 def estimate_all(profile: Optional[DeviceProfile] = None,

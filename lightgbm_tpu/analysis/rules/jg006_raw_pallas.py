@@ -1,14 +1,9 @@
-"""JG006 — pallas imported around the compat shim.
+"""JG006 — pallas imported around its single import point.
 
-``ops/pallas_compat.py`` is the single import point for the Pallas TPU
-API: it papers over the ``TPUCompilerParams``/``CompilerParams`` rename,
-provides the ``enable_x64`` shim, and — critically — degrades to
-``HAS_PALLAS = False`` so every caller takes its guarded XLA fallback on
-builds where pallas cannot construct kernels. A module that imports
-``jax.experimental.pallas`` directly bypasses all three: it crashes on
-0.4.x/exotic builds instead of falling back, and silently skips the
-version shims. Only the modules listed in ``pallas_compat_allow``
-(the shim itself) may touch the raw import.
+``ops/pallas_compat.py`` re-exports the Pallas TPU API (``pl``,
+``pltpu``, ``CompilerParams``, ``enable_x64``) so that a move in that
+still-experimental API is followed in one file. Only the modules listed
+in ``pallas_compat_allow`` (that file itself) may touch the raw import.
 """
 from __future__ import annotations
 
@@ -26,7 +21,7 @@ class RawPallasImport:
     id = "JG006"
     name = "raw-pallas-import"
     description = ("direct jax.experimental.pallas import bypasses "
-                   "ops/pallas_compat.py (version shims + XLA fallback)")
+                   "ops/pallas_compat.py (the single import point)")
 
     def check(self, ctx: ModuleContext) -> List[Finding]:
         allowed = {p.replace("\\", "/")
@@ -48,5 +43,5 @@ class RawPallasImport:
                 out.append(ctx.finding(
                     self.id, node,
                     "import pallas via ops/pallas_compat.py (pl, pltpu, "
-                    "TPUCompilerParams, HAS_PALLAS), not directly"))
+                    "CompilerParams, enable_x64), not directly"))
         return out

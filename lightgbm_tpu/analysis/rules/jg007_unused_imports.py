@@ -15,7 +15,7 @@ is deliberately conservative so its autofix is safe to run blind:
   edits the file again — the idempotency bug the round-trip test pins;
 * skipped entirely: ``__init__.py`` (re-export surface), ``__future__``
   imports, star imports, ``# noqa`` lines, imports inside ``try:``
-  blocks (version/feature probing idiom, e.g. pallas_compat), and
+  blocks (version/feature probing idiom), and
   imports sharing a source line with anything else (``import os; x=1``,
   trailing comments) — the counting and the fix are both line-grained.
 

@@ -48,10 +48,6 @@ REPLICATED_BYTES = 1 << 20
 # ---------------------------------------------------------------------------
 
 def _persist_programs() -> List[Tuple[str, object]]:
-    from ..ops.pallas_compat import HAS_PALLAS
-    if not HAS_PALLAS:
-        return []
-
     def build():
         from ..ops.pallas_grow import make_level_pass, make_split_pass
         WPA, NP, G, nbw = 8, 1024, 2, 2
@@ -79,13 +75,10 @@ def _persist_programs() -> List[Tuple[str, object]]:
 def _shared_programs() -> List[Tuple[str, object]]:
     """scan_pair + predict, traced ONCE per process and shared with
     the precision-flow auditor (same memo — see precision_audit)."""
-    from ..ops.pallas_compat import HAS_PALLAS
-    progs = []
-    if HAS_PALLAS:
-        progs += precision_audit._memo(
-            "scan_pair", precision_audit._scan_pair_program)
-    progs += precision_audit._memo(
-        "predict", precision_audit._predict_program)
+    progs = (precision_audit._memo(
+        "scan_pair", precision_audit._scan_pair_program)
+        + precision_audit._memo(
+            "predict", precision_audit._predict_program))
     return [(name, closed) for name, closed, _rng, _bless in progs]
 
 

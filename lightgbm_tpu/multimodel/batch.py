@@ -43,8 +43,8 @@ from ..utils.log import Log
 from . import driver
 
 # mirrors GBDT._batch_size: one fused 16-iteration program plus a k=1
-# tail program, and the guard that keeps a single batch under the
-# remote worker's watchdog at very large row*feature products
+# tail program, and the same size guard on one batch at very large
+# row*feature products
 MM_BATCH_K = 16
 MM_SIZE_GUARD = 150_000_000
 

@@ -47,30 +47,6 @@ import jax.numpy as jnp
 
 from ..telemetry import events as telemetry
 
-
-def _ensure_batching_rules() -> None:
-    """jax 0.4.x ships no vmap rule for ``optimization_barrier`` (the
-    grower uses it to pin the leaf-value compute order). The barrier is
-    semantically the identity, so the rule is exact: bind the batched
-    operands and pass the batch dims through — the same rule newer jax
-    versions ship built in. Registered once, idempotent."""
-    try:
-        from jax._src.lax.lax import optimization_barrier_p
-        from jax.interpreters import batching
-    except ImportError:       # pragma: no cover - jax layout changed
-        return
-    if optimization_barrier_p in batching.primitive_batchers:
-        return
-
-    def _rule(batched_args, batch_dims, **params):
-        return (optimization_barrier_p.bind(*batched_args, **params),
-                batch_dims)
-
-    batching.primitive_batchers[optimization_barrier_p] = _rule
-
-
-_ensure_batching_rules()
-
 # bucket ladder for the model-batch axis: B pads up to the next power of
 # two so distinct sweep widths reuse programs. Sweeps wider than
 # MM_MAX_BUCKET train in chunks of MM_MAX_BUCKET (multimodel/batch.py),

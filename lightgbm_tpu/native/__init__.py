@@ -14,9 +14,12 @@ import subprocess
 import tempfile
 from typing import Optional
 
-_CACHE = os.environ.get(
-    "LIGHTGBM_TPU_NATIVE_CACHE",
-    os.path.expanduser("~/.cache/lightgbm_tpu_native"))
+from .. import _CACHE_ROOT
+
+# built artefacts live inside the checkout, next to the JAX compile
+# cache: nothing prebuilt is read from outside it
+_CACHE = os.environ.get("LIGHTGBM_TPU_NATIVE_CACHE",
+                        os.path.join(_CACHE_ROOT, "native"))
 
 _libs = {}
 

@@ -51,7 +51,6 @@ from ..telemetry.health import (H_INF_HIST, H_NAN_GRAD, H_NAN_HESS,
                                 HEALTH_LEN, NUM_HEALTH)
 from ..utils.log import Log
 from .grow import TreeArrays
-from .pallas_compat import dynamic_grid_interpret_ok
 from .pallas_grow import (N_SCALARS, S_DB, S_DL, S_LE, S_LS, S_MASK, S_MF,
                           S_MT, S_NB, S_NCH, S_NL, S_S0, S_SH, S_SMALL_L,
                           S_THR, S_WG, make_root_hist, make_split_pass,
@@ -731,27 +730,6 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
     splits). The Mosaic path keeps the f32 fast-path trade
     (gpu_use_dp=false) unchanged.
     """
-    if kernel_impl == "pallas" and interpret \
-            and not dynamic_grid_interpret_ok():
-        # jax 0.4.x interpret mode cannot discharge the dynamic-grid
-        # split kernels (state-discharge dtype mismatch under x64);
-        # real-TPU Mosaic lowering is unaffected. Fall back loudly —
-        # but the widened XLA mode needs the f64 payload score layout,
-        # which is baked into the assets, so the downgrade is only
-        # possible when the caller built for it.
-        if not (bool(assets.geometry[10])
-                if len(assets.geometry) > 10 else False):
-            raise ValueError(
-                "pallas interpret mode cannot discharge the dynamic-grid "
-                "split kernels on this jax (< 0.5), and these assets "
-                "carry the f32 payload score layout the XLA emulation "
-                "cannot take; decide the downgrade before building "
-                "assets (build_assets(score64=True) + kernel_impl='xla', "
-                "as SerialTreeLearner._persist_kernel_effective does)")
-        Log.warning("pallas interpret mode cannot discharge the "
-                    "dynamic-grid split kernels on this jax (< 0.5); "
-                    "using the XLA kernel emulation")
-        kernel_impl = "xla"
     WPA, NP, G, plan, nbw, n, C, CR = assets.geometry[:8]
     K = assets.geometry[8] if len(assets.geometry) > 8 else 1
     has_w = bool(assets.geometry[9]) if len(assets.geometry) > 9 else False
@@ -2022,8 +2000,8 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
     def init_carry(pay, score0_row):
         """Fresh carry from the pristine payload + a row-ordered score
         vector ([n] or [K, n], any float dtype). One fused device program
-        — the eager op chain costs seconds of dispatch latency under
-        remote TPU."""
+        instead of an eager op chain (one dispatch, no [K, NP]
+        intermediates on the host's clock)."""
         s0 = score0_row.astype(SDT).reshape(K, n)
         sc = jnp.zeros((K, NP), SDT).at[:, :n].set(s0)
         return set_scores(pay, sc)
@@ -2067,6 +2045,14 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
     gr._eval_pair = evalB              # historical alias (B = 2)
     gr._root_hist = root_hist
     gr._pad_meta = pad_meta
+    # the kernels as built for this geometry (tests/test_chip_compile.py
+    # compiles exactly these for the described chip); None where the
+    # geometry or mode does not use one
+    gr._split_pass = split_pass
+    gr._seg_hist = seg_hist
+    gr._level_pass = level_pass
+    gr._level_seg = level_seg
+    gr.T_MAXL = T_MAXL
     return gr
 
 

@@ -1,58 +1,13 @@
-"""Single import point for the Pallas TPU API across jax versions.
+"""Single import point for the Pallas TPU API.
 
-jax 0.4.x spells the Mosaic compiler-params class
-``pltpu.TPUCompilerParams``; newer releases renamed it to
-``pltpu.CompilerParams``. A build where neither attribute exists cannot
-construct the Mosaic kernels at all, so the probe treats it exactly like
-a failed pallas import: ``HAS_PALLAS`` goes False and every caller takes
-its guarded XLA fallback instead of crashing later inside kernel
-construction with a ``NoneType is not callable``.
+Lint rule JG006 keeps every other module off the raw
+``jax.experimental.pallas`` import, so a move in that API is followed in
+this one file. The Mosaic kernels trace under ``enable_x64(False)`` so
+reference-parity f64 host math can stay on without weak-int promotion
+leaking i64 into the kernels.
 """
-from __future__ import annotations
+from jax import enable_x64  # noqa: F401
+from jax.experimental import pallas as pl  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu  # noqa: F401
 
-import jax as _jax
-
-# `jax.enable_x64` (the scoped dtype-default context) moved between
-# releases: 0.4.x only has jax.experimental.enable_x64, newer jax
-# promotes it to the top level. The Mosaic kernels trace under
-# enable_x64(False) so reference-parity f64 host math can stay on
-# without weak-int promotion leaking i64 into the kernels.
-if hasattr(_jax, "enable_x64"):
-    enable_x64 = _jax.enable_x64
-else:  # pragma: no cover - version-dependent
-    from jax.experimental import enable_x64  # noqa: F401
-
-try:  # pallas ships with jax; guard for exotic builds
-    from jax.experimental import pallas as pl  # noqa: F401
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-    TPUCompilerParams = getattr(
-        pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None))
-    if TPUCompilerParams is None:
-        raise ImportError("pallas TPU backend exposes neither "
-                          "CompilerParams nor TPUCompilerParams")
-    HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    pl = pltpu = TPUCompilerParams = None
-    HAS_PALLAS = False
-
-
-def _jax_version_tuple():
-    try:
-        return tuple(int(x) for x in _jax.__version__.split(".")[:2])
-    except Exception:  # pragma: no cover - exotic version strings
-        return (0, 0)
-
-
-def dynamic_grid_interpret_ok() -> bool:
-    """Whether the Pallas INTERPRETER can discharge the dynamic-grid
-    scalar-prefetch kernels (split_pass / level_pass).
-
-    jax 0.4.x's state-discharge pass rejects them under jax_enable_x64:
-    the aliased-payload update mixes weak-typed literals into a
-    ``lax.dynamic_update_slice`` with mismatched f32/f64 dtypes
-    (jax/_src/state/discharge.py raises TypeError). Real-TPU Mosaic
-    lowering and jax >= 0.5 interpret mode are unaffected. Callers that
-    would run such a kernel with interpret=True on an affected jax should
-    fall back to the XLA kernel emulation (grow_persist does, loudly) and
-    tests skip instead of erroring — tier-1 on old jax stays quiet."""
-    return _jax_version_tuple() >= (0, 5)
+CompilerParams = pltpu.CompilerParams

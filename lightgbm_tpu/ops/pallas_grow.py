@@ -68,8 +68,7 @@ import sys
 import jax
 import jax.numpy as jnp
 
-from .pallas_compat import HAS_PALLAS, enable_x64, pl, pltpu  # noqa: F401 — HAS_PALLAS re-exported (serial.py persist gate)
-from .pallas_compat import TPUCompilerParams as _TPUCompilerParams
+from .pallas_compat import CompilerParams, enable_x64, pl, pltpu
 
 I32 = jnp.int32
 U32 = jnp.uint32
@@ -224,7 +223,7 @@ def _hist_accum(hist_ref, bins_g, grad, hess, G: int):
     """hist_ref[g] += radix-16 one-hot MXU contraction of one chunk.
 
     bins_g: [G, E] i32; grad/hess: [E] f32 already masked to valid rows.
-    hist_ref: [G, 16, 64] f32 VMEM ref holding RAW accumulator columns
+    hist_ref: [G, 16, >=64] f32 VMEM ref holding RAW accumulator columns
     v*16+lo for v in (grad_hi, hess_hi, grad_lo, hess_lo) — the bf16 hi/lo
     pairs that make the contraction exact to f32 (ops/pallas_histogram
     docs). The 4 value columns ride ONE [64, E] rhs so each group costs one
@@ -248,7 +247,9 @@ def _hist_accum(hist_ref, bins_g, grad, hess, G: int):
         # rows breaks Mosaic); concatenating four known-good [16, E]
         # scaled one-hots gives the same [64, E] rhs
         bv = jnp.concatenate([oh_lo * v[None, :] for v in vt], axis=0)
-        hist_ref[g] = hist_ref[g] + jax.lax.dot_general(
+        # lanes [0, 64) only: the level kernels' accumulators carry
+        # HIST_LANES_PAD lanes (see below), the others exactly 64
+        hist_ref[g, :, 0:64] = hist_ref[g, :, 0:64] + jax.lax.dot_general(
             oh_hi, bv, dn, preferred_element_type=F32)            # [16, 64]
 
 
@@ -262,6 +263,15 @@ def plane_health(g_plane, h_plane):
     bad_g = jnp.sum(~jnp.isfinite(g_plane), dtype=I32)
     bad_h = jnp.sum(~jnp.isfinite(h_plane), dtype=I32)
     return bad_g + bad_h
+
+
+# The level kernels DMA each slot's finished accumulator into row j of an
+# HBM [S_max, ...] output. Mosaic refuses a leading-dim slice of an HBM
+# ref whose minor dim is not a multiple of the 128-lane tile, so their
+# accumulator scratch and output carry HIST_LANES_PAD lanes, of which the
+# first 64 hold the [16, 64] radix columns; the wrappers slice the pad
+# off outside the kernel.
+HIST_LANES_PAD = 128
 
 
 def _unpack_hist(hist):
@@ -480,7 +490,7 @@ def make_split_pass(WPA: int, NP: int, G: int, plan, nbw: int,
             cnt_ref[0] = st[6]
 
     E_ = C + 128
-    _cparams = _TPUCompilerParams(
+    _cparams = CompilerParams(
         vmem_limit_bytes=split_pass_vmem_bytes(WPA, E_, G))
 
     @jax.jit
@@ -504,9 +514,9 @@ def make_split_pass(WPA: int, NP: int, G: int, plan, nbw: int,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1,
                 grid=(grid,),
-                in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+                in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
                 out_specs=[
-                    pl.BlockSpec(memory_space=pltpu.ANY),
+                    pl.BlockSpec(memory_space=pl.ANY),
                     pl.BlockSpec((G, 16, 64),
                                  lambda i, s: (i * 0, i * 0, i * 0)),
                     pl.BlockSpec((1,), lambda i, s: (i * 0,),
@@ -732,7 +742,7 @@ def make_level_pass(WPA: int, NP: int, G: int, plan, nbw: int,
             cph.wait()
 
     E_ = C + 128
-    _cparams = _TPUCompilerParams(
+    _cparams = CompilerParams(
         vmem_limit_bytes=split_pass_vmem_bytes(WPA, E_, G))
 
     @jax.jit
@@ -741,7 +751,7 @@ def make_level_pass(WPA: int, NP: int, G: int, plan, nbw: int,
             pay2, hist, cnt = _call(pay, scal_mat, slot_of_step,
                                     base_of_slot,
                                     jnp.maximum(grid, 1).astype(jnp.int32))
-        return pay2, hist, cnt
+        return pay2, hist[..., :64], cnt
 
     def _call(pay, scal_mat, slot_of_step, base_of_slot, grid):
         return pl.pallas_call(
@@ -749,15 +759,15 @@ def make_level_pass(WPA: int, NP: int, G: int, plan, nbw: int,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=3,
                 grid=(grid,),
-                in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+                in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
                 out_specs=[
-                    pl.BlockSpec(memory_space=pltpu.ANY),
-                    pl.BlockSpec(memory_space=pltpu.ANY),
+                    pl.BlockSpec(memory_space=pl.ANY),
+                    pl.BlockSpec(memory_space=pl.ANY),
                     pl.BlockSpec((S_max,), lambda i, *s: (i * 0,),
                                  memory_space=pltpu.SMEM),
                 ],
                 scratch_shapes=[
-                    pltpu.VMEM((G, 16, 64), F32),   # hist accumulator
+                    pltpu.VMEM((G, 16, HIST_LANES_PAD), F32),  # hist acc
                     pltpu.VMEM((WPA, E), U32),      # wbuf
                     pltpu.VMEM((WPA, E), U32),      # obuf
                     pltpu.VMEM((WPA, E), U32),      # rbuf
@@ -771,7 +781,7 @@ def make_level_pass(WPA: int, NP: int, G: int, plan, nbw: int,
             ),
             out_shape=[
                 jax.ShapeDtypeStruct((WPA, NP), U32),
-                jax.ShapeDtypeStruct((S_max, G, 16, 64), F32),
+                jax.ShapeDtypeStruct((S_max, G, 16, HIST_LANES_PAD), F32),
                 jax.ShapeDtypeStruct((S_max,), I32),
             ],
             input_output_aliases={3: 0},
@@ -830,7 +840,7 @@ def make_level_seg_hist(WPA: int, NP: int, G: int, plan, nbw: int,
             cph.start()
             cph.wait()
 
-    _cparams = _TPUCompilerParams(
+    _cparams = CompilerParams(
         vmem_limit_bytes=seg_hist_vmem_bytes(WPA, E, G))
 
     @jax.jit
@@ -841,20 +851,21 @@ def make_level_seg_hist(WPA: int, NP: int, G: int, plan, nbw: int,
                 grid_spec=pltpu.PrefetchScalarGridSpec(
                     num_scalar_prefetch=3,
                     grid=(jnp.maximum(grid, 1).astype(jnp.int32),),
-                    in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-                    out_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+                    in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+                    out_specs=[pl.BlockSpec(memory_space=pl.ANY)],
                     scratch_shapes=[
-                        pltpu.VMEM((G, 16, 64), F32),
+                        pltpu.VMEM((G, 16, HIST_LANES_PAD), F32),
                         pltpu.VMEM((WPA, E), U32),
                         pltpu.SemaphoreType.DMA,
                         pltpu.SemaphoreType.DMA,
                     ],
                 ),
-                out_shape=[jax.ShapeDtypeStruct((S_max, G, 16, 64), F32)],
+                out_shape=[jax.ShapeDtypeStruct(
+                    (S_max, G, 16, HIST_LANES_PAD), F32)],
                 compiler_params=_cparams,
                 interpret=interpret,
             )(scal_mat, slot_of_step, base_of_slot, pay)[0]
-        return hist
+        return hist[..., :64]
 
     return level_seg_hist
 
@@ -904,7 +915,7 @@ def make_seg_hist(WPA: int, NP: int, G: int, plan, nbw: int,
         bins_g = _unpack_group_bins(w, plan)
         _hist_accum(hist_ref, bins_g, grad, hess, G)
 
-    _cparams = _TPUCompilerParams(
+    _cparams = CompilerParams(
         vmem_limit_bytes=seg_hist_vmem_bytes(WPA, E, G))
 
     @jax.jit
@@ -918,7 +929,7 @@ def make_seg_hist(WPA: int, NP: int, G: int, plan, nbw: int,
                 grid_spec=pltpu.PrefetchScalarGridSpec(
                     num_scalar_prefetch=1,
                     grid=(grid,),
-                    in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+                    in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
                     out_specs=[
                         pl.BlockSpec((G, 16, 64),
                                      lambda i, s: (i * 0, i * 0, i * 0)),
@@ -989,7 +1000,7 @@ def make_root_hist(WPA: int, NP: int, G: int, plan, nbw: int, n: int,
 
     # the streaming chunk buffer alone (WPA*C u32) outgrows the 16MB
     # Mosaic default on wide unbundled payloads (~180 words at C=16384)
-    _cparams = _TPUCompilerParams(
+    _cparams = CompilerParams(
         vmem_limit_bytes=seg_hist_vmem_bytes(WPA, C, G))
 
     def _call(pay):
@@ -997,7 +1008,7 @@ def make_root_hist(WPA: int, NP: int, G: int, plan, nbw: int, n: int,
             kernel,
             compiler_params=_cparams,
             grid=(nch,),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=[
                 pl.BlockSpec((G, 16, 64),
                              lambda i: (i * 0, i * 0, i * 0)),

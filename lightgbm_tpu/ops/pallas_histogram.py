@@ -27,8 +27,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .pallas_compat import HAS_PALLAS, pl  # noqa: F401 — HAS_PALLAS re-exported (kernel tests gate on it)
-from .pallas_compat import TPUCompilerParams as _TPUCompilerParams
+from .pallas_compat import CompilerParams, pl
 
 
 def _round_up(x: int, m: int) -> int:
@@ -178,7 +177,7 @@ def hist_window(bins_t: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
     G, C = bins_t.shape
     plan = hist_vmem_plan(w, G, C)
     use_radix, w_pad, ct = plan["use_radix"], plan["w_pad"], plan["ct"]
-    _cparams = _TPUCompilerParams(vmem_limit_bytes=plan["vmem_limit"])
+    _cparams = CompilerParams(vmem_limit_bytes=plan["vmem_limit"])
     kernel = _hist_kernel_radix if use_radix else _hist_kernel
     nst = (C + ct - 1) // ct
     if nst * ct != C:

@@ -36,8 +36,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-from .pallas_compat import HAS_PALLAS, pl, pltpu  # noqa: F401 — HAS_PALLAS re-exported (kernel tests gate on it)
-from .pallas_compat import TPUCompilerParams as _TPUCompilerParams
+from .pallas_compat import CompilerParams, enable_x64, pl, pltpu
 
 NEG_INF = float("-inf")
 
@@ -282,7 +281,7 @@ def scan_pair(scal, gb, hb, keep_r, keep_f, valid_r, valid_f, aux,
     _vmem = scan_pair_vmem_bytes(Fp, Wp)
     return pl.pallas_call(
         _scan_kernel,
-        compiler_params=_TPUCompilerParams(vmem_limit_bytes=_vmem),
+        compiler_params=CompilerParams(vmem_limit_bytes=_vmem),
         grid=(B,),
         in_specs=[
             pl.BlockSpec((1, 1, 128), lambda c: (c, c * 0, c * 0)),
@@ -540,21 +539,26 @@ def scan_blocks(scal, gb, hb, masks, do_fix: bool = False,
         scal.astype(jnp.float32))
     _vmem = scan_blocks_vmem_bytes(Gp, Wp)
     kern = functools.partial(_scan_blocks_kernel, do_fix)
-    return pl.pallas_call(
-        kern,
-        compiler_params=_TPUCompilerParams(vmem_limit_bytes=_vmem),
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1, 1, 128), lambda c: (c, c * 0, c * 0)),
-            pl.BlockSpec((1, Gp, Wp), lambda c: (c, c * 0, c * 0)),
-            pl.BlockSpec((1, Gp, Wp), lambda c: (c, c * 0, c * 0)),
-            pl.BlockSpec((BM_ROWS, Gp, Wp),
-                         lambda c: (c * 0, c * 0, c * 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 8, Gp), lambda c: (c, c * 0, c * 0)),
-        out_shape=jax.ShapeDtypeStruct((B, 8, Gp), jnp.float32),
-        interpret=interpret,
-    )(scal_p, gb, hb, masks)
+    # trace with 32-bit default dtypes (as ops/pallas_grow does): under
+    # jax_enable_x64 the static lane-roll shifts of _fill_fwd/_fill_bwd
+    # trace as i64, which Mosaic's rotate refuses
+    with enable_x64(False):
+        return pl.pallas_call(
+            kern,
+            compiler_params=CompilerParams(vmem_limit_bytes=_vmem),
+            grid=(B,),
+            in_specs=[
+                pl.BlockSpec((1, 1, 128), lambda c: (c, c * 0, c * 0)),
+                pl.BlockSpec((1, Gp, Wp), lambda c: (c, c * 0, c * 0)),
+                pl.BlockSpec((1, Gp, Wp), lambda c: (c, c * 0, c * 0)),
+                pl.BlockSpec((BM_ROWS, Gp, Wp),
+                             lambda c: (c * 0, c * 0, c * 0)),
+            ],
+            out_specs=pl.BlockSpec((1, 8, Gp),
+                                   lambda c: (c, c * 0, c * 0)),
+            out_shape=jax.ShapeDtypeStruct((B, 8, Gp), jnp.float32),
+            interpret=interpret,
+        )(scal_p, gb, hb, masks)
 
 
 def build_block_scan_meta(group_of, ls, nb, mt, db, mf, needs_fix,

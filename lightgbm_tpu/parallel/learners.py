@@ -41,7 +41,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.tree import Tree
 from ..ops.grow import DataLayout, GrowConfig, grow_tree, grow_tree_partitioned
@@ -50,23 +50,6 @@ from ..treelearner.serial import PARTITION_MIN_ROWS, SerialTreeLearner
 from ..utils.log import Log
 
 AXIS = "data"
-
-# jax >= 0.5 promotes shard_map to jax.shard_map with a `check_vma` kwarg;
-# 0.4.x has jax.experimental.shard_map.shard_map with `check_rep`. One
-# compat entry point so every sharded program builds on either runtime.
-try:
-    _jax_shard_map = jax.shard_map
-    _SM_LEGACY = False
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _jax_shard_map
-    _SM_LEGACY = True
-
-
-def shard_map_compat(f, **kw):
-    if _SM_LEGACY and "check_vma" in kw:
-        kw["check_rep"] = kw.pop("check_vma")
-    return _jax_shard_map(f, **kw)
-
 
 def _make_mesh(num_devices: int = 0) -> Mesh:
     devs = jax.devices()
@@ -108,9 +91,9 @@ class DataParallelTreeLearner(SerialTreeLearner):
                                 self.num_shards, weight_max=w_max)
         self.hist_quant, self.hist_quant_cert = hq if hq else (None, None)
         self.comm_overlap = resolve_comm_overlap(config)
-        # pad the HBM-resident bins ONCE; per-tree inputs pad per call
-        self._bins_padded = (jnp.pad(self.layout.bins, ((0, self._pad), (0, 0)))
-                             if self._pad else self.layout.bins)
+        # the v1 sharded grower's row-sharded bins, placed on first use
+        # (train_arrays): a run that stays on the persist path never pays
+        self._bins_padded = None
         # rebuild the sharded grow fn once per dataset
         self._sharded_grow = None
 
@@ -135,7 +118,7 @@ class DataParallelTreeLearner(SerialTreeLearner):
         ell_specs = (P(AXIS), P(AXIS)) if mv else ()
 
         @functools.partial(
-            shard_map_compat, mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=(P(AXIS), P(AXIS), P(AXIS), P(AXIS), P(), P())
             + ell_specs,
             out_specs=(_tree_arrays_spec(gc, row_sharded=True), P()),
@@ -163,6 +146,15 @@ class DataParallelTreeLearner(SerialTreeLearner):
         if self._sharded_grow is None:
             self._sharded_grow = self._build()
         pad = self._pad
+        if self._bins_padded is None:
+            # pad the HBM-resident bins ONCE (per-tree inputs pad per
+            # call) and commit them to the row sharding the program runs
+            # under: layout.bins is an uncommitted array on the first
+            # device, and left there every call would re-scatter it
+            bins = (jnp.pad(self.layout.bins, ((0, pad), (0, 0)))
+                    if pad else self.layout.bins)
+            self._bins_padded = jax.device_put(
+                bins, NamedSharding(self.mesh, P(AXIS)))
         bins = self._bins_padded
         if pad:
             grad = jnp.pad(grad, (0, pad))
@@ -240,7 +232,6 @@ class DataParallelTreeLearner(SerialTreeLearner):
                                         make_bag_transform,
                                         make_persist_grower,
                                         make_scan_driver)
-        from jax.sharding import NamedSharding
         cache = getattr(self.dataset, "_persist_cache", None)
         if cache is None:
             cache = self.dataset._persist_cache = {}
@@ -291,11 +282,11 @@ class DataParallelTreeLearner(SerialTreeLearner):
             wrapper.comm_overlap = inner.comm_overlap
             wrapper.wire_bytes_model = inner.wire_bytes_model
             wrapper.reduced_feature_frac = inner.reduced_feature_frac
-            wrapper.init_carry = jax.jit(shard_map_compat(
+            wrapper.init_carry = jax.jit(jax.shard_map(
                 inner.init_carry, mesh=mesh,
                 in_specs=(pay_spec, P(AXIS)), out_specs=pay_spec,
                 check_vma=False))
-            wrapper.finalize_scores = jax.jit(shard_map_compat(
+            wrapper.finalize_scores = jax.jit(jax.shard_map(
                 inner.finalize_scores, mesh=mesh,
                 in_specs=(pay_spec,), out_specs=P(AXIS),
                 check_vma=False))
@@ -311,7 +302,7 @@ class DataParallelTreeLearner(SerialTreeLearner):
             raw = make_scan_driver(wrapper.inner, gc, k,
                                    objective.payload_grad_fn(),
                                    wrap_jit=False, bag_fn=bag_fn)
-            smapped = shard_map_compat(
+            smapped = jax.shard_map(
                 raw, mesh=mesh,
                 in_specs=(pay_spec, P(), P(), P(), P(), P(), P()),
                 out_specs=(pay_spec,
@@ -409,7 +400,7 @@ class FeatureParallelTreeLearner(SerialTreeLearner):
         gw_global = self.gw_global
 
         @functools.partial(
-            shard_map_compat, mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=(P(), P(), P(), P(), P(), P()),
             out_specs=(_tree_arrays_spec(gc, row_sharded=False), P()),
             check_vma=False)

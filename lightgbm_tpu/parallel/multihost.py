@@ -64,7 +64,7 @@ from ..telemetry import events as telemetry
 from ..utils.log import Log
 from .distributed import (distributed_bin_mappers, init_network,
                           resolve_hist_quant)
-from .learners import AXIS, _tree_arrays_spec, shard_map_compat
+from .learners import AXIS, _tree_arrays_spec
 
 __all__ = ["init_network", "shard_rows", "train_multihost"]
 
@@ -647,7 +647,7 @@ def train_multihost(config: Config, X_local: np.ndarray,
 
         spec_gargs = tuple(garg_specs)
         score_spec = P(AXIS) if K == 1 else P(None, AXIS)
-        return jax.jit(shard_map_compat(
+        return jax.jit(jax.shard_map(
             body_fn, mesh=mesh,
             in_specs=(P(AXIS, None), P(AXIS), P(AXIS), spec_gargs,
                       score_spec, P(), P(), P(), P(), P())

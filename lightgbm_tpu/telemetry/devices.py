@@ -79,15 +79,37 @@ DEVICE_PROFILES: Dict[str, DeviceProfile] = {
     "v4": DeviceProfile("v4", vmem_bytes=32 * MIB, hbm_bytes=32 * GIB,
                         peak_flops=275e12, hbm_bw_bytes=1228e9,
                         ici_bw_bytes=300e9),
-    # host fallback: rounds recorded on CPU boxes (no accelerator) still
-    # get a roofline verdict — a generous desktop-class envelope so the
-    # bound CLASSIFICATION is meaningful even if the fraction is coarse
+    # NOT a device: an envelope for phase snapshots recorded on the CPU
+    # platform, so the bound CLASSIFICATION of a perf card still reads
+    # "host" there. Kept because three tier-1 tests stamp a card on the
+    # CPU (test_perf_gate::test_build_meta_roundtrips_through_validator,
+    # ::test_profile_perf_card_cli, test_expo_fastpath's profile-CLI
+    # smoke); nothing computed against it is a device metric.
     "cpu": DeviceProfile("cpu", vmem_bytes=16 * MIB, hbm_bytes=16 * GIB,
                          peak_flops=1e12, hbm_bw_bytes=50e9,
                          ici_bw_bytes=10e9),
 }
 
-DEFAULT_PROFILE = "v5e"
+# ``jax.devices()[0].device_kind`` (lowercased) -> profile name. The
+# v5e chip reports itself as "TPU v5 lite"; a kind that is not listed
+# here is an error, never a default.
+DEVICE_KINDS: Dict[str, str] = {
+    "tpu v5 lite": "v5e",
+    "tpu v5e": "v5e",
+    "tpu v5p": "v5p",
+    "tpu v5": "v5p",
+    "tpu v4": "v4",
+    "cpu": "cpu",
+}
+
+
+def on_tpu() -> bool:
+    """The one backend test of the package: True when JAX's default
+    backend is the TPU. Every Mosaic-vs-XLA choice (histogram, split
+    scan, persist kernels, interpret mode) keys off this and nothing
+    else, so a process that is not on the chip never claims it is."""
+    import jax
+    return jax.default_backend() == "tpu"
 
 
 def get_profile(name: str) -> DeviceProfile:
@@ -99,21 +121,24 @@ def get_profile(name: str) -> DeviceProfile:
 
 
 def detect_profile() -> DeviceProfile:
-    """Profile of the attached accelerator, or the default tuning target.
+    """Profile of the attached accelerator, matched on ``device_kind``.
 
-    Pure string matching on ``device_kind`` — never initializes a
-    backend that is not already initialized (the analysis gate runs on
-    CPU machines; touching jax.devices() there is fine, on a multi-host
-    setup mid-init it is not, so the env override wins outright)."""
+    The ``LGBTPU_DEVICE_PROFILE`` override wins outright (a machine
+    without the accelerator names the device it reasons about, and on a
+    multi-host setup mid-init ``jax.devices()`` must not be touched).
+    A backend that cannot be initialised raises whatever JAX raises; a
+    ``device_kind`` with no row in :data:`DEVICE_KINDS` is a
+    ``ValueError`` — peaks are never assumed for an unknown device."""
     override = os.environ.get("LGBTPU_DEVICE_PROFILE", "")
     if override:
         return get_profile(override)
-    try:
-        import jax
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:
-        return DEVICE_PROFILES[DEFAULT_PROFILE]
-    for name in DEVICE_PROFILES:
-        if name in kind:
-            return DEVICE_PROFILES[name]
-    return DEVICE_PROFILES[DEFAULT_PROFILE]
+    import jax
+    kind = jax.devices()[0].device_kind
+    name = DEVICE_KINDS.get(kind.lower())
+    if name is None:
+        raise ValueError(
+            "no device profile for device_kind %r (known kinds: %s); set "
+            "LGBTPU_DEVICE_PROFILE to one of %s to name the device"
+            % (kind, ", ".join(sorted(DEVICE_KINDS)),
+               ", ".join(sorted(DEVICE_PROFILES))))
+    return DEVICE_PROFILES[name]

@@ -191,11 +191,9 @@ FIXTURES = {
                 return pl.pallas_call(f, out_shape=shape)
             """,
         "negative": """
-            from .pallas_compat import HAS_PALLAS, pl, pltpu
+            from .pallas_compat import pl, pltpu
 
             def kernel_call(f, shape):
-                if not HAS_PALLAS:
-                    return None
                 return pl.pallas_call(f, out_shape=shape)
             """,
     },

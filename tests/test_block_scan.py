@@ -16,13 +16,9 @@ import pytest
 import jax.numpy as jnp
 
 from lightgbm_tpu.analysis import strict_numerics
-from lightgbm_tpu.ops.pallas_scan import (HAS_PALLAS, ScanLayout,
-                                          build_block_scan_meta,
+from lightgbm_tpu.ops.pallas_scan import (ScanLayout, build_block_scan_meta,
                                           scan_blocks, scan_pair)
 from lightgbm_tpu.ops.split import FeatureMeta
-
-if not HAS_PALLAS:  # pragma: no cover
-    pytest.skip("pallas unavailable", allow_module_level=True)
 
 W = 256
 

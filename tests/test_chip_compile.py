@@ -1,0 +1,220 @@
+"""Described-topology compiles: the Mosaic kernels of the training path,
+compiled for a TPU v5e that is described and not attached.
+
+Interpret mode (every other kernel test) cannot see what the chip's
+compiler refuses: a slice not aligned to the tiling, a rotate fed an i64
+shift, more VMEM than a kernel may hold. These cases hand the TPU compiler
+each ``pallas_call`` site at the geometry the code itself builds for a real
+configuration — the persist grower's kernels for HIGGS (10.5M x 28,
+255 bins, 255 leaves), the same payload with a finite ``max_depth`` for the
+level kernels, and an EFB-bundled Expo-like payload (11M rows) for the
+block scan — plus the whole fused k=16 scan driver. Nothing runs, so they
+say nothing about results or times; ``chip_smoke.py`` does that on the chip.
+
+All cases live in this one file: the topology is described inside a
+module-scoped fixture (never at import, in a skipif or in a parametrize),
+because only one process at a time may load the TPU library and each xdist
+worker imports every test file. The persistent compile cache is off around
+them — a described-topology entry cannot be read back without a chip.
+"""
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+import lightgbm_tpu as lgb
+from lightgbm_tpu.data.dataset import BinnedDataset
+from lightgbm_tpu.data.synth import make_expo_like, make_higgs_like
+from lightgbm_tpu.objectives import create_objective
+from lightgbm_tpu.ops import grow_persist as gp
+from lightgbm_tpu.ops.pallas_grow import N_SCALARS
+from lightgbm_tpu.ops.pallas_histogram import hist_window
+from lightgbm_tpu.ops.pallas_scan import (ScanLayout, build_block_scan_meta,
+                                          scan_blocks, scan_pair)
+from lightgbm_tpu.treelearner.serial import SerialTreeLearner
+
+HIGGS_ROWS = 10_500_000     # docs/Experiments.rst: HIGGS
+EXPO_ROWS = 11_000_000      # docs/Experiments.rst: Expo
+LEVEL_DEPTH = 8             # max_depth; with num_leaves = 2^8 the level
+#                             phase engages (bench.py's level configuration)
+SAMPLE_ROWS = 20_000        # rows actually binned: the bin structure only
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip("no v5e:2x2 topology can be described here: %r" % e)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+class _Built:
+    """One configuration as the code builds it for the chip: the learner's
+    GrowConfig on a TPU backend, and persist growers whose payload
+    geometry is that of the full row count."""
+
+    def __init__(self, X, y, rows, max_depths):
+        params = {"objective": "binary", "num_leaves": 255, "max_bin": 255}
+        cfg = lgb.Config(params)
+        self.ds = BinnedDataset.from_matrix(X, cfg, label=y)
+        small = gp.build_assets(self.ds, self.ds.metadata.label)
+        G, plan, nbw, CR = (small.geometry[2], small.geometry[3],
+                            small.geometry[4], small.geometry[7])
+        WPA, C, NP = gp._payload_geometry(rows, nbw, 0, CR)
+        self.assets = small._replace(
+            pay0=None,
+            geometry=(WPA, NP, G, plan, nbw, rows, C, CR, 1, False, False))
+        self.pay = (WPA, NP)
+        self.objective = create_objective("binary", cfg)
+        self.objective.init(self.ds.metadata, self.ds.num_data)
+        self.learners, self.growers = {}, {}
+        # the learner reads the backend to choose f32/bf16x2 and the
+        # Mosaic scan; steer it here, in the test, to what it does on TPU
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(jax, "default_backend", lambda: "tpu")
+            for md in max_depths:
+                c = lgb.Config(dict(params, max_depth=md) if md < 0 else
+                               dict(params, max_depth=md,
+                                    num_leaves=1 << md))
+                self.learners[md] = SerialTreeLearner(c, self.ds)
+        for md, learner in self.learners.items():
+            gc = learner.grow_config
+            assert gc.scan_impl == "pallas" and not gc.use_dp, gc
+            self.growers[md] = gp.make_persist_grower(
+                self.assets, learner.meta, gc, interpret=False,
+                kernel_impl="pallas", fix=learner.fix)
+
+
+@pytest.fixture(scope="module")
+def higgs():
+    X, y = make_higgs_like(SAMPLE_ROWS)
+    return _Built(X, y, HIGGS_ROWS, (-1, LEVEL_DEPTH))
+
+
+@pytest.fixture(scope="module")
+def expo():
+    X, y = make_expo_like(SAMPLE_ROWS)
+    return _Built(X, y, EXPO_ROWS, (LEVEL_DEPTH,))
+
+
+def _hist_window(higgs, expo, S):
+    learner = higgs.learners[-1]
+    G, C = len(higgs.ds.groups), learner.grow_config.window_chunk
+    w = int(learner.gw_global.shape[1])
+    return (lambda b, g, h: hist_window(b, g, h, w=w),
+            (S((G, C), jnp.int32), S((C,), jnp.float32),
+             S((C,), jnp.float32)))
+
+
+def _scan_pair(higgs, expo, S):
+    gr = higgs.growers[-1]
+    F = higgs.ds.num_features
+    lay = ScanLayout(gr._pad_meta, jnp.ones(F, bool), F, 256,
+                     len(higgs.ds.groups) * 256)
+    f32 = jnp.float32
+    plane, mask = (2, lay.Fp, lay.Wp), lay.keep_r.shape
+    return scan_pair, (S((2, 8), f32), S(plane, f32), S(plane, f32),
+                       S(mask, f32), S(mask, f32), S(mask, f32),
+                       S(mask, f32), S(lay.aux.shape, f32))
+
+
+def _scan_blocks(higgs, expo, S):
+    (group_of, ls, nb, mf, needs_fix, bundled, mt, db) = expo.assets.efb
+    assert bundled and len(expo.ds.groups) < expo.ds.num_features
+    G = len(expo.ds.groups)
+    blk = build_block_scan_meta(group_of, ls, nb, mt, db, mf, needs_fix,
+                                np.ones(expo.ds.num_features), G, 256)
+    _, Gp, Wp = blk["masks"].shape
+    do_fix = bool(needs_fix.any())
+    f32 = jnp.float32
+    return (lambda s, g, h, m: scan_blocks(s, g, h, m, do_fix=do_fix),
+            (S((2, 9), f32), S((2, Gp, Wp), f32), S((2, Gp, Wp), f32),
+             S(blk["masks"].shape, f32)))
+
+
+def _split_pass(higgs, expo, S):
+    return higgs.growers[-1]._split_pass, (
+        S(higgs.pay, jnp.uint32), S((N_SCALARS,), jnp.int32))
+
+
+def _seg_hist(higgs, expo, S):
+    return higgs.growers[-1]._seg_hist, (
+        S(higgs.pay, jnp.uint32), S((), jnp.int32), S((), jnp.int32))
+
+
+def _root_hist(higgs, expo, S):
+    return higgs.growers[-1]._root_hist, (S(higgs.pay, jnp.uint32),)
+
+
+def _level_args(built, gr, S):
+    i32 = jnp.int32
+    return (S(built.pay, jnp.uint32), S((gr.S_MAXL, 16), i32),
+            S((gr.T_MAXL,), i32), S((gr.S_MAXL,), i32), S((), i32))
+
+
+def _level_pass(higgs, expo, S):
+    gr = higgs.growers[LEVEL_DEPTH]
+    assert gr.use_level
+    return gr._level_pass, _level_args(higgs, gr, S)
+
+
+def _level_pass_inpass(higgs, expo, S):
+    """<= SEG_HIST_MIN_GROUPS groups: the smaller-child histograms
+    accumulate inside the partition pass (the Expo shape)."""
+    gr = expo.growers[LEVEL_DEPTH]
+    assert gr.use_level and gr._level_seg is None
+    return gr._level_pass, _level_args(expo, gr, S)
+
+
+def _level_seg_hist(higgs, expo, S):
+    gr = higgs.growers[LEVEL_DEPTH]
+    return gr._level_seg, _level_args(higgs, gr, S)
+
+
+def _fused_driver(higgs, expo, S):
+    """make_scan_driver's whole k=16 program: the kernels above plus the
+    XLA around them, at the size chip_smoke.py trains."""
+    k, F = 16, higgs.ds.num_features
+    learner, gr = higgs.learners[-1], higgs.growers[-1]
+    mode, grad_fn = higgs.objective.device_gradients()
+    run = gp.make_scan_driver(gr, learner.grow_config, k, grad_fn,
+                              grad_mode=mode, wrap_jit=False)
+    like = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: S(np.shape(a), jnp.asarray(a).dtype), tree)
+    return run, (S(higgs.pay, jnp.uint32), S((k, F), jnp.bool_),
+                 S((k, 2), jnp.uint32), S((k,), jnp.int32),
+                 like(learner.params), S((), jnp.float64),
+                 like(higgs.objective.persist_grad_args()))
+
+
+@pytest.mark.parametrize("case", [
+    _hist_window, _scan_pair, _scan_blocks, _split_pass, _level_pass,
+    _level_pass_inpass, _level_seg_hist, _seg_hist, _root_hist,
+    _fused_driver,
+], ids=lambda f: f.__name__.lstrip("_"))
+def test_compiles_for_v5e(case, one_chip, higgs, expo):
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fn, args = case(higgs, expo, S)
+    assert fn is not None, "the grower built no such kernel here"
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()

@@ -205,15 +205,8 @@ def test_expo_level_launches_per_tree_bounded():
 def test_level_mosaic_kernels_interpret_match_emulation(monkeypatch):
     """The production TPU level path (make_level_pass multi-leaf
     partition + in-pass histograms) in Pallas INTERPRETER mode must
-    reproduce the XLA-emulation trees. Skips on jax < 0.5, whose
-    interpret mode cannot discharge the dynamic-grid kernels
-    (make_persist_grower falls back to the emulation loudly there, so
-    interpret-vs-emulation would assert nothing)."""
-    from lightgbm_tpu.ops.pallas_compat import dynamic_grid_interpret_ok
+    reproduce the XLA-emulation trees."""
     from lightgbm_tpu.treelearner.serial import SerialTreeLearner
-    if not dynamic_grid_interpret_ok():
-        pytest.skip("pallas interpret mode cannot discharge the "
-                    "dynamic-grid level kernels on this jax (< 0.5)")
     X, y = _higgs_small(2048)
     base = {"objective": "binary", "num_leaves": 16, "max_depth": 4,
             "verbosity": -1, "min_data_in_leaf": 10, "max_bin": 31,

@@ -665,13 +665,8 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("XLA_FLAGS",
                       "--xla_force_host_platform_device_count=2")
 import jax
-jax.config.update("jax_platforms", "cpu")
-for opt, val in (("jax_num_cpu_devices", 2),
-                 ("jax_cpu_collectives_implementation", "gloo")):
-    try:
-        jax.config.update(opt, val)
-    except AttributeError:       # older jax: XLA_FLAGS already set it
-        pass
+jax.config.update("jax_num_cpu_devices", 2)
+jax.config.update("jax_cpu_collectives_implementation", "gloo")
 from lightgbm_tpu.config import Config
 from lightgbm_tpu.parallel.fingerprint import DivergenceError
 from lightgbm_tpu.parallel.multihost import shard_rows, train_multihost

@@ -18,8 +18,7 @@ import pytest
 import jax.numpy as jnp
 
 from lightgbm_tpu.analysis import strict_numerics
-from lightgbm_tpu.ops.pallas_histogram import (HAS_PALLAS, hist_window,
-                                               hist_window_xla)
+from lightgbm_tpu.ops.pallas_histogram import hist_window, hist_window_xla
 
 
 def _hist_strict(bins_t, grad, hess, w):
@@ -30,7 +29,6 @@ def _hist_strict(bins_t, grad, hess, w):
     return np.asarray(out)
 
 
-@pytest.mark.skipif(not HAS_PALLAS, reason="pallas unavailable")
 @pytest.mark.parametrize("C,G,W", [(512, 4, 64), (1024, 7, 256), (256, 1, 128)])
 def test_pallas_hist_matches_xla(C, G, W):
     rng = np.random.default_rng(0)
@@ -61,7 +59,6 @@ def _scatter_ref(bins, grad, hess, w):
     return out
 
 
-@pytest.mark.skipif(not HAS_PALLAS, reason="pallas unavailable")
 @pytest.mark.parametrize("C,G,W", [
     (768, 3, 256),    # byte groups -> radix-split kernel
     (768, 18, 256),   # the Expo geometry (few wide groups, radix)
@@ -100,7 +97,6 @@ def test_stripe_retune_few_groups():
     assert _select_impl(256, 4, 4096)[2] == 4096         # capped by C
 
 
-@pytest.mark.skipif(not HAS_PALLAS, reason="pallas unavailable")
 def test_pallas_hist_totals_exact():
     """Per-group totals must equal the f32 sums exactly (bf16 hi/lo split)."""
     rng = np.random.default_rng(1)

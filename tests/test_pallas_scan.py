@@ -15,13 +15,9 @@ import jax.numpy as jnp
 import lightgbm_tpu as lgb
 from lightgbm_tpu.data.dataset import BinnedDataset
 from lightgbm_tpu.ops.grow import grow_tree, grow_tree_partitioned
-from lightgbm_tpu.ops.pallas_scan import HAS_PALLAS
 from lightgbm_tpu.ops.split import SplitParams
 from lightgbm_tpu.treelearner.serial import (build_cat_layout,
                                              build_gw_global)
-
-if not HAS_PALLAS:  # pragma: no cover
-    pytest.skip("pallas unavailable", allow_module_level=True)
 
 
 def _problem(n=4000, f=7, seed=3, missing=True):

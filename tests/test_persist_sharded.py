@@ -306,14 +306,7 @@ def test_persist_mosaic_kernels_interpret_match_emulation(monkeypatch):
     must reproduce the XLA-emulation trees — covers the Mosaic wiring
     (chunk DMA alignment rolls, lane masks, FIFO drains, seg_hist
     start/len) that the emulation-only tests never touch."""
-    from lightgbm_tpu.ops.pallas_compat import dynamic_grid_interpret_ok
     from lightgbm_tpu.treelearner.serial import SerialTreeLearner
-    if not dynamic_grid_interpret_ok():
-        # jax 0.4.x state discharge rejects the dynamic-grid kernels in
-        # interpret mode (make_persist_grower downgrades to the XLA
-        # emulation loudly); emu-vs-emu here would assert nothing
-        pytest.skip("pallas interpret mode cannot discharge the "
-                    "dynamic-grid split kernels on this jax (< 0.5)")
     X, y = _data(seed=97)
     n_small, rounds = 2048, ROUNDS   # >= the fused batch size, so the
     Xs, ys = X[:n_small], y[:n_small]   # persist driver engages

@@ -702,15 +702,8 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=2")
 import jax
-jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 2)
-except AttributeError:   # jax 0.4.x: the XLA_FLAGS above covers it
-    pass
-try:
-    jax.config.update("jax_cpu_collectives_implementation", "gloo")
-except AttributeError:
-    pass
+jax.config.update("jax_num_cpu_devices", 2)
+jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
 rank = int(sys.argv[1])
 port = sys.argv[2]

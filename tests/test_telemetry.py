@@ -334,3 +334,54 @@ def test_xplane_parse_smoke(tmp_path):
     planes = xplane.parse_xplane_dir(tdir)
     report = xplane.format_device_report(planes, iters=1)
     assert isinstance(report, str) and report
+
+
+# ---------------------------------------------------------------------------
+# which device, which grower: nothing is assumed and nothing is silent
+# ---------------------------------------------------------------------------
+
+class _Dev:
+    def __init__(self, kind):
+        self.device_kind = kind
+
+
+def test_detect_profile_matches_device_kind_or_raises(monkeypatch):
+    import jax
+    from lightgbm_tpu.telemetry import devices
+    monkeypatch.delenv("LGBTPU_DEVICE_PROFILE", raising=False)
+    # the v5e chip reports itself as "TPU v5 lite", not "v5e"
+    monkeypatch.setattr(jax, "devices", lambda *a: [_Dev("TPU v5 lite")])
+    assert devices.detect_profile().name == "v5e"
+    monkeypatch.setattr(jax, "devices", lambda *a: [_Dev("TPU v9 mega")])
+    with pytest.raises(ValueError, match="no device profile"):
+        devices.detect_profile()
+
+    def no_backend(*a):
+        raise RuntimeError("Unable to initialize backend")
+    monkeypatch.setattr(jax, "devices", no_backend)
+    with pytest.raises(RuntimeError):
+        devices.detect_profile()
+    # a machine without the accelerator names its device itself
+    monkeypatch.setenv("LGBTPU_DEVICE_PROFILE", "v4")
+    assert devices.detect_profile().name == "v4"
+
+
+@pytest.mark.parametrize("backend,want", [("tpu", True), ("cpu", False),
+                                          ("gpu", False)])
+def test_on_tpu_is_true_on_the_tpu_only(monkeypatch, backend, want):
+    import jax
+    from lightgbm_tpu.telemetry import devices
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert devices.on_tpu() is want
+
+
+def test_v1_fused_scan_counts_its_trees():
+    """A run that misses the persist path says so: the v1 grower inside
+    the fused k=16 lax.scan counts into v1_grow_trees like the
+    per-iteration v1 path does."""
+    X, y = _toy(n=600)
+    events.enable("timers")
+    lgb.train(dict(TOY_PARAMS), lgb.Dataset(X, y), 17, verbose_eval=False)
+    counts = events.counts_snapshot()
+    assert counts.get("tree_learner::v1_grow_trees") == 17, counts
+    assert "tree_learner::persist_scan_trees" not in counts, counts
