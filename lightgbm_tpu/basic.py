@@ -19,6 +19,7 @@ from .config import Config, params_to_config, _METRIC_ALIASES
 from .data.dataset import BinnedDataset
 from .metrics import create_metric
 from .objectives import create_objective
+from .telemetry import events as telemetry_events
 from .utils.log import LightGBMError, Log
 
 try:
@@ -121,6 +122,13 @@ class Dataset:
             raise LightGBMError(
                 "Cannot construct Dataset since the raw data has been freed; "
                 "set free_raw_data=False when creating the Dataset")
+        # run record: binning from inside (children io::ToFloat64(copy),
+        # io::FindBinAndGroup, io::PushMatrix(binning))
+        with telemetry_events.scope("io::Construct", category="setup",
+                                    always=True):
+            return self._construct()
+
+    def _construct(self) -> "Dataset":
         if isinstance(self.data, (str, bytes)):
             return self._construct_from_path(str(self.data))
         cfg = params_to_config(self.params)
@@ -151,8 +159,10 @@ class Dataset:
             if self.free_raw_data:
                 self.data = None
             return self
-        X, names, cat_idx = _data_to_2d(self.data, self.feature_name,
-                                        self.categorical_feature)
+        with telemetry_events.scope("io::ToFloat64(copy)", category="io",
+                                    always=True):
+            X, names, cat_idx = _data_to_2d(self.data, self.feature_name,
+                                            self.categorical_feature)
         self._inner = BinnedDataset.from_matrix(
             X, cfg,
             categorical_features=cat_idx,

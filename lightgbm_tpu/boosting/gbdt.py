@@ -65,6 +65,11 @@ class GBDT:
         self._pending: List[tuple] = []       # async fast-path device trees
         # (start_pos, stacked, shrink, init0s, mode) — mode 'gbdt'|'rf'
         self._pending_batches: List[tuple] = []
+        # run-record tags (telemetry.run_tags) of the newest fused launch,
+        # and by start_pos of the launch that grew each pending batch, so
+        # that a late materialize is put down to that launch
+        self._run_tags: dict = {}
+        self._batch_run_tags: Dict[int, dict] = {}
         # engine sets allow_batch when no before-iteration callbacks/evals
         # exist; then K iterations fuse into one jitted lax.scan dispatch
         self.allow_batch = False
@@ -399,7 +404,7 @@ class GBDT:
         return K if remaining >= K and K > 1 else 1
 
     @telemetry.timed("boosting::TrainMultiIterFast(launch)",
-                     category="boosting")
+                     category="boosting", always=True, new_launch=True)
     def _train_multi_iter_fast(self, k: int) -> bool:
         """K fused iterations (one device dispatch); see
         SerialTreeLearner.train_arrays_scan / train_arrays_scan_persist."""
@@ -440,6 +445,7 @@ class GBDT:
         start = len(self.models)
         self._pending_batches.append((start, stacked, self.shrinkage_rate,
                                       init0s, "gbdt"))
+        self._note_run_tags(start)
         self.models.extend([None] * (k * ntpi))
         self.iter += k
         self._batch_credit = k - 1
@@ -469,13 +475,16 @@ class GBDT:
         row-ordered score buffer (one device scatter; keeps the carry)."""
         if not getattr(self, "_persist_scores_dirty", False):
             return
-        sc = self.tree_learner.persist_finalize_scores()
-        if sc is not None:
-            if sc.ndim == 2:    # multiclass: [K, N] class-major
-                for c in range(sc.shape[0]):
-                    self.train_score._score[c] = sc[c]
-            else:
-                self.train_score._score[0] = sc
+        with telemetry.scope("boosting::SyncPersistScores",
+                             category="boosting", always=True,
+                             **self._run_tags):
+            sc = self.tree_learner.persist_finalize_scores()
+            if sc is not None:
+                if sc.ndim == 2:    # multiclass: [K, N] class-major
+                    for c in range(sc.shape[0]):
+                        self.train_score._score[c] = sc[c]
+                else:
+                    self.train_score._score[0] = sc
         self._persist_scores_dirty = False
 
     def _train_one_iter_fast(self) -> bool:
@@ -529,8 +538,6 @@ class GBDT:
             self._materialize_pending()
         return False
 
-    @telemetry.timed("boosting::MaterializePending(D2H+wait)",
-                     category="device_wait")
     def _materialize_pending(self) -> None:
         """Pull all pending device trees to host in one transfer; detect a
         no-split stop (reference stops and pops that iteration's trees —
@@ -539,12 +546,25 @@ class GBDT:
         self._sync_persist_scores()
         if not self._pending and not self._pending_batches:
             return
+        # fused batches are O(1) per launch and belong to the run record;
+        # the per-iteration path's pending trees stay behind the mode
+        with telemetry.scope("boosting::MaterializePending",
+                             category="boosting",
+                             always=bool(self._pending_batches),
+                             **self._run_tags):
+            self._materialize_now()
+
+    def _note_run_tags(self, start: int) -> None:
+        self._run_tags = self._batch_run_tags[start] = telemetry.run_tags()
+
+    def _materialize_now(self) -> None:
         import jax
 
-        def get_packed(pytree):
+        def get_packed(pytree, **record):
             """One device->host transfer for a whole pytree: bitcast every
             leaf to a flat u8 blob, concatenate, transfer once, re-split.
-            Each leaf transferred separately costs one D2H round trip."""
+            Each leaf transferred separately costs one D2H round trip.
+            `record`: how the transfer's span is recorded."""
             leaves, treedef = jax.tree.flatten(pytree)
             blobs = []
             for x in leaves:
@@ -553,8 +573,12 @@ class GBDT:
                 if x.dtype != jnp.uint8:
                     x = jax.lax.bitcast_convert_type(x, jnp.uint8)
                 blobs.append(x.reshape(-1))
-            blob = np.asarray(jnp.concatenate(blobs) if blobs else
-                              jnp.zeros((0,), jnp.uint8))
+            dev = (jnp.concatenate(blobs) if blobs else
+                   jnp.zeros((0,), jnp.uint8))
+            # the one transfer blocks until the device has grown the trees
+            with telemetry.scope("boosting::MaterializePending(D2H+wait)",
+                                 category="device_wait", **record):
+                blob = np.asarray(dev)
             out, off = [], 0
             for x in leaves:
                 nb = (int(np.prod(x.shape)) * x.dtype.itemsize
@@ -574,31 +598,13 @@ class GBDT:
         for start, stacked, shrink, init0s, bmode in self._pending_batches:
             if not isinstance(init0s, tuple):
                 init0s = (init0s,)
-            host_b = get_packed(stacked)
-            kb = int(host_b.num_leaves.shape[0])
-            for i in range(kb):
-                cls = i % ntpi
-                ha = jax.tree.map(lambda a, i=i: a[i], host_b)
-                tree = Tree.from_grower(ha, self.train_data)
-                if tree.num_leaves > 1:
-                    if bmode == "rf":
-                        # rf.hpp:103-160: no shrinkage, EVERY tree gets
-                        # the constant init-score bias (the device dance
-                        # already folded it into the payload scores)
-                        if abs(init0s[cls]) > K_EPSILON:
-                            tree.add_bias(init0s[cls])
-                    else:
-                        tree.shrink(shrink)
-                        if i < ntpi and abs(init0s[cls]) > K_EPSILON:
-                            tree.add_bias(init0s[cls])
-                else:
-                    tree = Tree(1)
-                    if bmode != "rf" and start + i < ntpi:
-                        # reference keeps the iteration-0 constant tree at
-                        # the boosted-from-average output (gbdt.cpp:396-411)
-                        tree.leaf_value[0] = init0s[cls]
-                self.models[start + i] = tree
+            tags = self._batch_run_tags.get(start, {})
+            host_b = get_packed(stacked, always=True, **tags)
+            with telemetry.scope("boosting::MaterializePending(host trees)",
+                                 category="boosting", always=True, **tags):
+                self._batch_to_trees(host_b, start, shrink, init0s, bmode)
         self._pending_batches = []
+        self._batch_run_tags = {}
         if not self._pending:
             self._truncate_if_stopped()
             return
@@ -643,6 +649,35 @@ class GBDT:
                 del self.models[cut:]
                 self.iter = len(self.models) // ntpi
         self._truncate_if_stopped()
+
+    def _batch_to_trees(self, host_b, start: int, shrink, init0s,
+                        bmode: str) -> None:
+        """Host trees of one fused batch, from its arrays on the host."""
+        import jax
+        ntpi = self.num_tree_per_iteration
+        kb = int(host_b.num_leaves.shape[0])
+        for i in range(kb):
+            cls = i % ntpi
+            ha = jax.tree.map(lambda a, i=i: a[i], host_b)
+            tree = Tree.from_grower(ha, self.train_data)
+            if tree.num_leaves > 1:
+                if bmode == "rf":
+                    # rf.hpp:103-160: no shrinkage, EVERY tree gets
+                    # the constant init-score bias (the device dance
+                    # already folded it into the payload scores)
+                    if abs(init0s[cls]) > K_EPSILON:
+                        tree.add_bias(init0s[cls])
+                else:
+                    tree.shrink(shrink)
+                    if i < ntpi and abs(init0s[cls]) > K_EPSILON:
+                        tree.add_bias(init0s[cls])
+            else:
+                tree = Tree(1)
+                if bmode != "rf" and start + i < ntpi:
+                    # reference keeps the iteration-0 constant tree at
+                    # the boosted-from-average output (gbdt.cpp:396-411)
+                    tree.leaf_value[0] = init0s[cls]
+            self.models[start + i] = tree
 
     def _truncate_if_stopped(self) -> None:
         """Batch entries can contain a 1-leaf tree (no-split stop

@@ -13,6 +13,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from ..models.tree import Tree
+from ..telemetry import events as telemetry
 from ..utils.log import Log
 from .gbdt import GBDT, K_EPSILON
 
@@ -76,6 +77,8 @@ class RF(GBDT):
             return False
         return self._train_multi_iter_fast(max(self._batch_size(), 1))
 
+    @telemetry.timed("boosting::TrainMultiIterFast(launch)",
+                     category="boosting", always=True, new_launch=True)
     def _train_multi_iter_fast(self, k: int) -> bool:
         learner = self.tree_learner
         fmasks = jnp.asarray(
@@ -103,6 +106,7 @@ class RF(GBDT):
         start = len(self.models)
         self._pending_batches.append(
             (start, stacked, 1.0, (float(self.init_scores[0]),), "rf"))
+        self._note_run_tags(start)
         self.models.extend([None] * k)
         self.iter += k
         self._batch_credit = k - 1

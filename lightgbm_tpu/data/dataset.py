@@ -25,7 +25,7 @@ from typing import Dict, List, Optional, Sequence
 import numpy as np
 
 from ..config import Config
-from ..utils import timer
+from ..telemetry import events as telemetry_events
 from ..utils.log import Log
 from .bin_mapper import BinMapper, BinType, kZeroThreshold
 
@@ -246,9 +246,11 @@ class BinnedDataset:
         cat_set = set(int(c) for c in categorical_features)
         sample = _sample_data(X, config.bin_construct_sample_cnt,
                               config.data_random_seed)
-        with timer.scope("io::FindBinAndGroup", category="io"):
+        with telemetry_events.scope("io::FindBinAndGroup", category="io",
+                                    always=True):
             ds._construct_from_sample(sample, n, config, cat_set)
-        with timer.scope("io::PushMatrix(binning)", category="io"):
+        with telemetry_events.scope("io::PushMatrix(binning)", category="io",
+                                    always=True):
             ds._push_matrix(X)
         return ds
 
@@ -370,7 +372,8 @@ class BinnedDataset:
                     for f in range(nf)]
             rows = [sc.indices[sc.indptr[f]:sc.indptr[f + 1]]
                     for f in range(nf)]
-            with timer.scope("io::FindBinAndGroup", category="io"):
+            with telemetry_events.scope("io::FindBinAndGroup",
+                                        category="io", always=True):
                 ds._construct_from_sample(SampleCols(vals, rows, total),
                                           n, config, cat_set)
         else:
@@ -380,7 +383,8 @@ class BinnedDataset:
             ds.groups = reference.groups
             ds._finish_layout_like(reference)
 
-        with timer.scope("io::PushSparse(binning)", category="io"):
+        with telemetry_events.scope("io::PushSparse(binning)",
+                                    category="io", always=True):
             G = len(ds.groups)
             chunk = max(1024, int(2 ** 25 / max(nf, 1)))
             if ds._choose_multival(config, X):

@@ -22,6 +22,7 @@ import numpy as np
 
 from . import callback
 from .basic import Booster, Dataset
+from .telemetry import events as telemetry_events
 from .utils.log import LightGBMError, Log
 
 _ROUND_COUNT_KEYS = (
@@ -361,7 +362,6 @@ def _train_distributed(params, train_set, num_boost_round, valid_sets,
                 resume_iter, ck_text, ck_meta, _man = found
                 es_resume = ck_meta.get("early_stopping")
                 ck_orig_init = int(ck_meta.get("n_init", 0))
-                from .telemetry import events as telemetry_events
                 telemetry_events.count("resilience::reshard_rows",
                                        len(idx), category="resilience")
         else:
@@ -476,6 +476,7 @@ def _train_distributed(params, train_set, num_boost_round, valid_sets,
         params=dict(params))
 
 
+@telemetry_events.train_root
 def train(params: Dict[str, Any], train_set: Dataset,
           num_boost_round: int = 100,
           valid_sets: Optional[List[Dataset]] = None,
@@ -497,7 +498,6 @@ def train(params: Dict[str, Any], train_set: Dataset,
     if num_boost_round <= 0:
         raise ValueError("num_boost_round should be greater than zero.")
     from .basic import params_to_config
-    from .telemetry import events as telemetry_events
     cfg0 = params_to_config(params)
     # configure before the num_machines split so tpu_telemetry/telemetry_out
     # params also activate the collective spans on the distributed path

@@ -298,7 +298,8 @@ def _pack_payload(binned: np.ndarray, labels: np.ndarray, n: int,
     return pay
 
 
-@telemetry.timed("ops::BuildPersistPayload(H2D)", category="ops")
+@telemetry.timed("ops::BuildPersistPayload(pack)", category="ops",
+                 always=True)
 def build_assets(dataset, labels: np.ndarray, C: int = 0,
                  CR: int = 16384, num_shards: int = 1,
                  num_scores: int = 1,
@@ -2108,16 +2109,22 @@ def make_scan_driver(gr, gc, k: int, grad_fn, grad_mode: str = "payload",
     def run_rf(pay, fmasks, bagw, aux, iters, params, bias):
         def body(pay, per):
             fmask, w_row, ax, it = per
-            pay = gr.fill_grad_const(pay, grad_fn, bias)
+            with jax.named_scope("fill_grad"):
+                pay = gr.fill_grad_const(pay, grad_fn, bias)
             gh2 = gr.grad_health(pay) if use_health else None
-            pay, bag_cnt = gr.apply_row_weights(pay, w_row)
-            pay, lstate, tree, nl, _root, stats = gr.grow(
-                pay, params, fmask, bag_cnt=bag_cnt, it=it)
+            with jax.named_scope("bag_transform"):
+                pay, bag_cnt = gr.apply_row_weights(pay, w_row)
+            with jax.named_scope("grow"):
+                pay, lstate, tree, nl, _root, stats = gr.grow(
+                    pay, params, fmask, bag_cnt=bag_cnt, it=it)
             if gh2 is not None:
                 stats = stats.at[STAT_HEALTH0 + H_NAN_GRAD].add(gh2[0]) \
                              .at[STAT_HEALTH0 + H_NAN_HESS].add(gh2[1])
-            pay = gr.apply_scores_avg(pay, lstate, nl, ax[0], ax[1], bias)
-            out = gr.to_tree_arrays(lstate, tree, nl)
+            with jax.named_scope("apply_scores"):
+                pay = gr.apply_scores_avg(pay, lstate, nl, ax[0], ax[1],
+                                          bias)
+            with jax.named_scope("to_tree_arrays"):
+                out = gr.to_tree_arrays(lstate, tree, nl)
             return pay, (out, stats)
         payK, (stacked, stats_k) = jax.lax.scan(
             body, pay, (fmasks, bagw, aux, iters), length=k)
@@ -2129,7 +2136,7 @@ def make_scan_driver(gr, gc, k: int, grad_fn, grad_mode: str = "payload",
             return telemetry.launch_wrapper(
                 jax.jit(run_rf, donate_argnums=(0,)),
                 "ops::persist_scan(launch)", category="ops",
-                histogram="ops::persist_program_wall", k=k)
+                histogram="ops::persist_program_wall", always=True, k=k)
         return run_rf
 
     def run(pay, fmasks, wkeys, iters, params, shrink, gargs):
@@ -2143,41 +2150,53 @@ def make_scan_driver(gr, gc, k: int, grad_fn, grad_mode: str = "payload",
                 outs = []
                 stats = jnp.zeros((STATS_LEN,), jnp.int32)
                 for cls in range(K):
-                    pay = gr.fill_grad_multi(pay, grad_fn, cls)
+                    with jax.named_scope("fill_grad"):
+                        pay = gr.fill_grad_multi(pay, grad_fn, cls)
                     stats = _add_grad_health(stats, pay)
                     bag_cnt = None
                     if bag_fn is not None:
                         # same window key for every class: one bag per
                         # iteration, as in the reference
-                        pay, bag_cnt = bag_fn(pay, wkey, it)
-                    pay, lstate, tree, nl, _root, tstats = gr.grow(
-                        pay, params, fmask[cls], bag_cnt=bag_cnt,
-                        it=it * K + cls)
+                        with jax.named_scope("bag_transform"):
+                            pay, bag_cnt = bag_fn(pay, wkey, it)
+                    with jax.named_scope("grow"):
+                        pay, lstate, tree, nl, _root, tstats = gr.grow(
+                            pay, params, fmask[cls], bag_cnt=bag_cnt,
+                            it=it * K + cls)
                     stats = stats + tstats
-                    pay = gr.apply_scores(pay, lstate, nl, shrink, cls)
-                    outs.append(gr.to_tree_arrays(lstate, tree, nl))
+                    with jax.named_scope("apply_scores"):
+                        pay = gr.apply_scores(pay, lstate, nl, shrink, cls)
+                    with jax.named_scope("to_tree_arrays"):
+                        outs.append(gr.to_tree_arrays(lstate, tree, nl))
                 out = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
                 return pay, (out, stats)
-            if grad_mode == "pos":
-                pay = gr.fill_grad_pos(pay, grad_fn, gargs)
-            elif grad_mode == "row":
-                pay = gr.fill_grad_row(pay, grad_fn, gargs)
-            else:
-                pay = gr.fill_grad(pay, grad_fn)
+            # device-side names for the trace and the HLO dump (metadata
+            # only: the program is the same)
+            with jax.named_scope("fill_grad"):
+                if grad_mode == "pos":
+                    pay = gr.fill_grad_pos(pay, grad_fn, gargs)
+                elif grad_mode == "row":
+                    pay = gr.fill_grad_row(pay, grad_fn, gargs)
+                else:
+                    pay = gr.fill_grad(pay, grad_fn)
             # probe the objective's RAW gradients (pre-bag: a bag zero
             # cannot launder an Inf into an unremarkable 0, and NaN*0
             # is NaN anyway)
             gh2 = gr.grad_health(pay) if use_health else None
             bag_cnt = None
             if bag_fn is not None:
-                pay, bag_cnt = bag_fn(pay, wkey, it)
-            pay, lstate, tree, nl, _root, stats = gr.grow(
-                pay, params, fmask, bag_cnt=bag_cnt, it=it)
+                with jax.named_scope("bag_transform"):
+                    pay, bag_cnt = bag_fn(pay, wkey, it)
+            with jax.named_scope("grow"):
+                pay, lstate, tree, nl, _root, stats = gr.grow(
+                    pay, params, fmask, bag_cnt=bag_cnt, it=it)
             if gh2 is not None:
                 stats = stats.at[STAT_HEALTH0 + H_NAN_GRAD].add(gh2[0]) \
                              .at[STAT_HEALTH0 + H_NAN_HESS].add(gh2[1])
-            pay = gr.apply_scores(pay, lstate, nl, shrink)
-            out = gr.to_tree_arrays(lstate, tree, nl)
+            with jax.named_scope("apply_scores"):
+                pay = gr.apply_scores(pay, lstate, nl, shrink)
+            with jax.named_scope("to_tree_arrays"):
+                out = gr.to_tree_arrays(lstate, tree, nl)
             return pay, (out, stats)
         payK, (stacked, stats_k) = jax.lax.scan(
             body, pay, (fmasks, wkeys, iters), length=k)
@@ -2216,5 +2235,5 @@ def make_scan_driver(gr, gc, k: int, grad_fn, grad_mode: str = "payload",
         return telemetry.launch_wrapper(
             jax.jit(run, donate_argnums=(0,)),
             "ops::persist_scan(launch)", category="ops",
-            histogram="ops::persist_program_wall", k=k)
+            histogram="ops::persist_program_wall", always=True, k=k)
     return run

@@ -312,7 +312,7 @@ class DataParallelTreeLearner(SerialTreeLearner):
             driver = telemetry.launch_wrapper(
                 jax.jit(smapped, donate_argnums=(0,)),
                 "collective::persist_scan(launch)", category="collective",
-                shards=S, mode=gc.parallel_mode, k=k)
+                always=True, shards=S, mode=gc.parallel_mode, k=k)
             cache[dkey] = driver
         return assets, wrapper, driver
 

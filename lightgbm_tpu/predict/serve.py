@@ -86,9 +86,8 @@ class BatchServer:
         self.devices = list(devices) if devices is not None \
             else list(jax.local_devices())
         self._mesh = build_mesh(self.devices)
-        # instance-local serving stats: stats() must work (and the bench
-        # must report true compile counts) even with telemetry off, where
-        # events.count() is a no-op
+        # instance-local serving stats: stats() reports this server's own
+        # counts (events.count() is process-wide)
         self._compiled_buckets = set()
         self._bucket_hits = 0
         self._sharded_batches = 0

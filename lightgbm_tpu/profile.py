@@ -1,9 +1,10 @@
 """`python -m lightgbm_tpu.profile` — op-level device profile of training.
 
 Traces N boosting iterations on the real chip with the jax profiler, then
-prints device time per XLA op name via the reusable xplane parser
-(:mod:`lightgbm_tpu.telemetry.xplane`). The old top-level ``prof_trace.py``
-dev script is now a thin wrapper over this entry point.
+prints device time per XLA op name, the device's idle gaps by program span
+and the program's ``lgbm:`` spans as the profiler saw them, via the reusable
+xplane reader (:mod:`lightgbm_tpu.telemetry.xplane`, on
+``jax.profiler.ProfileData``: nothing else has to be installed).
 
 Usage: python -m lightgbm_tpu.profile [--shape NAME] [rows] [iters]
                                       [key=value ...]
@@ -279,13 +280,8 @@ def main(argv=None) -> int:
               file=sys.stderr)
 
     if use_xplane:
-        try:
-            planes = xplane.parse_xplane_dir(tdir)
-        except ImportError as exc:
-            print("xplane proto bindings unavailable (%s); raw trace left "
-                  "in %s" % (exc, tdir), file=sys.stderr)
-            return 1
-        print(xplane.format_device_report(planes, iters=iters))
+        print(xplane.format_device_report(xplane.parse_xplane_dir(tdir),
+                                          iters=iters))
     written = maybe_export(out) if out else None
     if written:
         print("host-side spans: %s ; metrics: %s" % written, file=sys.stderr)

@@ -12,17 +12,31 @@ see :mod:`lightgbm_tpu.telemetry.xplane`.
 
 Three modes:
 
-  * ``OFF``    (default) — every entry point is a no-op behind one int
-    compare; nothing is recorded, nothing prints at exit, and no extra
-    ``block_until_ready`` is inserted anywhere.
-  * ``TIMERS`` — counters only: per-name accumulated seconds + hit counts
-    (the TIMETAG-style report), no per-event storage.
-  * ``TRACE``  — counters plus a bounded in-memory timeline of span events
+  * ``OFF``    (default) — the RUN RECORD only: unit-less counters
+    (:func:`count`) and the spans marked ``always=True``, which occur
+    O(1) times per ``lgb.train`` or per fused launch (set-up steps, launch
+    dispatches, compile events). Each such span accumulates seconds, self
+    seconds and hits like any other and leaves one entry in a bounded ring
+    (:data:`RING_EVENTS`, :func:`ring_snapshot`) carrying the identifiers
+    of the ``engine.train`` call and of the fused launch it belongs to.
+    Every other span is a no-op behind one int compare, nothing prints at
+    exit, nothing blocks (no ``block_until_ready``, ``sync_value`` and
+    :func:`device_wait` do nothing) and no compiled program changes.
+  * ``TIMERS`` — every span: per-name accumulated seconds + hit counts
+    (the TIMETAG-style report), no per-event storage beyond the ring.
+  * ``TRACE``  — plus a bounded in-memory timeline of span events
     (begin timestamp, duration, thread, nesting parent, tags) that
     exports to ``chrome://tracing`` JSON via :mod:`export`.
 
-Thread safety: one process-wide lock guards the counter tables and the
-event buffer; the per-thread nesting stack lives in thread-local storage.
+Whenever a span records, in any mode, it also enters a
+``jax.profiler.TraceAnnotation("lgbm:" + name)``: free without a profiler
+session, and with one (``jax.profiler.trace``, ``python -m
+lightgbm_tpu.profile``) the span lands on the host plane of the same
+``.xplane.pb`` as the device's operations, on one clock.
+
+Thread safety: one process-wide lock guards the counter tables, the ring
+and the event buffer; the per-thread nesting stack lives in thread-local
+storage.
 """
 from __future__ import annotations
 
@@ -32,8 +46,10 @@ import functools
 import os
 import threading
 import time
-from collections import defaultdict
+from collections import defaultdict, deque
 from typing import Callable, Dict, List, Optional, Tuple
+
+import jax
 
 OFF, TIMERS, TRACE = 0, 1, 2
 _MODE_NAMES = {"off": OFF, "timers": TIMERS, "trace": TRACE,
@@ -52,11 +68,19 @@ _counts: Dict[str, float] = defaultdict(float)
 _count_cat: Dict[str, str] = {}
 _events: List[dict] = []
 _dropped = 0
+# the run record's ring: one entry per `always` span, oldest dropped first
+RING_EVENTS = 4096
+_ring: deque = deque(maxlen=RING_EVENTS)
+# identifiers the ring entries carry: engine.train calls are numbered from 1
+# over the life of the process (reset() leaves the numbering alone), the
+# fused launches of one train from 0; 0 / None outside a train
+_train_seq = 0
+_cur_train = 0
+_cur_launch: Optional[int] = None
 _iter_records: List[dict] = []
 _tls = threading.local()
 _out_path: Optional[str] = None
 _exported = False
-_compile_hook_on = False
 # flight-recorder sinks (telemetry/flight.py): None when disarmed, so the
 # hot path pays one is-None check; armed, every span exit / counter bump
 # also lands in the crash ring buffer regardless of TRACE vs TIMERS mode
@@ -107,7 +131,6 @@ def enable(new_mode="timers") -> None:
         new_mode = _MODE_NAMES.get(new_mode.strip().lower(), TIMERS)
     _mode = max(int(new_mode), TIMERS)
     _mode_source = "api"
-    _install_compile_hook()
 
 
 def disable() -> None:
@@ -183,6 +206,7 @@ def reset() -> None:
         _counts.clear()
         _count_cat.clear()
         del _events[:]
+        _ring.clear()
         del _iter_records[:]
         _dropped = 0
         _exported = False
@@ -209,9 +233,9 @@ def add(name: str, seconds: float, category: str = "misc") -> None:
 
 
 def count(name: str, inc: float = 1.0, category: str = "count") -> None:
-    """Unit-less monotonic counter (leaf counts, recompiles, drops...)."""
-    if _mode == OFF:
-        return
+    """Unit-less monotonic counter (leaf counts, recompiles, drops...).
+    Part of the run record: recorded in every mode, so call it per launch,
+    per train or per request, never per split or per row."""
     with _lock:
         _counts[name] += inc
         _count_cat.setdefault(name, category)
@@ -241,44 +265,122 @@ def _stack() -> list:
     return st
 
 
-def _record_event(name: str, category: str, t0: float, t1: float,
-                  parent: Optional[str], tags: Optional[dict]) -> None:
-    global _dropped
-    ev = {"name": name, "cat": category, "ts": t0 + _EPOCH,
-          "dur": t1 - t0, "tid": threading.get_ident()}
-    if parent is not None:
-        ev["parent"] = parent
-    if tags:
-        ev["args"] = tags
+# ---------------------------------------------------------------------------
+# run identifiers
+# ---------------------------------------------------------------------------
+
+def begin_launch() -> int:
+    """Number the fused launch that starts now (from 0 within the train in
+    flight); every span until the next call carries it."""
+    global _cur_launch
     with _lock:
-        if len(_events) < MAX_EVENTS:
-            _events.append(ev)
-        else:
-            _dropped += 1
+        _cur_launch = 0 if _cur_launch is None else _cur_launch + 1
+        return _cur_launch
+
+
+def run_tags() -> dict:
+    """The identifiers a span opened now would carry, as the ``train=`` /
+    ``launch=`` tags :func:`scope` takes. A booster keeps them to tag what
+    it does for that launch after ``engine.train`` returned."""
+    return {"train": _cur_train, "launch": _cur_launch}  # guarded-by: GIL
+
+
+def train_root(fn):
+    """Decorator for ``engine.train``: numbers the call and runs it under
+    the ``engine::train`` span, the root of one job's run record."""
+    @functools.wraps(fn)
+    def wrap(*a, **k):
+        global _train_seq, _cur_train, _cur_launch
+        with _lock:
+            outer = (_cur_train, _cur_launch)
+            _train_seq += 1
+            _cur_train, _cur_launch = _train_seq, None
+        try:
+            with scope("engine::train", category="setup", always=True):
+                return fn(*a, **k)
+        finally:
+            with _lock:
+                _cur_train, _cur_launch = outer
+    return wrap
+
+
+def _span_ids(st: list, tags: dict) -> Tuple[int, Optional[int]]:
+    """Explicit ``train=`` / ``launch=`` tags win, then the train in flight,
+    then the enclosing span (a compile under a booster's late
+    ``MaterializePending`` belongs to that booster's launch)."""
+    if _cur_train:
+        train, launch = _cur_train, _cur_launch     # guarded-by: GIL
+    elif st:
+        train, launch = st[-1][2], st[-1][3]
+    else:
+        train, launch = 0, None
+    return tags.pop("train", train), tags.pop("launch", launch)
+
+
+def _record_span(name: str, category: str, t0: float, elapsed: float,
+                 self_s: float, parent: Optional[str], train: int,
+                 launch: Optional[int], tags: Optional[dict],
+                 always: bool) -> None:
+    """Fold one finished span into the tables, the ring (`always` spans)
+    and the TRACE timeline."""
+    global _dropped
+    with _lock:
+        _acc[name] += elapsed
+        _acc_self[name] += self_s
+        _cnt[name] += 1
+        _cat.setdefault(name, category)
+        if not always and _mode != TRACE:
+            return
+        ev = {"name": name, "cat": category, "ts": t0 + _EPOCH,
+              "dur": elapsed, "self": self_s,
+              "tid": threading.get_ident(), "train": train}
+        if launch is not None:
+            ev["launch"] = launch
+        if parent is not None:
+            ev["parent"] = parent
+        if tags:
+            ev["args"] = tags
+        if always:
+            _ring.append(ev)
+        if _mode == TRACE:
+            if len(_events) < MAX_EVENTS:
+                _events.append(ev)
+            else:
+                _dropped += 1
 
 
 @contextlib.contextmanager
-def scope(name: str, category: str = "misc", sync_value=None, **tags):
+def scope(name: str, category: str = "misc", sync_value=None,
+          always: bool = False, **tags):
     """Accumulate the wall time of the enclosed block under `name`.
+
+    ``always=True`` puts the span in the run record: it is recorded with
+    telemetry OFF too and leaves a ring entry. Only for spans that occur
+    O(1) times per ``lgb.train`` or per fused launch.
 
     When `sync_value` is a callable, it is invoked on exit and its result
     passed to jax.block_until_ready before the clock stops — use for
-    scopes whose cost is a device computation. In TRACE mode the span is
-    also appended to the event timeline with its nesting parent.
+    scopes whose cost is a device computation (never with telemetry OFF:
+    the run record does not block). In TRACE mode the span is also
+    appended to the event timeline with its nesting parent.
     """
-    if _mode == OFF:
+    if _mode == OFF and not always:
         yield
         return
     st = _stack()
     parent = st[-1][0] if st else None
-    st.append([name, 0.0])   # [name, accumulated child-span seconds]
+    train, launch = _span_ids(st, tags)
+    # [name, accumulated child-span seconds, train, launch]
+    st.append([name, 0.0, train, launch])
+    note = {"train": train} if launch is None \
+        else {"train": train, "launch": launch}
     t0 = time.perf_counter()
     try:
-        yield
+        with jax.profiler.TraceAnnotation("lgbm:" + name, **note):
+            yield
     finally:
-        if sync_value is not None:
+        if sync_value is not None and _mode != OFF:
             try:
-                import jax
                 jax.block_until_ready(sync_value())
             except Exception:
                 pass
@@ -287,13 +389,8 @@ def scope(name: str, category: str = "misc", sync_value=None, **tags):
         elapsed = t1 - t0
         if st:
             st[-1][1] += elapsed
-        with _lock:
-            _acc[name] += elapsed
-            _acc_self[name] += elapsed - entry[1]
-            _cnt[name] += 1
-            _cat.setdefault(name, category)
-        if _mode == TRACE:
-            _record_event(name, category, t0, t1, parent, tags or None)
+        _record_span(name, category, t0, elapsed, elapsed - entry[1],
+                     parent, train, launch, tags or None, always)
         # same single-snapshot discipline as count(): never two reads
         # of the global sink around a call
         sink = _flight_span       # guarded-by: GIL
@@ -301,14 +398,19 @@ def scope(name: str, category: str = "misc", sync_value=None, **tags):
             sink(name, category, t0 + _EPOCH, elapsed)
 
 
-def timed(name: str, category: str = "misc") -> Callable:
-    """Decorator form (the FunctionTimer analog)."""
+def timed(name: str, category: str = "misc", always: bool = False,
+          new_launch: bool = False) -> Callable:
+    """Decorator form (the FunctionTimer analog). ``new_launch`` marks the
+    function that issues one fused launch: every call numbers the next
+    launch (:func:`begin_launch`) before its span opens."""
     def deco(fn):
         @functools.wraps(fn)
         def wrap(*a, **k):
-            if _mode == OFF:
+            if new_launch:
+                begin_launch()
+            if _mode == OFF and not always:
                 return fn(*a, **k)
-            with scope(name, category=category):
+            with scope(name, category=category, always=always):
                 return fn(*a, **k)
         return wrap
     return deco
@@ -324,8 +426,10 @@ def _is_tracer(x) -> bool:
 
 def launch_wrapper(fn, name: str, category: str = "ops",
                    tracer_arg: Optional[int] = None,
-                   histogram: Optional[str] = None, **tags) -> Callable:
-    """Wrap a jitted callable in a launch-cost span (OFF: one int compare).
+                   histogram: Optional[str] = None,
+                   always: bool = False, **tags) -> Callable:
+    """Wrap a jitted callable in a launch-cost span (OFF: one int compare,
+    unless ``always`` puts it in the run record).
 
     Dispatch is async, so the span measures LAUNCH cost; device time shows
     up at the next sync point or the xplane profile. When ``tracer_arg``
@@ -340,7 +444,7 @@ def launch_wrapper(fn, name: str, category: str = "ops",
     totals — the persist level-program driver records here."""
     @functools.wraps(fn)
     def wrapper(*a, **k):
-        if _mode == OFF:
+        if _mode == OFF and not always:
             return fn(*a, **k)
         n = name
         traced = False
@@ -349,7 +453,7 @@ def launch_wrapper(fn, name: str, category: str = "ops",
             n += "(trace)" if traced else "(launch)"
         t0 = time.perf_counter()
         try:
-            with scope(n, category=category, **tags):
+            with scope(n, category=category, always=always, **tags):
                 return fn(*a, **k)
         finally:
             if histogram is not None and not traced:
@@ -368,7 +472,6 @@ def device_wait(name: str, value, **tags):
         return value
     with scope(name, category="device_wait", **tags):
         try:
-            import jax
             jax.block_until_ready(value)
         except Exception:
             pass
@@ -411,8 +514,8 @@ def category_totals() -> Dict[str, float]:
     (boosting::TrainOneIter encloses tree_learner:: and ops:: spans; the
     inclusive per-name table would count the same second up to 4 times
     across categories), so these values near-partition the instrumented
-    wall time. Exception: ``compile`` rides jax.monitoring callbacks that
-    fire *inside* host spans, so it can still overlap the host categories.
+    wall time; the ``compile`` spans made from jax.monitoring events count
+    as children of the host span they fired in, like any nested span.
     The per-name tables (:func:`snapshot` / :func:`snapshot_full`) stay
     inclusive, matching the reference Timer semantics."""
     out: Dict[str, float] = defaultdict(float)
@@ -423,8 +526,18 @@ def category_totals() -> Dict[str, float]:
 
 
 def events_snapshot() -> List[dict]:
+    """The TRACE-mode timeline (empty in the other modes)."""
     with _lock:
         return list(_events)
+
+
+def ring_snapshot() -> List[dict]:
+    """The run record's ring, oldest first: one dict per `always` span with
+    ``name``, ``cat``, ``ts`` (unix seconds), ``dur``, ``self`` (``dur``
+    less its child spans), ``tid``, ``train``, and where they apply
+    ``launch``, ``parent`` and ``args``. Filled in every mode."""
+    with _lock:
+        return list(_ring)
 
 
 def dropped_events() -> int:
@@ -437,41 +550,80 @@ def iteration_records() -> List[dict]:
 
 
 # ---------------------------------------------------------------------------
-# XLA compile tracking (recompile counts for the TrainingMonitor)
+# XLA compile tracking: which step compiled, and for how long
 # ---------------------------------------------------------------------------
 
+_JAX_DURATIONS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax::jaxpr_trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax::lower",
+    "/jax/core/compile/backend_compile_duration": "jax::backend_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "jax::cache_load",
+}
+_JAX_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "jax::cache_hits",
+    "/jax/compilation_cache/cache_misses": "jax::cache_misses",
+    "/jax/compilation_cache/compile_requests_use_cache":
+        "jax::compile_requests",
+}
+# finished jax events a thread keeps until an enclosing one absorbs them
+_JAX_DONE_MAX = 1024
+# shorter events are not recorded: nearly all are the traces of the jitted
+# jax.numpy functions a trace calls (a thousand in a tiny train, a tenth of
+# its trace seconds), whose time stays in the enclosing trace's own
+_JAX_MIN_S = 1e-3
+
+
 def _on_jax_duration(event: str, duration: float, **kw) -> None:
-    if _mode == OFF:
+    """One jax.monitoring duration event -> one run-record span that ends
+    now, under the span open on this thread. JAX reports an event when it
+    ends, and a trace reports the traces of the jitted functions it calls
+    before itself: those become its children, so self seconds add up to
+    wall seconds. ``jax::backend_compile`` wraps the persistent-cache
+    look-up; a hit is recorded as ``jax::cache_load`` alone."""
+    name = _JAX_DURATIONS.get(event)
+    if name is None:
         return
-    if "backend_compile" in event:
-        with _lock:
-            _acc["jax::backend_compile"] += duration
-            _acc_self["jax::backend_compile"] += duration
-            _cnt["jax::backend_compile"] += 1
-            _cat.setdefault("jax::backend_compile", "compile")
-            _counts["jax::backend_compile"] += 1.0
-            _count_cat.setdefault("jax::backend_compile", "compile")
+    if name == "jax::cache_load":
+        _tls.cache_hit = True
+    elif name == "jax::backend_compile" and getattr(_tls, "cache_hit", False):
+        _tls.cache_hit = False
+        return
+    if duration < _JAX_MIN_S:
+        return
+    t0 = time.perf_counter() - duration
+    done = getattr(_tls, "jax_done", None)
+    if done is None:
+        done = _tls.jax_done = deque(maxlen=_JAX_DONE_MAX)
+    inner = 0.0
+    while done and done[-1][0] >= t0:
+        inner += done.pop()[1]
+    done.append((t0, duration))
+    self_s = max(duration - inner, 0.0)
+    st = _stack()
+    parent = None
+    if st:
+        parent = st[-1][0]
+        st[-1][1] += self_s
+    tags = {}
+    train, launch = _span_ids(st, tags)
+    if kw.get("fun_name"):
+        tags["fun"] = str(kw["fun_name"])
+    _record_span(name, "compile", t0, duration, self_s, parent, train,
+                 launch, tags or None, True)
+    if name == "jax::backend_compile":
+        # the TrainingMonitor's per-iteration recompile count
+        count(name, category="compile")
 
 
-def _install_compile_hook() -> None:
-    """Count XLA backend compiles via jax.monitoring (idempotent; the
-    listener itself no-ops when telemetry is OFF)."""
-    global _compile_hook_on
-    with _lock:
-        # check-then-set under the lock: two threads enabling telemetry
-        # at once must not double-register the jax listener
-        if _compile_hook_on:
-            return
-        _compile_hook_on = True
-    try:
-        import jax
-        jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
-    except Exception:  # pragma: no cover - very old jax
-        pass
+def _on_jax_event(event: str, **kw) -> None:
+    name = _JAX_EVENTS.get(event)
+    if name is not None:
+        count(name, category="compile")
 
 
-if _mode != OFF:
-    _install_compile_hook()
+# installed once, at import, in every mode
+jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+jax.monitoring.register_event_listener(_on_jax_event)
 
 
 # ---------------------------------------------------------------------------

@@ -25,12 +25,12 @@ The ring is capacity-bounded (not time-bounded): 4096 entries comfortably
 cover the last seconds of any instrumented run while keeping the dump
 small enough to write inside a dying process.
 
-Telemetry-OFF caveat: the span/counter sinks and the histograms live
-behind the telemetry mode gate (the pinned ``tpu_telemetry=off`` zero-
-overhead contract), so an armed-but-telemetry-off run (fault plan or
-multihost with default params) dumps only the EXPLICIT :func:`note`
-events — recent collectives, retries, timeouts, the kill — with empty
-span/counter/histogram tables. That is still a real postmortem (what
+Telemetry-OFF caveat: most spans and the histograms live behind the
+telemetry mode gate, so an armed-but-telemetry-off run (fault plan or
+multihost with default params) dumps the EXPLICIT :func:`note` events —
+recent collectives, retries, timeouts, the kill — plus the run record
+(counters and the O(1)-per-launch spans, which are on in every mode),
+with empty histogram tables. That is still a real postmortem (what
 died, on which collective, when); enable ``tpu_telemetry=timers`` for
 the full record.
 """

@@ -549,11 +549,12 @@ class SerialTreeLearner:
     def _persist_health_mode(self) -> bool:
         """tpu_numerics_stats: 'auto' accumulates the device-side
         numerics health vector (NaN/Inf counters + split-margin
-        histogram) in the persist scan carry WHEN telemetry is on —
-        with telemetry off the flush would drop everything, so the
-        default run pays nothing (the off-mode zero-overhead
-        contract). 'on'/'force' accumulates regardless (the flush
-        still gates on telemetry); 'off' zeroes it."""
+        histogram) in the persist scan carry WHEN telemetry is on, so
+        the default run's compiled program carries no probe (the
+        off-mode contract: the run record changes no program).
+        'on'/'force' accumulates regardless (the flush's counters are
+        recorded in every mode, its histogram with telemetry on);
+        'off' zeroes it."""
         opt = str(getattr(self.config, "tpu_numerics_stats",
                           "auto")).lower()
         if opt in ("off", "false", "0"):
@@ -599,39 +600,57 @@ class SerialTreeLearner:
         stat_from_scan = bag_spec[0] != "none" or mode == "rf"
         gkey = ("grower", K, use_w_row, self.grow_config,
                 stat_from_scan, kernel_impl, level_mode, health)
-        gr = cache.get(gkey)
-        if gr is None:
-            gr = make_persist_grower(assets, self.meta, self.grow_config,
-                                     interpret=interpret,
-                                     kernel_impl=kernel_impl,
-                                     stat_from_scan=stat_from_scan,
-                                     fix=self.fix, level_mode=level_mode,
-                                     health=health)
-            if assets.efb[5]:          # bundled: block-scan fast path
-                telemetry.count("tree_learner::persist_bundle_blockscan",
-                                category="tree_learner")
-            cache[gkey] = gr
         dkey = ("driver", K, use_w_row, k, self.grow_config,
                 objective.static_fingerprint(), bag_spec, kernel_impl,
                 level_mode, health, mode)
-        driver = cache.get(dkey)
-        if driver is None:
-            bag_fn = (make_bag_transform(bag_spec, assets.geometry)
-                      if stat_from_scan else None)
-            # the objective's ONE capability surface hands the driver
-            # both the fill contract and the kernel
-            gmode, gfn = objective.device_gradients()
-            if mode == "rf":
-                driver = make_scan_driver(gr, self.grow_config, k, gfn,
-                                          mode="rf")
-            elif K > 1:
-                driver = make_scan_driver(gr, self.grow_config, k, gfn,
-                                          bag_fn=bag_fn)
-            else:
-                driver = make_scan_driver(gr, self.grow_config, k, gfn,
-                                          grad_mode=gmode, bag_fn=bag_fn)
-            cache[dkey] = driver
+        gr, driver = cache.get(gkey), cache.get(dkey)
+        if gr is not None and driver is not None:
+            return assets, gr, driver
+        # run record: host closure building, apart from the payload pack
+        with telemetry.scope("tree_learner::PersistBuild(trace)",
+                             category="setup", always=True):
+            if gr is None:
+                gr = make_persist_grower(assets, self.meta,
+                                         self.grow_config,
+                                         interpret=interpret,
+                                         kernel_impl=kernel_impl,
+                                         stat_from_scan=stat_from_scan,
+                                         fix=self.fix,
+                                         level_mode=level_mode,
+                                         health=health)
+                if assets.efb[5]:          # bundled: block-scan fast path
+                    telemetry.count(
+                        "tree_learner::persist_bundle_blockscan",
+                        category="tree_learner")
+                cache[gkey] = gr
+            if driver is None:
+                bag_fn = (make_bag_transform(bag_spec, assets.geometry)
+                          if stat_from_scan else None)
+                # the objective's ONE capability surface hands the driver
+                # both the fill contract and the kernel
+                gmode, gfn = objective.device_gradients()
+                if mode == "rf":
+                    driver = make_scan_driver(gr, self.grow_config, k, gfn,
+                                              mode="rf")
+                elif K > 1:
+                    driver = make_scan_driver(gr, self.grow_config, k, gfn,
+                                              bag_fn=bag_fn)
+                else:
+                    driver = make_scan_driver(gr, self.grow_config, k, gfn,
+                                              grad_mode=gmode,
+                                              bag_fn=bag_fn)
+                cache[dkey] = driver
         return assets, gr, driver
+
+    @staticmethod
+    def _persist_init_carry(gr, assets, score0):
+        """The first program that takes the host payload. The span is the
+        dispatch's wall (the staging copy of ``pay0`` and that program's
+        compile or cache load); without a block its end is the dispatch's
+        end, not the copy's."""
+        with telemetry.scope("tree_learner::InitCarry(H2D launch)",
+                             category="setup", always=True):
+            return gr.init_carry(assets.pay0, jnp.asarray(score0))
 
     @telemetry.timed("tree_learner::TrainScanPersist(launch)",
                      category="tree_learner")
@@ -646,7 +665,7 @@ class SerialTreeLearner:
         assets, gr, driver = self._persist_cached(objective, k, bag_spec)
         pay = getattr(self, "_persist_carry", None)
         if pay is None:
-            pay = gr.init_carry(assets.pay0, jnp.asarray(score0))
+            pay = self._persist_init_carry(gr, assets, score0)
         pay, stacked, stats = driver(pay, jnp.asarray(fmasks),
                                      jnp.asarray(wkeys, jnp.uint32),
                                      jnp.asarray(iters, jnp.int32),
@@ -682,7 +701,7 @@ class SerialTreeLearner:
                                                   mode="rf")
         pay = getattr(self, "_persist_carry", None)
         if pay is None:
-            pay = gr.init_carry(assets.pay0, jnp.asarray(score0))
+            pay = self._persist_init_carry(gr, assets, score0)
         pay, stacked, stats = driver(pay, jnp.asarray(fmasks),
                                      jnp.asarray(bagw, jnp.float32),
                                      jnp.asarray(aux, jnp.float64),
@@ -738,7 +757,9 @@ class SerialTreeLearner:
         # wait is pipeline time (the callers' device_wait spans own it),
         # not sentinel cost; only the host-side conversion below is the
         # sentinel's bill, and that is what the < 2% pin measures
-        v = np.asarray(jax.device_get(st))
+        with telemetry.scope("tree_learner::FlushStats(D2H+wait)",
+                             category="device_wait", always=True):
+            v = np.asarray(jax.device_get(st))
         with telemetry.scope("numerics::flush", category="numerics"):
             if v[0]:
                 telemetry.count("tree_learner::level_programs",
@@ -781,9 +802,11 @@ class SerialTreeLearner:
         pay = getattr(self, "_persist_carry", None)
         if pay is None:
             return None
-        self.flush_level_stats()
-        gr = self._persist_gr
-        return gr.finalize_scores(pay).astype(jnp.float64)
+        with telemetry.scope("tree_learner::FinalizeScores(launch)",
+                             category="launch", always=True):
+            self.flush_level_stats()
+            gr = self._persist_gr
+            return gr.finalize_scores(pay).astype(jnp.float64)
 
     @telemetry.timed("tree_learner::TrainScan(launch)",
                      category="tree_learner")

@@ -43,20 +43,49 @@ TOY_PARAMS = {"objective": "binary", "num_leaves": 7, "min_data_in_leaf": 5,
 # ---------------------------------------------------------------------------
 
 def test_disabled_by_default_noop():
+    """OFF is the run record only: mode-gated spans, the timeline and the
+    iteration records stay empty and nothing blocks; counters and the
+    `always` spans are there, the latter in a bounded ring."""
     assert events.mode() == events.OFF
     assert not events.enabled() and not timer.enabled()
     with events.scope("x", category="misc"):
         pass
     events.add("y", 1.0)
-    events.count("z")
     events.record_iteration({"iteration": 0})
     assert events.snapshot() == {}
-    assert events.counts_snapshot() == {}
     assert events.events_snapshot() == []
+    assert events.ring_snapshot() == []
     assert events.iteration_records() == []
     # device_wait must NOT block (and must hand the value back) when off
     sentinel = object()
     assert events.device_wait("w", sentinel) is sentinel
+    # the run record: counters, and the spans that ask for it
+    events.count("z")
+    assert events.counts_snapshot() == {"z": 1.0}
+    blocked = []
+    with events.scope("outer", category="setup", always=True,
+                      sync_value=lambda: blocked.append(1)):
+        with events.scope("gated"):
+            pass
+        with events.scope("inner", always=True, k=16):
+            pass
+    assert blocked == [], "an always span blocked with telemetry off"
+    assert set(events.snapshot()) == {"outer", "inner"}
+    ring = events.ring_snapshot()
+    assert [e["name"] for e in ring] == ["inner", "outer"]
+    inner, outer = ring
+    assert inner["parent"] == "outer" and "parent" not in outer
+    assert inner["args"] == {"k": 16} and outer["cat"] == "setup"
+    assert {"ts", "dur", "self", "tid", "train"} <= set(inner)
+    assert outer["self"] <= outer["dur"] - inner["dur"] + 1e-9
+    assert events.events_snapshot() == []
+    for _ in range(events.RING_EVENTS + 10):
+        with events.scope("many", always=True):
+            pass
+    assert len(events.ring_snapshot()) == events.RING_EVENTS
+    assert events.snapshot()["many"][1] == events.RING_EVENTS + 10
+    events.reset()
+    assert events.ring_snapshot() == [] and events.counts_snapshot() == {}
 
 
 def test_atexit_hook_silent_when_disabled(capsys):
@@ -102,19 +131,29 @@ def test_noop_scope_overhead_is_tiny():
 
 
 def test_training_off_records_nothing_1k_rows():
-    """tpu_telemetry=off (default): a 1k-row run leaves the registry empty
+    """tpu_telemetry=off (default): a 1k-row run leaves the run record and
+    nothing else (no mode-gated span, no timeline, no iteration record),
     and a warm re-run stays fast (coarse per-iteration overhead guard)."""
     X, y = _toy(n=1000)
     ds = lgb.Dataset(X, y)
     lgb.train(dict(TOY_PARAMS), ds, 8, verbose_eval=False)
-    assert events.snapshot() == {}
+    ring_names = {e["name"] for e in events.ring_snapshot()}
+    assert "engine::train" in ring_names and "io::Construct" in ring_names
+    # every accumulated name is a run-record span: nothing mode-gated
+    # (boosting::TrainOneIter, tree_learner::Train(launch), ...) ran
+    assert set(events.snapshot()) <= ring_names
+    assert "boosting::TrainOneIter" not in events.snapshot()
     assert events.events_snapshot() == []
+    assert events.iteration_records() == []
+    assert events.counts_snapshot()["tree_learner::v1_grow_trees"] == 8
     t0 = time.perf_counter()
     ds2 = lgb.Dataset(X, y)
     bst = lgb.train(dict(TOY_PARAMS), ds2, 8, verbose_eval=False)
     bst._booster._materialize_pending()
     warm = time.perf_counter() - t0
-    assert events.snapshot() == {}
+    assert "boosting::TrainOneIter" not in events.snapshot()
+    assert events.events_snapshot() == []
+    assert len(events.ring_snapshot()) <= events.RING_EVENTS
     assert warm < 30.0, "warm 1k-row 8-iter run took %.1fs" % warm
 
 
@@ -130,6 +169,145 @@ def test_off_vs_timers_identical_model():
     p_on = bst_on.predict(X)
     np.testing.assert_array_equal(p_off, p_on)
     assert events.snapshot(), "timers mode recorded nothing"
+
+
+# ---------------------------------------------------------------------------
+# the run record of a fused-launch train (telemetry off throughout)
+# ---------------------------------------------------------------------------
+
+PERSIST_PARAMS = dict(TOY_PARAMS, tpu_persist_scan="force")
+
+
+def _persist_train(rounds=33, n=2000, seed=3):
+    X, y = _toy(n=n, seed=seed)
+    return lgb.train(dict(PERSIST_PARAMS), lgb.Dataset(X, y), rounds,
+                     verbose_eval=False)
+
+
+def test_run_record_of_an_off_mode_train():
+    """One train: the set-up spans once, one dispatch span per fused
+    launch, all under engine::train with one `train` and launches numbered
+    from 0; the next train gets the next `train`."""
+    assert events.mode() == events.OFF
+    _persist_train(33)              # 16 + 16 + a tail of 1: three launches
+    ring = events.ring_snapshot()
+    by_name = {}
+    for e in ring:
+        by_name.setdefault(e["name"], []).append(e)
+    for name in ("engine::train", "ops::BuildPersistPayload(pack)",
+                 "tree_learner::InitCarry(H2D launch)"):
+        assert len(by_name[name]) == 1, (name, sorted(by_name))
+    train = by_name["engine::train"][0]["train"]
+    assert train >= 1
+    ours = [e for e in ring if not e["name"].startswith("jax::")]
+    assert {e["train"] for e in ours} == {train}
+    launches = by_name["ops::persist_scan(launch)"]
+    assert [e["launch"] for e in launches] == [0, 1, 2]
+    assert [e["launch"] for e in
+            by_name["boosting::TrainMultiIterFast(launch)"]] == [0, 1, 2]
+    assert "launch" not in by_name["engine::train"][0]
+    assert by_name["ops::BuildPersistPayload(pack)"][0]["launch"] == 0
+    # parents form one tree under engine::train
+    names = set(by_name)
+    for e in ring:
+        if e["name"] == "engine::train":
+            assert "parent" not in e
+        else:
+            assert e["parent"] in names, e
+    for e in launches:
+        assert e["parent"] == "boosting::TrainMultiIterFast(launch)"
+    for e in by_name["boosting::TrainMultiIterFast(launch)"]:
+        assert e["parent"] == "engine::train"
+    root = by_name["engine::train"][0]
+    for e in ring:
+        assert e["ts"] >= root["ts"] - 1e-3
+        assert e["ts"] + e["dur"] <= root["ts"] + root["dur"] + 1e-3
+    # launch 0 compiled the fused program, under its dispatch span
+    compiled = [e for e in by_name["jax::backend_compile"]
+                + by_name.get("jax::cache_load", [])
+                if e.get("parent") == "ops::persist_scan(launch)"]
+    assert compiled and compiled[0]["launch"] == 0
+    _persist_train(16, seed=4)
+    trains = [e["train"] for e in events.ring_snapshot()
+              if e["name"] == "engine::train"]
+    assert trains == [train, train + 1]
+
+
+def test_late_materialize_belongs_to_the_launch_that_grew_the_trees():
+    """The host trees are built when somebody asks for the model, after
+    engine.train returned: those spans still carry the train and the
+    launch of their batch, split into the wait and the tree building."""
+    bst = _persist_train(32)
+    assert not [e for e in events.ring_snapshot()
+                if e["name"].startswith("boosting::MaterializePending")]
+    bst._booster._materialize_pending()
+    ring = events.ring_snapshot()
+    train = [e for e in ring if e["name"] == "engine::train"][0]["train"]
+    for name, cat in (("boosting::MaterializePending(D2H+wait)",
+                       "device_wait"),
+                      ("boosting::MaterializePending(host trees)",
+                       "boosting")):
+        got = [e for e in ring if e["name"] == name]
+        assert [e["launch"] for e in got] == [0, 1], name
+        assert {e["train"] for e in got} == {train}
+        assert {e["cat"] for e in got} == {cat}
+        assert {e["parent"] for e in got} == {"boosting::MaterializePending"}
+    tail = [e for e in ring
+            if e["name"] == "tree_learner::FinalizeScores(launch)"]
+    assert tail and tail[-1]["train"] == train and tail[-1]["launch"] == 1
+
+
+def test_compile_event_is_a_child_of_the_open_span():
+    import jax
+    import jax.numpy as jnp
+    x = jnp.arange(7.0)
+    events.reset()                  # drop the compiles of making x
+    with events.scope("holder", category="setup", always=True):
+        jax.block_until_ready(jax.jit(lambda v: jnp.tanh(v) * 3.25 + 7)(x))
+    ring = events.ring_snapshot()
+    comp = [e for e in ring if e["name"] in ("jax::backend_compile",
+                                             "jax::cache_load")]
+    assert comp, [e["name"] for e in ring]
+    assert all(e["parent"] == "holder" and e["cat"] == "compile"
+               for e in comp)
+    holder = [e for e in ring if e["name"] == "holder"][0]
+    kids = sum(e["self"] for e in ring if e["name"].startswith("jax::"))
+    assert holder["self"] == pytest.approx(holder["dur"] - kids, abs=1e-6)
+    counts = events.counts_snapshot()
+    assert counts.get("jax::compile_requests", 0) >= 1
+    for e in comp:
+        assert holder["ts"] <= e["ts"] + 1e-3
+        assert e["ts"] + e["dur"] <= holder["ts"] + holder["dur"] + 1e-3
+
+
+def test_fast_path_counters_readable_with_telemetry_off():
+    assert events.mode() == events.OFF
+    bst = _persist_train(32)
+    counts = events.counts_snapshot()
+    assert counts["tree_learner::persist_scan_trees"] == 32
+    assert counts.get("tree_learner::v1_grow_trees", 0) == 0
+    # the device-side launch counter arrives at the score finalize
+    bst._booster._sync_persist_scores()
+    assert events.counts_snapshot()["tree_learner::iter_launches"] == 2
+
+
+def test_program_spans_on_the_profilers_clock(tmp_path):
+    """Under a profiler session the recorded spans are TraceAnnotations:
+    they come back from the .xplane.pb through telemetry/xplane.py."""
+    import jax
+    from lightgbm_tpu.telemetry import xplane
+    tdir = str(tmp_path / "trace")
+    with jax.profiler.trace(tdir):
+        _persist_train(16, n=1500)
+    trace = xplane.parse_xplane_dir(tdir)
+    names = {name for _, _, name in trace["host"]}
+    assert {"engine::train", "boosting::TrainMultiIterFast(launch)",
+            "ops::persist_scan(launch)"} <= names, names
+    spans = {name: (s, e) for s, e, name in trace["host"]}
+    root, launch = spans["engine::train"], spans["ops::persist_scan(launch)"]
+    assert root[0] <= launch[0] and launch[1] <= root[1]
+    report = xplane.format_device_report(trace, iters=16)
+    assert "lgbm:" in report and "engine::train" in report
 
 
 # ---------------------------------------------------------------------------
@@ -168,6 +346,8 @@ def test_thread_safety():
                 pass
             with events.scope("own-%d" % i):
                 pass
+            with events.scope("rec", always=True):
+                pass
             events.count("hits")
 
     ts = [threading.Thread(target=work, args=(i,)) for i in range(threads)]
@@ -180,6 +360,9 @@ def test_thread_safety():
     for i in range(threads):
         assert snap["own-%d" % i][1] == per
     assert events.counts_snapshot()["hits"] == threads * per
+    ring = [e for e in events.ring_snapshot() if e["name"] == "rec"]
+    assert len(ring) == threads * per == snap["rec"][1]
+    assert len({e["tid"] for e in ring}) == threads
 
 
 def test_timer_module_aliases():
@@ -318,22 +501,44 @@ def test_monitor_standalone_record():
 
 
 # ---------------------------------------------------------------------------
-# xplane device profile (needs the TF proto bindings; CPU traces carry no
-# XLA-op device planes, so this only checks the parse/report plumbing)
+# xplane device profile (jax.profiler.ProfileData; CPU traces carry no
+# XLA-op device planes, so this checks the parse/report plumbing and the
+# gap attribution on a trace made by hand)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.slow
 def test_xplane_parse_smoke(tmp_path):
-    pytest.importorskip("tensorflow.tsl.profiler.protobuf")
     import jax
     import jax.numpy as jnp
     from lightgbm_tpu.telemetry import xplane
     tdir = str(tmp_path / "trace")
     with xplane.collect_trace(tdir):
         jax.block_until_ready(jax.jit(lambda x: x * 2)(jnp.ones(128)))
-    planes = xplane.parse_xplane_dir(tdir)
-    report = xplane.format_device_report(planes, iters=1)
+    trace = xplane.parse_xplane_dir(tdir)
+    assert set(trace) == {"device", "host"}
+    report = xplane.format_device_report(trace, iters=1)
     assert isinstance(report, str) and report
+
+
+def test_xplane_idle_gaps_by_program_span():
+    from lightgbm_tpu.telemetry import xplane
+    ms = 1_000_000
+    trace = {"device": {"/device:TPU:0": [
+        (0, 10 * ms, "%split_pass.1"), (12 * ms, 20 * ms, "%split_pass.2"),
+        (20 * ms, 21 * ms, "%fusion.7"), (30 * ms, 40 * ms, "seg_hist.3")]},
+        "host": [(0, 50 * ms, "engine::train"),
+                 (25 * ms, 29 * ms, "boosting::TrainMultiIterFast(launch)")]}
+    assert xplane.op_totals(trace)["/device:TPU:0"] == {
+        "split_pass": (18 * ms, 2), "fusion": (1 * ms, 1),
+        "seg_hist": (10 * ms, 1)}
+    busy, traced, gaps = xplane.idle_gaps(trace)
+    assert busy == pytest.approx(0.029) and traced == pytest.approx(0.040)
+    # the innermost covering span takes the gap
+    assert gaps == {"engine::train": pytest.approx(0.002),
+                    "boosting::TrainMultiIterFast(launch)":
+                        pytest.approx(0.009)}
+    report = xplane.format_device_report(trace, iters=2)
+    assert "split_pass" in report and "idle 27.50%" in report
+    assert xplane.base_name("%fusion.12 = f32[8]") == "fusion"
 
 
 # ---------------------------------------------------------------------------
