@@ -210,12 +210,14 @@ def estimate_scan_blocks(shape: BenchShape,
 
 def estimate_split_pass(shape: BenchShape, profile: DeviceProfile,
                         level: bool = False) -> KernelEstimate:
-    from ..ops.pallas_grow import split_pass_vmem_bytes
+    from ..ops.pallas_grow import _ceil8, split_pass_vmem_bytes
     WPA, C, _NP, nbw = _payload_geom(shape)
     E = C + 128
     G = shape.groups
-    # scratch_shapes: wbuf/obuf/rbuf + 4 FIFO slots (WP_LIVE <= WPA rows)
-    scratch = (3 * WPA * E + 4 * WPA * E) * 4 + G * 16 * 64 * 4
+    # scratch_shapes: wbuf/obuf/rbuf + 4 FIFO slots (WP_LIVE <= WPA rows,
+    # one lane tile past E) + the partition's two control planes
+    scratch = ((3 * WPA * E + 4 * WPA * (E + 128)) * 4 + G * 16 * 64 * 4
+               + 2 * _ceil8(E // 128) * 128 * 4)
     # decode temporaries: group-bin planes + the radix one-hot contraction
     temps = G * E * 4 + 64 * E * 2 + 2 * 16 * E * 2
     return _check(KernelEstimate(

@@ -34,9 +34,11 @@ Mosaic kernels over a single transposed payload matrix:
       - accumulates the SMALLER child's histogram as radix-16 one-hot MXU
         contractions (the GPU histogram kernel analog,
         src/treelearner/ocl/histogram256.cl, re-derived for the MXU),
-      - packs the chunk with a Kogge-Stone hole-shift compaction (log2 E
-        stages of static lane rolls + selects — word moves only, bit-exact,
-        no sort, no scratch matmul),
+      - partitions the chunk tile by tile: one prefix sum gives both sides'
+        control words, 7 hole-shift lane stages compact each 128-lane tile
+        in registers, and a dynamic roll appends the kept lanes to the
+        FIFO slot at the offset the drain will write them (word moves only,
+        bit-exact, no sort, no scratch matmul),
       - partitions the payload IN PLACE: a two-ended writeback with a
         2-chunk FIFO. Chunks are read from whichever end has the smaller
         write-space gap and drained two steps later, so reads always lead
@@ -63,8 +65,6 @@ emulation and the v1 growers (tests/test_persist_sharded.py).
 """
 from __future__ import annotations
 
-import sys
-
 import jax
 import jax.numpy as jnp
 
@@ -73,10 +73,6 @@ from .pallas_compat import CompilerParams, enable_x64, pl, pltpu
 I32 = jnp.int32
 U32 = jnp.uint32
 F32 = jnp.float32
-
-# the unrolled compaction stages trace deeper than CPython's default limit
-if sys.getrecursionlimit() < 20000:
-    sys.setrecursionlimit(20000)
 
 # scalar-prefetch slot indices for split_pass
 S_NCH = 0         # number of payload chunks of the segment
@@ -97,11 +93,9 @@ S_MF = 14         # most_freq (feature-local) bin
 N_SCALARS = 15
 
 
-def _log2_ceil(x: int) -> int:
-    n = 0
-    while (1 << n) < x:
-        n += 1
-    return n
+def _ceil8(x: int) -> int:
+    """x rounded up to whole sublane tiles."""
+    return -(-x // 8) * 8
 
 
 # -- scoped-vmem requests ----------------------------------------------------
@@ -116,11 +110,16 @@ def _log2_ceil(x: int) -> int:
 # and C grows instead.
 
 def split_pass_vmem_bytes(WPA: int, E: int, G: int) -> int:
-    """split_pass / level_pass: 7 chunk-sized u32 buffers + the radix
-    hist accumulator + ~3 buffers of compaction temporaries."""
+    """split_pass / level_pass: 3 chunk-sized u32 buffers, 4 FIFO slots
+    one lane tile wider, the radix hist accumulator, and ~3 buffers of
+    Mosaic's own staging for the re-aligned chunk value and the dynamic
+    rolls around it (the compiler's stack reads 44.1 MB at WPA 40,
+    E 16512). The partition adds two [E / 128, 128] control planes and
+    otherwise works tile by tile in registers."""
     return int(min(96 << 20,
-                   7 * WPA * E * 4 + G * 16 * 64 * 4 + (20 << 20)
-                   + 3 * WPA * E * 4))
+                   3 * WPA * E * 4 + 4 * WPA * (E + 128) * 4
+                   + G * 16 * 64 * 4 + (20 << 20)
+                   + 3 * WPA * E * 4 + 2 * _ceil8(E // 128) * 128 * 4))
 
 
 def seg_hist_vmem_bytes(WPA: int, E: int, G: int) -> int:
@@ -158,51 +157,128 @@ def _lane_iota(E: int):
     return jax.lax.broadcasted_iota(I32, (1, E), 1)
 
 
-def _prefix_sum_lanes(x, E: int):
-    """Inclusive prefix sum along lanes of [1, E] i32 (Kogge-Stone)."""
-    lane = _lane_iota(E)
-    for b in range(_log2_ceil(E)):
+def _tile_prefix_sum(g):
+    """Inclusive prefix sum along the 128 lanes of every row of [T, 128]
+    i32 (Kogge-Stone; a row is one lane tile, so 7 stages whatever T)."""
+    lane = jax.lax.broadcasted_iota(I32, g.shape, 1)
+    for b in range(7):
         sh = 1 << b
-        shifted = pltpu.roll(x, sh, 1)
-        x = x + jnp.where(lane >= sh, shifted, jnp.int32(0))
-    return x
+        g = g + jnp.where(lane >= sh, pltpu.roll(g, sh, 1), 0)
+    return g
 
 
-def _compact(block, keep, E: int, to_right: bool):
-    """Stable compaction of [R, E] u32 lanes with keep toward lane 0
-    (or toward lane E-1 when to_right).
+def _compact_tiles(xs, cs):
+    """Hole-shift compaction inside 128-lane tiles, both sides at once.
 
-    Hole-shift method: each kept lane moves by r = number of dropped lanes
-    before it (after it, for to_right); process r bit by bit from the low
-    end — at stage b every kept lane whose remaining shift has bit b set
-    moves 2^b. Low-to-high is collision-free: two kept lanes whose
-    positions differ by < 2^b have equal remaining shifts (both multiples
-    of 2^b), so if the arriving lane moves the vacating lane moves too.
-    Word moves + selects only: bit-exact for any payload.
+    xs: tiles [R, 128] u32; cs: their control words [1, 128] i32 — bits
+    0-6 hold how far a left row moves toward lane 0 (its holes before it
+    in the tile), bits 8-14 the same for a right row, 0 = a hole or a row
+    already home. Stage b moves every lane whose bit b is set by 2^b; the
+    word is never decremented (clearing lower bits changes nothing above
+    them) and travels with its row, a vacated lane becomes a hole.
+    Low-to-high is collision-free: two kept lanes closer than 2^b have
+    equal remaining shifts, so where one arrives the other has left; a
+    row never leaves its tile, so the cyclic wrap of the roll carries no
+    set bit. Word moves and selects only: bit-exact for any payload.
+    Stage-major over the group so the rolls of different tiles overlap.
+    Returns [(left-compacted, right-compacted)] per tile, both toward
+    lane 0 in row order.
     """
-    keep_i = keep.astype(I32)[None, :]                       # [1, E]
-    drop_incl = _prefix_sum_lanes(1 - keep_i, E)
-    if to_right:
-        # holes AFTER lane i = total_dropped - inclusive_prefix(i)
-        total = jnp.max(drop_incl)                           # last lane
-        holes = total - drop_incl
-    else:
-        holes = drop_incl - (1 - keep_i)
-    r = jnp.where(keep_i > 0, holes, 0)                      # [1, E]
-    x = block
-    k = keep_i
-    for b in range(_log2_ceil(E)):
+    xl, xr, cs = list(xs), list(xs), list(cs)
+    for b in range(7):
         sh = 1 << b
-        step = sh if to_right else E - sh                    # roll direction
-        x_s = pltpu.roll(x, step, 1)
-        r_s = pltpu.roll(r, step, 1)
-        k_s = pltpu.roll(k, step, 1)
-        arrives = (k_s > 0) & (((r_s >> b) & 1) > 0)         # [1, E]
-        moved = (k > 0) & (((r >> b) & 1) > 0)
-        x = jnp.where(arrives, x_s, x)
-        r = jnp.where(arrives, r_s - sh, r)
-        k = jnp.where(arrives, 1, jnp.where(moved, 0, k))
-    return x
+        for i, c in enumerate(cs):
+            c_s = pltpu.roll(c, 128 - sh, 1)
+            arr_l = (c_s & sh) != 0
+            arr_r = (c_s & (sh << 8)) != 0
+            xl[i] = jnp.where(arr_l, pltpu.roll(xl[i], 128 - sh, 1), xl[i])
+            xr[i] = jnp.where(arr_r, pltpu.roll(xr[i], 128 - sh, 1), xr[i])
+            if b < 6:
+                lo = jnp.where(arr_l, c_s,
+                               jnp.where((c & sh) != 0, 0, c)) & 0xFF
+                hi = jnp.where(arr_r, c_s,
+                               jnp.where((c & (sh << 8)) != 0, 0, c)) & 0xFF00
+                cs[i] = lo | hi
+    return list(zip(xl, xr))
+
+
+def _partition_chunk(src, R: int, gl, m, base_l, base_r, dst_l, dst_r,
+                     ctl, cnt):
+    """Stable two-sided partition of one chunk into a FIFO slot pair.
+
+    src: VMEM ref [>= R, E] u32, chunk rows at lanes [0, m), m <= E - 128
+    (the last lane tile is the DMA's alignment slack and holds no row);
+    gl: [E] bool, the valid lanes that go left (the other valid lanes go
+    right). dst_l / dst_r: VMEM refs [>= R, E + 128]; afterwards dst_l
+    lanes [base_l, base_l + nL) hold the left rows in order and dst_r
+    lanes [base_r, base_r + nR) the right rows in order, every other lane
+    is undefined. The caller passes as bases the lane offsets the drain
+    will write the blocks at, so the drain blends a slot without rolling
+    it. ctl / cnt: VMEM scratch [>= E / 128, 128] i32.
+
+    One prefix sum serves both sides: with P the inclusive count of left
+    rows inside the tile, a left row at tile lane j has j + 1 - P holes
+    before it and a valid right row has P. The chunk is then walked tile
+    by tile: 7 lane stages compact the tile for each side
+    (_compact_tiles), and the kept prefix is appended to its side's
+    running output by one dynamic roll and a merge with the tile being
+    filled, which is stored each time and carried in registers — no
+    read-modify-write of the slot. A group of tiles goes through the
+    stages together so that their rolls overlap: the XLU's round trip,
+    not its throughput, bounds a single tile.
+    """
+    E = src.shape[1]
+    T = E // 128
+    # the select keeps the two reshapes apart: Mosaic has no [E] -> [T, 128]
+    g = jnp.where(gl[None, :], 1, 0).astype(I32).reshape(T, 128)
+    P = _tile_prefix_sum(g)
+    j = jax.lax.broadcasted_iota(I32, (T, 128), 1)
+    pos = jax.lax.broadcasted_iota(I32, (T, 128), 0) * 128 + j
+    ctl[0:T, :] = jnp.where(g > 0, j + 1 - P,
+                            jnp.where(pos < m, P << 8, 0))
+    cnt[0:T, :] = jnp.broadcast_to(P[:, 127:128], (T, 128))
+
+    lane = jax.lax.broadcasted_iota(I32, (R, 128), 1)
+
+    def place(x, off, acc, n, dst):
+        q = off >> 7
+        d = off & 127
+        rot = pltpu.roll(x, d, 1)
+        merged = jnp.where(lane >= d, rot, acc)
+        dst[0:R, pl.ds(pl.multiple_of(q * 128, 128), 128)] = merged
+        # vector compare: a scalar-bool select does not lower (see dlv)
+        full = (jnp.zeros_like(lane) + (d + n)) >= 128
+        return off + n, jnp.where(full, rot, merged)
+
+    def group(t0, k, carry):
+        """Tiles t0 .. t0 + k - 1 (t0 traced, k static)."""
+        off_l, acc_l, off_r, acc_r = carry
+        tile = [pl.ds(pl.multiple_of((t0 + s) * 128, 128), 128)
+                for s in range(k)]
+        packed = _compact_tiles([src[0:R, tile[s]] for s in range(k)],
+                                [ctl[pl.ds(t0 + s, 1), :] for s in range(k)])
+        for s, (x_l, x_r) in enumerate(packed):
+            n_l = cnt[pl.ds(t0 + s, 1), :][0, 0]
+            n_r = jnp.clip(m - (t0 + s) * 128, 0, 128) - n_l
+            off_l, acc_l = place(x_l, off_l, acc_l, n_l, dst_l)
+            off_r, acc_r = place(x_r, off_r, acc_r, n_r, dst_r)
+        return off_l, acc_l, off_r, acc_r
+
+    # chip readings (PERF.md, PR 29): 8 tiles a group leave the loop bound
+    # by the roll's latency, 16 by its throughput, 32 gain nothing; wide
+    # payloads spill at 16 and read the same at 8
+    G = 16 if R <= 16 else 8
+    tiles = T - 1
+    zero = jnp.zeros((R, 128), U32)
+    carry = (base_l, zero, base_r, zero)
+    if tiles // G:
+        carry = jax.lax.fori_loop(
+            0, tiles // G, lambda gi, c: group(gi * G, G, c), carry)
+    if tiles % G:
+        carry = group(jnp.int32(tiles // G * G), tiles % G, carry)
+    off_l, acc_l, off_r, acc_r = carry
+    dst_l[0:R, pl.ds(pl.multiple_of((off_l >> 7) * 128, 128), 128)] = acc_l
+    dst_r[0:R, pl.ds(pl.multiple_of((off_r >> 7) * 128, 128), 128)] = acc_r
 
 
 def _unpack_group_bins(pay_block, plan):
@@ -318,12 +394,14 @@ def make_split_pass(WPA: int, NP: int, G: int, plan, nbw: int,
     """
     assert WPA % 8 == 0, "payload row count must be padded to 8"
     E = C + 128
+    TP = _ceil8(E // 128)
     grad_row = nbw + 2
     WP_LIVE = wp_live or (nbw + 5)
     assert WP_LIVE <= WPA
 
     def kernel(ns, pay_in, pay_out, hist_ref, cnt_ref,
-               wbuf, obuf, rbuf, slots, st, sem_r, sem_w, sem_rmw):
+               wbuf, obuf, rbuf, slots, ctl, tcnt, st, sem_r, sem_w,
+               sem_rmw):
         # st (SMEM i32): 0 fr, 1 br, 2 lf, 3 rf, 4 pendL, 5 pendR,
         #                6 nleft, 7+2p nL(slot p), 8+2p nR(slot p)
         i = pl.program_id(0)
@@ -356,10 +434,12 @@ def make_split_pass(WPA: int, NP: int, G: int, plan, nbw: int,
             p = jax.lax.rem(i, jnp.int32(2))  # == (i-2) % 2
             nL_ = jnp.where(p == 0, st[7], st[9])
             nR_ = jnp.where(p == 0, st[8], st[10])
-            src_l = jnp.where(p == 0, slots[0], slots[2])
-            src_r = jnp.where(p == 0, slots[1], slots[3])
+            src_l = jnp.where(p == 0, slots[0, 0:WP_LIVE, 0:E],
+                              slots[2, 0:WP_LIVE, 0:E])
+            src_r = jnp.where(p == 0, slots[1, 0:WP_LIVE, 0:E],
+                              slots[3, 0:WP_LIVE, 0:E])
 
-            # left block: slot lanes [0, nL) -> payload [lf, lf+nL)
+            # left block: slot lanes [dL, dL+nL) -> payload [lf, lf+nL)
             lf = st[2]
             al = _align128(lf)
             dL = lf - al
@@ -368,8 +448,7 @@ def make_split_pass(WPA: int, NP: int, G: int, plan, nbw: int,
             cp.start()
             cp.wait()
             sel = (lane >= dL) & (lane < dL + nL_)
-            obuf[:WP_LIVE] = jnp.where(sel[None, :],
-                                       pltpu.roll(src_l, dL, 1),
+            obuf[:WP_LIVE] = jnp.where(sel[None, :], src_l,
                                        rbuf[:WP_LIVE])
             if WP_LIVE < WPA:
                 obuf[WP_LIVE:] = rbuf[WP_LIVE:]
@@ -380,7 +459,7 @@ def make_split_pass(WPA: int, NP: int, G: int, plan, nbw: int,
             st[2] = lf + nL_
             st[4] = st[4] - nL_
 
-            # right block: slot lanes [E-nR, E) -> payload [rf-nR, rf)
+            # right block: slot lanes [dR, dR+nR) -> payload [rf-nR, rf)
             rf = st[3]
             rs = rf - nR_
             al2 = _align128(rs)
@@ -390,8 +469,7 @@ def make_split_pass(WPA: int, NP: int, G: int, plan, nbw: int,
             cp2.start()
             cp2.wait()
             sel2 = (lane >= dR) & (lane < dR + nR_)
-            obuf[:WP_LIVE] = jnp.where(sel2[None, :],
-                                       pltpu.roll(src_r, dR + nR_, 1),
+            obuf[:WP_LIVE] = jnp.where(sel2[None, :], src_r,
                                        rbuf[:WP_LIVE])
             if WP_LIVE < WPA:
                 obuf[WP_LIVE:] = rbuf[WP_LIVE:]
@@ -445,7 +523,6 @@ def make_split_pass(WPA: int, NP: int, G: int, plan, nbw: int,
             go_left = (gd & dlv) | ((~gd) & cmp_left)
 
             gl = valid & go_left
-            gr = valid & (~go_left)
             nL = jnp.sum(gl.astype(F32), dtype=F32).astype(I32)
             nR = m - nL
             st[6] = st[6] + nL
@@ -458,30 +535,23 @@ def make_split_pass(WPA: int, NP: int, G: int, plan, nbw: int,
                 bins_g = _unpack_group_bins(w, plan)
                 _hist_accum(hist_ref, bins_g, grad, hess, G)
 
-            # pack both sides into this step's FIFO slot
-            wp_live = w[:WP_LIVE]
-            if _skip_pack:
-                packedL = wp_live
-                packedR = wp_live
-            else:
-                packedL = _compact(wp_live, gl, E, to_right=False)
-                packedR = _compact(wp_live, gr, E, to_right=True)
-
+            # pack both sides into this step's FIFO slot pair; obuf is
+            # idle between drains and lends the chunk a tile-addressable
+            # home
             pr = jax.lax.rem(i, jnp.int32(2))
-
-            @pl.when(pr == 0)
-            def _():
-                slots[0] = packedL
-                slots[1] = packedR
-                st[7] = nL
-                st[8] = nR
-
-            @pl.when(pr == 1)
-            def _():
-                slots[2] = packedL
-                slots[3] = packedR
-                st[9] = nL
-                st[10] = nR
+            if _skip_pack:
+                slots[2 * pr, 0:WP_LIVE, 0:E] = w[:WP_LIVE]
+                slots[2 * pr + 1, 0:WP_LIVE, 0:E] = w[:WP_LIVE]
+            else:
+                obuf[...] = w
+                # the blocks land where the drain two steps on will
+                # write them: after the blocks still pending
+                _partition_chunk(
+                    obuf, WP_LIVE, gl, m,
+                    (st[2] + st[4]) & 127, (st[3] - st[5] - nR) & 127,
+                    slots.at[2 * pr], slots.at[2 * pr + 1], ctl, tcnt)
+            st[7 + 2 * pr] = nL
+            st[8 + 2 * pr] = nR
             st[4] = st[4] + nL
             st[5] = st[5] + nR
 
@@ -526,7 +596,11 @@ def make_split_pass(WPA: int, NP: int, G: int, plan, nbw: int,
                     pltpu.VMEM((WPA, E), U32),     # wbuf
                     pltpu.VMEM((WPA, E), U32),     # obuf
                     pltpu.VMEM((WPA, E), U32),     # rbuf
-                    pltpu.VMEM((4, WP_LIVE, E), U32),  # FIFO slots (2 x L/R)
+                    # FIFO slots (2 x L/R): whole sublane tiles, and
+                    # one lane tile past E for the placement's last store
+                    pltpu.VMEM((4, _ceil8(WP_LIVE), E + 128), U32),
+                    pltpu.VMEM((TP, 128), I32),    # ctl: control words
+                    pltpu.VMEM((TP, 128), I32),    # tcnt: rows left a tile
                     pltpu.SMEM((12,), I32),        # st
                     pltpu.SemaphoreType.DMA,
                     pltpu.SemaphoreType.DMA,
@@ -580,13 +654,14 @@ def make_level_pass(WPA: int, NP: int, G: int, plan, nbw: int,
     """
     assert WPA % 8 == 0, "payload row count must be padded to 8"
     E = C + 128
+    TP = _ceil8(E // 128)
     grad_row = nbw + 2
     WP_LIVE = wp_live or (nbw + 5)
     assert WP_LIVE <= WPA
 
     def kernel(sm, so, bo, pay_in, pay_out, hist_out, cnt_ref,
-               hacc, wbuf, obuf, rbuf, slots, st, sem_r, sem_w, sem_rmw,
-               sem_h):
+               hacc, wbuf, obuf, rbuf, slots, ctl, tcnt, st, sem_r, sem_w,
+               sem_rmw, sem_h):
         i = pl.program_id(0)
         j = so[i]                       # slot of this step
         lo = i - bo[j]                  # local step within the slot
@@ -619,8 +694,10 @@ def make_level_pass(WPA: int, NP: int, G: int, plan, nbw: int,
             p = jax.lax.rem(lo, jnp.int32(2))
             nL_ = jnp.where(p == 0, st[7], st[9])
             nR_ = jnp.where(p == 0, st[8], st[10])
-            src_l = jnp.where(p == 0, slots[0], slots[2])
-            src_r = jnp.where(p == 0, slots[1], slots[3])
+            src_l = jnp.where(p == 0, slots[0, 0:WP_LIVE, 0:E],
+                              slots[2, 0:WP_LIVE, 0:E])
+            src_r = jnp.where(p == 0, slots[1, 0:WP_LIVE, 0:E],
+                              slots[3, 0:WP_LIVE, 0:E])
 
             lf = st[2]
             al = _align128(lf)
@@ -630,8 +707,7 @@ def make_level_pass(WPA: int, NP: int, G: int, plan, nbw: int,
             cp.start()
             cp.wait()
             sel = (lane >= dL) & (lane < dL + nL_)
-            obuf[:WP_LIVE] = jnp.where(sel[None, :],
-                                       pltpu.roll(src_l, dL, 1),
+            obuf[:WP_LIVE] = jnp.where(sel[None, :], src_l,
                                        rbuf[:WP_LIVE])
             if WP_LIVE < WPA:
                 obuf[WP_LIVE:] = rbuf[WP_LIVE:]
@@ -651,8 +727,7 @@ def make_level_pass(WPA: int, NP: int, G: int, plan, nbw: int,
             cp2.start()
             cp2.wait()
             sel2 = (lane >= dR) & (lane < dR + nR_)
-            obuf[:WP_LIVE] = jnp.where(sel2[None, :],
-                                       pltpu.roll(src_r, dR + nR_, 1),
+            obuf[:WP_LIVE] = jnp.where(sel2[None, :], src_r,
                                        rbuf[:WP_LIVE])
             if WP_LIVE < WPA:
                 obuf[WP_LIVE:] = rbuf[WP_LIVE:]
@@ -700,7 +775,6 @@ def make_level_pass(WPA: int, NP: int, G: int, plan, nbw: int,
             go_left = (gd & dlv) | ((~gd) & cmp_left)
 
             gl = valid & go_left
-            gr = valid & (~go_left)
             nL = jnp.sum(gl.astype(F32), dtype=F32).astype(I32)
             nR = m - nL
             st[6] = st[6] + nL
@@ -712,25 +786,14 @@ def make_level_pass(WPA: int, NP: int, G: int, plan, nbw: int,
                 bins_g = _unpack_group_bins(w, plan)
                 _hist_accum(hacc, bins_g, grad, hess, G)
 
-            wp_rows = w[:WP_LIVE]
-            packedL = _compact(wp_rows, gl, E, to_right=False)
-            packedR = _compact(wp_rows, gr, E, to_right=True)
-
             pr = jax.lax.rem(lo, jnp.int32(2))
-
-            @pl.when(pr == 0)
-            def _():
-                slots[0] = packedL
-                slots[1] = packedR
-                st[7] = nL
-                st[8] = nR
-
-            @pl.when(pr == 1)
-            def _():
-                slots[2] = packedL
-                slots[3] = packedR
-                st[9] = nL
-                st[10] = nR
+            obuf[...] = w
+            _partition_chunk(
+                obuf, WP_LIVE, gl, m,
+                (st[2] + st[4]) & 127, (st[3] - st[5] - nR) & 127,
+                slots.at[2 * pr], slots.at[2 * pr + 1], ctl, tcnt)
+            st[7 + 2 * pr] = nL
+            st[8 + 2 * pr] = nR
             st[4] = st[4] + nL
             st[5] = st[5] + nR
 
@@ -771,7 +834,9 @@ def make_level_pass(WPA: int, NP: int, G: int, plan, nbw: int,
                     pltpu.VMEM((WPA, E), U32),      # wbuf
                     pltpu.VMEM((WPA, E), U32),      # obuf
                     pltpu.VMEM((WPA, E), U32),      # rbuf
-                    pltpu.VMEM((4, WP_LIVE, E), U32),  # FIFO slots
+                    pltpu.VMEM((4, _ceil8(WP_LIVE), E + 128), U32),  # FIFO slots
+                    pltpu.VMEM((TP, 128), I32),     # ctl
+                    pltpu.VMEM((TP, 128), I32),     # tcnt
                     pltpu.SMEM((12,), I32),         # st
                     pltpu.SemaphoreType.DMA,
                     pltpu.SemaphoreType.DMA,

@@ -7,8 +7,10 @@ shift, more VMEM than a kernel may hold. These cases hand the TPU compiler
 each ``pallas_call`` site at the geometry the code itself builds for a real
 configuration — the persist grower's kernels for HIGGS (10.5M x 28,
 255 bins, 255 leaves), the same payload with a finite ``max_depth`` for the
-level kernels, and an EFB-bundled Expo-like payload (11M rows) for the
-block scan — plus the whole fused k=16 scan driver. Nothing runs, so they
+level kernels, an EFB-bundled Expo-like payload (11M rows) for the
+block scan, and the MS-LTR payload (137 features: 40 live rows, whole
+sublane tiles, so split_pass has no spare sublane) — plus the whole fused
+k=16 scan driver. Nothing runs, so they
 say nothing about results or times; ``chip_smoke.py`` does that on the chip.
 
 All cases live in this one file: the topology is described inside a
@@ -17,6 +19,7 @@ because only one process at a time may load the TPU library and each xdist
 worker imports every test file. The persistent compile cache is off around
 them — a described-topology entry cannot be read back without a chip.
 """
+import inspect
 import os
 
 import numpy as np
@@ -28,7 +31,8 @@ from jax.sharding import SingleDeviceSharding
 
 import lightgbm_tpu as lgb
 from lightgbm_tpu.data.dataset import BinnedDataset
-from lightgbm_tpu.data.synth import make_expo_like, make_higgs_like
+from lightgbm_tpu.data.synth import (make_expo_like, make_higgs_like,
+                                      make_ltr_like)
 from lightgbm_tpu.objectives import create_objective
 from lightgbm_tpu.ops import grow_persist as gp
 from lightgbm_tpu.ops.pallas_grow import N_SCALARS
@@ -39,6 +43,7 @@ from lightgbm_tpu.treelearner.serial import SerialTreeLearner
 
 HIGGS_ROWS = 10_500_000     # docs/Experiments.rst: HIGGS
 EXPO_ROWS = 11_000_000      # docs/Experiments.rst: Expo
+MSLTR_ROWS = 2_270_296      # docs/Experiments.rst: MS LTR
 LEVEL_DEPTH = 8             # max_depth; with num_leaves = 2^8 the level
 #                             phase engages (bench.py's level configuration)
 SAMPLE_ROWS = 20_000        # rows actually binned: the bin structure only
@@ -115,6 +120,12 @@ def expo():
     return _Built(X, y, EXPO_ROWS, (LEVEL_DEPTH,))
 
 
+@pytest.fixture(scope="module")
+def msltr():
+    X, y, _ = make_ltr_like(SAMPLE_ROWS)
+    return _Built(X, (y > 1).astype(np.float64), MSLTR_ROWS, (-1,))
+
+
 def _hist_window(higgs, expo, S):
     learner = higgs.learners[-1]
     G, C = len(higgs.ds.groups), learner.grow_config.window_chunk
@@ -153,6 +164,15 @@ def _scan_blocks(higgs, expo, S):
 def _split_pass(higgs, expo, S):
     return higgs.growers[-1]._split_pass, (
         S(higgs.pay, jnp.uint32), S((N_SCALARS,), jnp.int32))
+
+
+def _split_pass_msltr(higgs, expo, S, msltr):
+    """40 live payload rows: the partition's tiles are five whole
+    sublane tiles and its group of tiles is shorter."""
+    nbw = msltr.assets.geometry[4]
+    assert nbw + 5 == 40 == msltr.pay[0], msltr.assets.geometry[:5]
+    return msltr.growers[-1]._split_pass, (
+        S(msltr.pay, jnp.uint32), S((N_SCALARS,), jnp.int32))
 
 
 def _seg_hist(higgs, expo, S):
@@ -206,15 +226,19 @@ def _fused_driver(higgs, expo, S):
 
 
 @pytest.mark.parametrize("case", [
-    _hist_window, _scan_pair, _scan_blocks, _split_pass, _level_pass,
-    _level_pass_inpass, _level_seg_hist, _seg_hist, _root_hist,
+    _hist_window, _scan_pair, _scan_blocks, _split_pass, _split_pass_msltr,
+    _level_pass, _level_pass_inpass, _level_seg_hist, _seg_hist, _root_hist,
     _fused_driver,
 ], ids=lambda f: f.__name__.lstrip("_"))
-def test_compiles_for_v5e(case, one_chip, higgs, expo):
+def test_compiles_for_v5e(case, one_chip, higgs, expo, request):
     def S(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    fn, args = case(higgs, expo, S)
+    # a case names the other configurations it needs; they are built only
+    # for it
+    more = [request.getfixturevalue(name)
+            for name in list(inspect.signature(case).parameters)[3:]]
+    fn, args = case(higgs, expo, S, *more)
     assert fn is not None, "the grower built no such kernel here"
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
