@@ -60,8 +60,8 @@ class BenchShape:
 
     ``groups`` is the post-EFB feature-group count: unbundled datasets
     carry one byte group per feature; bundled ones pack their one-hot
-    blocks into <=255-offset byte groups (Expo's 648 features bundle to
-    18 groups; Allstate's ~4218 one-hot columns to ~17 plus the 8
+    blocks into <=255-offset byte groups (Expo's 700 features bundle to
+    16 groups; Allstate's ~4218 one-hot columns to ~17 plus the 8
     numerics)."""
 
     name: str
@@ -79,7 +79,7 @@ class BenchShape:
 BENCH_SHAPES: Dict[str, BenchShape] = {
     "higgs": BenchShape("higgs", rows=10_500_000, features=28, groups=28,
                         bundled=False),
-    "expo": BenchShape("expo", rows=2_000_000, features=648, groups=18,
+    "expo": BenchShape("expo", rows=2_000_000, features=700, groups=16,
                        bundled=True),
     "allstate": BenchShape("allstate", rows=1_000_000, features=4226,
                            groups=25, bundled=True),

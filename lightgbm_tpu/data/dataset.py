@@ -303,29 +303,47 @@ class BinnedDataset:
         inner_mappers = [ds.bin_mappers[f] for f in ds.used_features]
         n_inner = len(inner_mappers)
         if config.enable_bundle and n_inner > 1:
-            nz_masks = []
-            for i, f in enumerate(ds.used_features):
-                mapper = inner_mappers[i]
-                if isinstance(sample, SampleCols):
-                    bins = mapper.value_to_bin(sample.values[f])
-                    mask = np.zeros(total_sample, bool)
-                    mask[sample.rows[f][bins != mapper.most_freq_bin]] = True
-                    nz_masks.append(mask)
-                else:
-                    bins = mapper.value_to_bin(sample[:, f])
-                    nz_masks.append(bins != mapper.most_freq_bin)
-            order = sorted(range(n_inner),
-                           key=lambda i: -int(nz_masks[i].sum()))
-            max_conflict = int(total_sample / 10000
-                               + config.max_conflict_rate * total_sample)
-            groups = _greedy_bundle(
-                nz_masks, order, [m.num_bin for m in inner_mappers],
-                total_sample, max_conflict)
-            ds.groups = groups
+            with telemetry_events.scope("io::FindGroups(EFB)", category="io",
+                                        always=True):
+                ds.groups = ds._find_groups(sample, inner_mappers,
+                                            total_sample, config)
         else:
             ds.groups = [[i] for i in range(n_inner)]
+        # run record: the newest construct's grouping (set, not summed)
+        telemetry_events.clear_counts_prefix("io::efb_")
+        telemetry_events.count("io::efb_groups", float(len(ds.groups)),
+                               category="io")
+        telemetry_events.count(
+            "io::efb_bundled_features",
+            float(sum(len(g) for g in ds.groups if len(g) > 1)),
+            category="io")
 
         ds._finish_layout(config)
+
+    def _find_groups(self, sample, inner_mappers, total_sample: int,
+                     config: Config) -> List[List[int]]:
+        """EFB: the rows of the sample outside each feature's most frequent
+        bin, then the greedy conflict-bounded bundling over them."""
+        nz_masks = []
+        for i, f in enumerate(self.used_features):
+            mapper = inner_mappers[i]
+            if isinstance(sample, SampleCols):
+                bins = mapper.value_to_bin(sample.values[f])
+                # a row without a stored value sits where a zero falls
+                mask = np.full(total_sample,
+                               mapper.default_bin != mapper.most_freq_bin)
+                mask[sample.rows[f]] = bins != mapper.most_freq_bin
+                nz_masks.append(mask)
+            else:
+                bins = mapper.value_to_bin(sample[:, f])
+                nz_masks.append(bins != mapper.most_freq_bin)
+        order = sorted(range(len(inner_mappers)),
+                       key=lambda i: -int(nz_masks[i].sum()))
+        max_conflict = int(total_sample / 10000
+                           + config.max_conflict_rate * total_sample)
+        return _greedy_bundle(
+            nz_masks, order, [m.num_bin for m in inner_mappers],
+            total_sample, max_conflict)
 
     @classmethod
     def from_sparse(cls, X, config: Config,
@@ -334,14 +352,19 @@ class BinnedDataset:
                     feature_names: Optional[List[str]] = None,
                     reference: Optional["BinnedDataset"] = None,
                     ) -> "BinnedDataset":
-        """Streaming CSR ingest: sample -> bin mappers -> chunked binning,
-        never materializing the dense [n, features] matrix (the reference
-        streams sparse rows through Dataset::PushOneRow the same way,
-        src/io/dataset_loader.cpp:714-1004). Host memory is bounded by one
-        row chunk (~256 MB dense) + the binned output [n, groups]."""
+        """CSR ingest: sample -> bin mappers -> binning of the stored values
+        only, never materializing the dense [n, features] matrix (the
+        reference pushes a sparse row's nonzeros through
+        Dataset::PushOneRow the same way, src/io/dataset_loader.cpp:
+        714-1004). Host memory: the binned output [n, groups] (the numpy
+        fallback also makes one CSC copy of the input). The multi-value
+        (ELL) layout still bins dense row chunks of ~256 MB."""
         import scipy.sparse as sp  # noqa: F401 — import guard: a clear ImportError beats a tocsr AttributeError
         X = X.tocsr()
-        X.sort_indices()
+        if not X.has_canonical_format:
+            # sorted columns, duplicates summed: what todense() would read
+            X = X.copy()
+            X.sum_duplicates()
         n, nf = X.shape
         ds = cls()
         ds.num_data = n
@@ -385,12 +408,12 @@ class BinnedDataset:
 
         with telemetry_events.scope("io::PushSparse(binning)",
                                     category="io", always=True):
-            G = len(ds.groups)
-            chunk = max(1024, int(2 ** 25 / max(nf, 1)))
             if ds._choose_multival(config, X):
                 # stream into the multi-value layout: host memory is
                 # bounded by one dense chunk + the non-default entries
                 # (the dense [n, G] matrix is never materialized)
+                G = len(ds.groups)
+                chunk = max(1024, int(2 ** 25 / max(nf, 1)))
                 gd = ds.group_default_bins()
                 buf = np.zeros((chunk, G), dtype=ds._bin_dtype())
                 coo = []
@@ -404,13 +427,78 @@ class BinnedDataset:
                     force=str(getattr(config, "tpu_multival",
                                       "auto")).lower() == "force")
             else:
-                binned = np.zeros((n, G), dtype=ds._bin_dtype())
-                for a in range(0, n, chunk):
-                    b = min(a + chunk, n)
-                    Xc = np.asarray(X[a:b].todense(), dtype=np.float64)
-                    ds._bin_rows(Xc, binned[a:b])
-                ds.binned = binned
+                ds._push_sparse(X)
         return ds
+
+    def _push_sparse(self, X) -> None:
+        """Quantize a canonical CSR by its stored values: the same `binned`
+        as `_bin_rows` on the dense matrix, cell for cell. Every row of a
+        group starts where a zero falls (the group's default bin 0 in a
+        bundle, the zero's own bin alone), and only rows with a stored
+        value in one of the group's features are written."""
+        n = X.shape[0]
+        binned = np.zeros((n, len(self.groups)), dtype=self._bin_dtype())
+        self.binned = binned
+        if self._push_sparse_native(X, binned):
+            return
+        csc = X.tocsc()
+        for gid, feats in enumerate(self.groups):
+            multi = len(feats) > 1
+            col = binned[:, gid]
+            local = 1 if multi else 0
+            for i in feats:
+                f = self.used_features[i]
+                m = self.bin_mappers[f]
+                lo, hi = csc.indptr[f], csc.indptr[f + 1]
+                rows = csc.indices[lo:hi]
+                b = m.value_to_bin(csc.data[lo:hi])
+                if not multi:
+                    col[:] = m.default_bin      # where a zero falls
+                    col[rows] = b
+                else:
+                    if m.default_bin != m.most_freq_bin:
+                        # the rows WITHOUT a stored value are the ones that
+                        # leave the most frequent bin: the dense route
+                        # writes them all, over the group's earlier features
+                        kept = col[rows]
+                        col[:] = local + m.default_bin
+                        col[rows] = kept
+                    nz = b != m.most_freq_bin
+                    col[rows[nz]] = local + b[nz]
+                local += m.num_bin
+
+    def _push_sparse_native(self, X, out: np.ndarray) -> bool:
+        """C++/OpenMP binning of the CSR's rows by their stored values
+        (native/binrows.cpp:bin_csr); False -> use numpy."""
+        from ..native import load
+        import ctypes
+        lib = load("binrows", extra_flags=("-fopenmp",))
+        if lib is None or not self.groups:
+            return False
+        m = self._native_bin_meta()
+        data = X.data
+        if data.dtype not in (np.float32, np.float64):
+            data = data.astype(np.float64)
+        data = np.ascontiguousarray(data)
+        indices = np.ascontiguousarray(X.indices, dtype=np.int32)
+        indptr = np.ascontiguousarray(X.indptr, dtype=np.int64)
+        p = ctypes.c_void_p
+
+        def arr(a):
+            return a.ctypes.data_as(p)
+        lib.bin_csr(arr(data), ctypes.c_int32(data.dtype.itemsize),
+                    arr(indices), arr(indptr),
+                    ctypes.c_int64(X.shape[0]), ctypes.c_int64(X.shape[1]),
+                    ctypes.c_int32(len(self.groups)),
+                    arr(m["group_ptr"]), arr(m["feat_col"]),
+                    arr(m["feat_numbin"]), arr(m["feat_mostfreq"]),
+                    arr(m["feat_missing"]), arr(m["feat_iscat"]),
+                    arr(m["bounds_ptr"]), arr(m["bounds"]),
+                    arr(m["lut_ptr"]), arr(m["lut"]),
+                    out.ctypes.data_as(p),
+                    ctypes.c_int32(out.dtype.itemsize),
+                    ctypes.c_int64(out.shape[1]))
+        return True
 
     def _choose_multival(self, config: Config, X=None) -> bool:
         """Pick the multi-value (ELL) device layout when the dense [N, G]

@@ -123,6 +123,17 @@ static inline int32_t value_to_bin(
 }
 
 // out element width selected by out_bytes in {1, 2, 4}
+static inline void store_bin(void* out, int32_t out_bytes, int64_t pos,
+                             int64_t val) {
+  if (out_bytes == 1) {
+    static_cast<uint8_t*>(out)[pos] = static_cast<uint8_t>(val);
+  } else if (out_bytes == 2) {
+    static_cast<uint16_t*>(out)[pos] = static_cast<uint16_t>(val);
+  } else {
+    static_cast<int32_t*>(out)[pos] = static_cast<int32_t>(val);
+  }
+}
+
 void bin_rows(const double* X, int64_t n, int64_t stride, int32_t G,
               const int32_t* group_ptr, const int32_t* feat_col,
               const int32_t* feat_numbin, const int32_t* feat_mostfreq,
@@ -130,10 +141,6 @@ void bin_rows(const double* X, int64_t n, int64_t stride, int32_t G,
               const int64_t* bounds_ptr, const double* bounds,
               const int64_t* lut_ptr, const int32_t* lut,
               void* out, int32_t out_bytes, int64_t out_stride) {
-  uint8_t* out8 = static_cast<uint8_t*>(out);
-  uint16_t* out16 = static_cast<uint16_t*>(out);
-  int32_t* out32 = static_cast<int32_t*>(out);
-
   int32_t K = group_ptr[G];
   // LUT construction costs ~2k searches per feature: only worth it when
   // the row count amortizes it, and degrade to the plain search when the
@@ -188,17 +195,128 @@ void bin_rows(const double* X, int64_t n, int64_t stride, int32_t G,
           local += feat_numbin[k];
         }
       }
-      int64_t pos = i * out_stride + g;
-      if (out_bytes == 1) {
-        out8[pos] = static_cast<uint8_t>(val);
-      } else if (out_bytes == 2) {
-        out16[pos] = static_cast<uint16_t>(val);
-      } else {
-        out32[pos] = static_cast<int32_t>(val);
-      }
+      store_bin(out, out_bytes, i * out_stride + g, val);
     }
   }
   free(fluts);
+}
+
+// CSR by stored values: the same `out` as bin_rows on the dense matrix,
+// cell for cell, from a canonical CSR (columns ascending in a row, no
+// duplicates). A row's value in a group is decided by the LAST feature of
+// the group (in group order) whose bin leaves its most frequent bin, where
+// a feature without a stored value sits in the bin a zero falls in; only
+// the stored entries are binned, the zeros' bins once per feature.
+// data_bytes in {4, 8}: float32 or float64 stored values.
+void bin_csr(const void* data, int32_t data_bytes, const int32_t* indices,
+             const int64_t* indptr, int64_t n, int64_t ncols, int32_t G,
+             const int32_t* group_ptr, const int32_t* feat_col,
+             const int32_t* feat_numbin, const int32_t* feat_mostfreq,
+             const int32_t* feat_missing, const int32_t* feat_iscat,
+             const int64_t* bounds_ptr, const double* bounds,
+             const int64_t* lut_ptr, const int32_t* lut,
+             void* out, int32_t out_bytes, int64_t out_stride) {
+  const float* data32 = static_cast<const float*>(data);
+  const double* data64 = static_cast<const double*>(data);
+
+  int32_t K = group_ptr[G];
+  auto bin_of = [&](int32_t k, double v) {
+    return value_to_bin(v, feat_numbin[k], feat_missing[k], feat_iscat[k],
+                        bounds + bounds_ptr[k], lut + lut_ptr[k],
+                        lut_ptr[k + 1] - lut_ptr[k], nullptr);
+  };
+  // column -> flat feature index (-1: a column no group uses)
+  int32_t* col_k = static_cast<int32_t*>(malloc(sizeof(int32_t) * ncols));
+  int32_t* feat_group = static_cast<int32_t*>(malloc(sizeof(int32_t) * K));
+  int64_t* feat_local = static_cast<int64_t*>(malloc(sizeof(int64_t) * K));
+  int32_t* zero_bin = static_cast<int32_t*>(malloc(sizeof(int32_t) * K));
+  // a row's value in a group before any stored value is seen: the zero's
+  // bin for a feature alone, the sentinel 0 in a bundle
+  int64_t* group_zero = static_cast<int64_t*>(malloc(sizeof(int64_t) * G));
+  // per bundle: does any feature's zero leave its most frequent bin?
+  int32_t* group_zout = static_cast<int32_t*>(malloc(sizeof(int32_t) * G));
+  for (int64_t c = 0; c < ncols; ++c) col_k[c] = -1;
+  for (int32_t g = 0; g < G; ++g) {
+    int32_t k0 = group_ptr[g], k1 = group_ptr[g + 1];
+    bool multi = k1 - k0 > 1;
+    int64_t local = multi ? 1 : 0;
+    group_zero[g] = 0;
+    group_zout[g] = 0;
+    for (int32_t k = k0; k < k1; ++k) {
+      col_k[feat_col[k]] = k;
+      feat_group[k] = g;
+      feat_local[k] = local;
+      zero_bin[k] = bin_of(k, 0.0);
+      if (!multi) {
+        group_zero[g] = zero_bin[k];
+      } else if (zero_bin[k] != feat_mostfreq[k]) {
+        group_zout[g] = 1;
+      }
+      local += feat_numbin[k];
+    }
+  }
+
+#if defined(_OPENMP)
+#pragma omp parallel
+#endif
+  {
+    // the winning feature of each group in this row, and its value
+    int32_t* win_k = static_cast<int32_t*>(malloc(sizeof(int32_t) * G));
+    int64_t* win_v = static_cast<int64_t*>(malloc(sizeof(int64_t) * G));
+    // stored[k] == i + 1: feature k has a stored value in row i
+    int64_t* stored = static_cast<int64_t*>(calloc(K, sizeof(int64_t)));
+#if defined(_OPENMP)
+#pragma omp for schedule(static)
+#endif
+    for (int64_t i = 0; i < n; ++i) {
+      for (int32_t g = 0; g < G; ++g) {
+        win_k[g] = -1;
+        win_v[g] = group_zero[g];
+      }
+      for (int64_t e = indptr[i]; e < indptr[i + 1]; ++e) {
+        int64_t c = indices[e];
+        if (c < 0 || c >= ncols) continue;
+        int32_t k = col_k[c];
+        if (k < 0) continue;
+        double v = data_bytes == 4 ? static_cast<double>(data32[e])
+                                   : data64[e];
+        int32_t b = bin_of(k, v);
+        int32_t g = feat_group[k];
+        stored[k] = i + 1;
+        if (group_ptr[g + 1] - group_ptr[g] == 1) {
+          win_v[g] = b;
+        } else if (b != feat_mostfreq[k] && k > win_k[g]) {
+          win_k[g] = k;
+          win_v[g] = feat_local[k] + b;
+        }
+      }
+      for (int32_t g = 0; g < G; ++g) {
+        int64_t val = win_v[g];
+        if (group_zout[g]) {
+          // a zero that leaves the most frequent bin writes too, unless
+          // the row stores a value there: the last such feature after the
+          // stored winner takes the cell (none: the group's sentinel)
+          int32_t k0 = group_ptr[g], k1 = group_ptr[g + 1];
+          for (int32_t k = k1 - 1; k > win_k[g] && k >= k0; --k) {
+            if (zero_bin[k] != feat_mostfreq[k] && stored[k] != i + 1) {
+              val = feat_local[k] + zero_bin[k];
+              break;
+            }
+          }
+        }
+        store_bin(out, out_bytes, i * out_stride + g, val);
+      }
+    }
+    free(win_k);
+    free(win_v);
+    free(stored);
+  }
+  free(col_k);
+  free(feat_group);
+  free(feat_local);
+  free(zero_bin);
+  free(group_zero);
+  free(group_zout);
 }
 
 int32_t binrows_num_threads() {

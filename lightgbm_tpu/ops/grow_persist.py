@@ -114,7 +114,7 @@ def can_level_grow(gc) -> bool:
 # group count at or below which the smaller-child histogram accumulates
 # IN the split_pass kernel instead of a separate post-partition seg_hist
 # pass: with few (wide) groups the per-row MXU histogram work is cheap and
-# the extra kernel launch per split dominates (the Expo shape: 18 groups,
+# the extra kernel launch per split dominates (the Expo shape: 16 groups,
 # 254 launches/tree saved); with many groups the seg_hist economy (only
 # ~n/2 rows touched per level instead of all n) wins back the launch.
 # Either way the leaf-wise subtraction trick still applies — only WHERE
@@ -911,13 +911,15 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
         # [G, 256] group planes, derived ONCE per payload geometry and
         # reused across every level and tree (the per-feature path
         # re-gathered [2, F, 256] copies and re-applied FixHistogram
-        # tensors per split — at Expo's 648 features from 18 groups that
-        # was a 36x duplication on the hottest fixed cost)
+        # tensors per split — at Expo's 700 features from 16 groups that
+        # was a 44x duplication on the hottest fixed cost)
         from .pallas_scan import (BM_VALID_F, BM_VALID_R,
                                   build_block_scan_meta, scan_blocks)
-        blk = build_block_scan_meta(
-            group_of_np, ls_np, nb_np, mt_np, db_np, mf_np, needs_fix_np,
-            np.asarray(meta.penalty, np.float64), G, W)
+        with telemetry.scope("ops::BuildBlockScanMeta", category="ops",
+                             always=True):
+            blk = build_block_scan_meta(
+                group_of_np, ls_np, nb_np, mt_np, db_np, mf_np,
+                needs_fix_np, np.asarray(meta.penalty, np.float64), G, W)
         Gp, Wp = blk["masks"].shape[1:]
         blk_masks0 = jnp.asarray(blk["masks"])
         blk_owner = jnp.asarray(
@@ -2032,6 +2034,10 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
     gr.K = K
     gr.score64 = score64
     gr.wide = wide
+    # which mechanisms this grower's splits go through (the learner
+    # counts trees by them: blockscan_trees, inpass_hist_trees)
+    gr.block_scan = bool(bundled and not wide)
+    gr.inpass_hist = bool(inpass_hist)
     gr.use_level = use_level
     gr.S_MAXL = S_MAXL
     gr.health = health

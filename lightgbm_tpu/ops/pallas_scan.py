@@ -305,7 +305,7 @@ def scan_pair(scal, gb, hb, keep_r, keep_f, valid_r, valid_f, aux,
 #
 # For EFB-bundled datasets the per-feature formulation above is wasteful:
 # every bundled feature's row holds a COPY of its whole [W] group block
-# (Expo: 648 feature rows from 18 groups — a 36x duplication re-gathered
+# (Expo: 700 feature rows from 16 groups — a 44x duplication re-gathered
 # per split). The block kernel below scans the [G, W] group planes
 # DIRECTLY: each lane belongs to exactly one feature's bin window, the six
 # cumulative sums run per group block, and per-lane window quantities

@@ -643,6 +643,20 @@ class SerialTreeLearner:
         return assets, gr, driver
 
     @staticmethod
+    def _count_persist_trees(gr, k: int):
+        """Run record: k trees on the persist path, and by which of the
+        grower's mechanisms (split scan over the bundled group planes;
+        smaller-child histogram built inside split_pass)."""
+        telemetry.count("tree_learner::persist_scan_trees", float(k),
+                        category="tree_learner")
+        if gr.block_scan:
+            telemetry.count("tree_learner::blockscan_trees", float(k),
+                            category="tree_learner")
+        if gr.inpass_hist:
+            telemetry.count("tree_learner::inpass_hist_trees", float(k),
+                            category="tree_learner")
+
+    @staticmethod
     def _persist_init_carry(gr, assets, score0):
         """The first program that takes the host payload. The span is the
         dispatch's wall (the staging copy of ``pay0`` and that program's
@@ -660,9 +674,8 @@ class SerialTreeLearner:
         """K iterations on the persistent payload. Keeps (pay, score_pos)
         as a device carry on this learner; scores return to row order only
         in persist_finalize_scores()."""
-        telemetry.count("tree_learner::persist_scan_trees", float(k),
-                        category="tree_learner")
         assets, gr, driver = self._persist_cached(objective, k, bag_spec)
+        self._count_persist_trees(gr, k)
         pay = getattr(self, "_persist_carry", None)
         if pay is None:
             pay = self._persist_init_carry(gr, assets, score0)
@@ -695,10 +708,9 @@ class SerialTreeLearner:
         dance all inside the scan (the RF half of the fused boosting
         iteration). aux is [k, 2] f64 = (total_iter, 1/(total_iter+1));
         bias is the objective's constant init score."""
-        telemetry.count("tree_learner::persist_scan_trees", float(k),
-                        category="tree_learner")
         assets, gr, driver = self._persist_cached(objective, k,
                                                   mode="rf")
+        self._count_persist_trees(gr, k)
         pay = getattr(self, "_persist_carry", None)
         if pay is None:
             pay = self._persist_init_carry(gr, assets, score0)

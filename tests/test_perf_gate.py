@@ -371,14 +371,14 @@ def test_report_card_fraction_pinned_higgs_v5e():
 
 
 def test_report_card_fraction_pinned_expo_v5e():
-    # expo bundles 648 features into 18 byte groups: the plane traffic
-    # collapses but the split scan still walks all 648 features
+    # expo bundles 700 features into 16 byte groups: the plane traffic
+    # collapses but the split scan still walks all 700 features
     prof = get_profile("v5e")
     work = {"rows": 2_000_000, "iters": 96, "num_leaves": 255}
     snap = _snap(wall_ops=8.0, wall_other=1.0, program_total=8.0,
                  work=work)
     card = perfmodel.report_card(snap, "expo", profile=prof)
-    m = perfmodel.work_model(2_000_000, 18, 648, 96, 255)
+    m = perfmodel.work_model(2_000_000, 16, 700, 96, 255)
     t_hbm = m["bytes"] / 819e9
     t_comp = m["flops"] / (197e12 * perfmodel.F32_DERATE)
     expect = max(t_hbm, t_comp)

@@ -102,3 +102,122 @@ def test_sparse_predict_chunked_matches_dense():
     np.testing.assert_allclose(p_sparse[:20_000], p_dense, rtol=1e-12)
     c = bst.predict(X[:15_000], pred_contrib=True)
     assert c.shape == (15_000, f + 1)
+
+
+# ---------------------------------------------------------------------------
+# binning by stored values (PR 32): `from_sparse` writes only the rows that
+# have a stored value in a group, and must still equal the dense binning of
+# the same matrix cell for cell
+# ---------------------------------------------------------------------------
+
+def _onehot_blocks(rng, n, cards):
+    cols, base = [], 0
+    for card in cards:
+        cols.append(base + rng.integers(0, card, n))
+        base += card
+    cols = np.stack(cols, axis=1)
+    return scipy_sparse.csr_matrix(
+        (np.ones(cols.size), cols.ravel(),
+         np.arange(n + 1) * len(cards)), shape=(n, base))
+
+
+def _case_onehot(rng, n):
+    return _onehot_blocks(rng, n, [12, 31, 7, 60]), {}
+
+
+def _case_explicit_zeros_and_negatives(rng, n):
+    X = scipy_sparse.random(n, 40, density=0.05, format="csr",
+                            random_state=np.random.RandomState(5),
+                            data_rvs=lambda k: rng.normal(size=k))
+    X.data[::3] = 0.0                       # stored zeros stay stored
+    assert (X.data == 0).any() and (X.data < 0).any()
+    return X, {}
+
+
+def _case_nan(rng, n):
+    X = scipy_sparse.random(n, 30, density=0.08, format="csr",
+                            random_state=np.random.RandomState(6),
+                            data_rvs=lambda k: rng.normal(size=k))
+    X.data[::5] = np.nan
+    return X.astype(np.float32), {}         # float32 stored values too
+
+
+def _case_empty_columns(rng, n):
+    X = _onehot_blocks(rng, n, [9, 20]).tolil()
+    X[:, 3] = 0
+    X[:, 11] = 0
+    return scipy_sparse.hstack(
+        [X.tocsr(), scipy_sparse.csr_matrix((n, 4))]).tocsr(), {}
+
+
+def _case_most_frequent_bin_is_not_zero(rng, n):
+    # columns 0-2 are 1.0 on four rows of five: the most frequent bin holds
+    # the ones, and the rows WITHOUT a stored value are the ones that move
+    dense = np.zeros((n, 8))
+    for f in range(3):
+        dense[rng.random(n) < 0.8, f] = 1.0
+    dense[:, 3:] = _onehot_blocks(rng, n, [5]).toarray()
+    return scipy_sparse.csr_matrix(dense), {}
+
+
+def _case_conflicts_inside_a_bundle(rng, n):
+    # two one-hot blocks that overlap on a few rows in a hundred: with
+    # max_conflict_rate > 0 they share bundles and the later feature wins
+    X = _onehot_blocks(rng, n, [40]).tolil()
+    extra = rng.integers(0, 40, n)
+    for r in np.nonzero(rng.random(n) < 0.03)[0]:
+        X[r, extra[r]] = 1.0
+    return X.tocsr(), {"max_conflict_rate": 0.1}
+
+
+def _case_duplicates_not_canonical(rng, n):
+    rows = rng.integers(0, n, 6 * n)
+    cols = rng.integers(0, 25, 6 * n)
+    coo = scipy_sparse.coo_matrix((rng.normal(size=6 * n), (rows, cols)),
+                                  shape=(n, 25))
+    X = scipy_sparse.csr_matrix((coo.data, coo.col,
+                                 np.searchsorted(np.sort(coo.row),
+                                                 np.arange(n + 1))),
+                                shape=(n, 25))
+    assert not X.has_canonical_format
+    return X, {}
+
+
+_CSR_CASES = {f.__name__[6:]: f for f in (
+    _case_onehot, _case_explicit_zeros_and_negatives, _case_nan,
+    _case_empty_columns, _case_most_frequent_bin_is_not_zero,
+    _case_conflicts_inside_a_bundle, _case_duplicates_not_canonical)}
+
+
+@pytest.mark.parametrize("route", ["native", "numpy"])
+@pytest.mark.parametrize("case", sorted(_CSR_CASES))
+def test_binning_by_stored_values_equals_dense_binning(case, route,
+                                                       monkeypatch):
+    from lightgbm_tpu.data.dataset import BinnedDataset
+    took = []
+    real = BinnedDataset._push_sparse_native
+    monkeypatch.setattr(
+        BinnedDataset, "_push_sparse_native",
+        lambda self, X, out: took.append(
+            route == "native" and real(self, X, out)) or took[-1])
+    rng = np.random.default_rng(7)
+    X, extra = _CSR_CASES[case](rng, 3000)
+    before = X.copy()
+    monkeypatch.setattr(
+        scipy_sparse.csr_matrix, "todense",
+        lambda self, *a, **k: pytest.fail("from_sparse made a dense chunk"))
+    params = {"verbosity": -1, "min_data_in_leaf": 1, "min_data_in_bin": 1,
+              "tpu_multival": "off", **extra}
+    sp_ds = lgb.Dataset(X, params=dict(params)).construct()._inner
+    dn_ds = lgb.Dataset(before.toarray(), params=dict(params)) \
+        .construct()._inner
+    assert took == [route == "native"], "the native kernel did not build"
+    assert sp_ds.groups == dn_ds.groups
+    if case != "nan":
+        assert any(len(g) > 1 for g in sp_ds.groups) or \
+            case == "duplicates_not_canonical"
+    assert sp_ds.binned.dtype == dn_ds.binned.dtype
+    assert np.array_equal(sp_ds.binned, dn_ds.binned)
+    # the caller's matrix is left as it was handed over
+    assert np.array_equal(X.data, before.data, equal_nan=True)
+    assert np.array_equal(X.indices, before.indices)
