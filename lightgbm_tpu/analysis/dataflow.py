@@ -223,9 +223,12 @@ def is_narrowing(src, dst) -> bool:
 def _default_for_aval(aval, err: float = INF) -> AbsVal:
     dt = getattr(aval, "dtype", None)
     shape = tuple(getattr(aval, "shape", ()) or ())
+    try:
+        dt = None if dt is None else np.dtype(dt)
+    except TypeError:       # a DMA semaphore, a PRNG key: no numbers
+        dt = None
     if dt is None:
         return AbsVal(None, shape, Interval.top(), err)
-    dt = np.dtype(dt)
     if dt.kind == "b":
         return AbsVal(dt, shape, Interval(0.0, 1.0), 0.0)
     if dt.kind in "iu":

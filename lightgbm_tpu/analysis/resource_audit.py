@@ -214,10 +214,11 @@ def estimate_split_pass(shape: BenchShape, profile: DeviceProfile,
     WPA, C, _NP, nbw = _payload_geom(shape)
     E = C + 128
     G = shape.groups
-    # scratch_shapes: wbuf/obuf/rbuf + 4 FIFO slots (WP_LIVE <= WPA rows,
-    # one lane tile past E) + the partition's two control planes
-    scratch = ((3 * WPA * E + 4 * WPA * (E + 128)) * 4 + G * 16 * 64 * 4
-               + 2 * _ceil8(E // 128) * 128 * 4)
+    # scratch_shapes: wbuf/obuf + 4 FIFO slots (WP_LIVE <= WPA rows, one
+    # lane tile past E) + the drain's two open tiles + the partition's
+    # two control planes
+    scratch = ((2 * WPA * E + 4 * WPA * (E + 128) + 2 * WPA * 128) * 4
+               + G * 16 * 64 * 4 + 2 * _ceil8(E // 128) * 128 * 4)
     # decode temporaries: group-bin planes + the radix one-hot contraction
     temps = G * E * 4 + 64 * E * 2 + 2 * 16 * E * 2
     return _check(KernelEstimate(

@@ -259,11 +259,12 @@ def _payload_geometry(n: int, nbw: int, C: int, CR: int,
     WP = payload_weight_row(nbw, K, score64) + (1 if has_weight else 0)
     WPA = ((WP + 7) // 8) * 8
     if C <= 0:
-        # split_pass VMEM scales with WPA (7 chunk-sized u32 buffers + the
+        # split_pass VMEM scales with WPA (6 chunk-sized u32 buffers + the
         # hist accumulator + compaction temporaries). The kernel raises the
         # Mosaic scoped-VMEM limit to its footprint (v5e carries 128MB),
         # so chunks are sized for DMA-latency amortization, not the 16MB
-        # default: small chunks cost ~5 serialized DMA latencies each
+        # default: every chunk waits for its read and pays a step's fixed
+        # scalar work
         C = 16384 if WPA <= 56 else 8192
     NP = max(((n + 127) // 128 + 2) * 128 + C + 256,
              ((n + CR - 1) // CR) * CR)

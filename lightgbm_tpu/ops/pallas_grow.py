@@ -46,8 +46,14 @@ Mosaic kernels over a single transposed payload matrix:
         top-down) with no scratch buffer and no second pass — this replaces
         v1's scratch + copy-back design (ops/grow.py pass A + pass B).
     Chunk windows are DMAed at 128-aligned lane offsets and re-aligned in
-    VMEM with one dynamic roll; partial-lane writes blend read-modify-write
-    so neighbouring leaves' rows are untouched.
+    VMEM with one dynamic roll. The drain writes WHOLE 128-lane tiles,
+    each once, and reads nothing back: a block lies in its slot at the
+    sub-tile offset it is written at, the tile it shares with the block
+    before it is carried in VMEM from one chunk's partition to the next,
+    and the old payload is read in two tiles only, the segment's own ends
+    (where a neighbouring leaf's rows live), once, before any write. The
+    writes stay in flight under the next chunk's read, decision and
+    histogram (_make_segment_step has the hazard argument).
 
   * root_hist (static grid): one streaming pass building the root histogram
     and the gradient/hessian totals.
@@ -104,20 +110,24 @@ def _ceil8(x: int) -> int:
 # same numbers against the device profiles (telemetry/devices.py), so an
 # over-budget geometry fails `python -m lightgbm_tpu.analysis` instead of
 # OOMing the first real-TPU run. The default 16MB scoped-VMEM limit forces
-# small chunks whose cost is pure DMA latency (~5 serialized DMAs per
-# chunk); v5e cores carry 128MB of VMEM, so the limits are sized to each
-# kernel's actual footprint (buffers + Mosaic temporaries scale with E)
-# and C grows instead.
+# small chunks whose cost is DMA latency and per-step scalar work (every
+# chunk waits for its read); v5e cores carry 128MB of VMEM, so the limits
+# are sized to each kernel's actual footprint (buffers + Mosaic
+# temporaries scale with E) and C grows instead.
 
 def split_pass_vmem_bytes(WPA: int, E: int, G: int) -> int:
-    """split_pass / level_pass: 3 chunk-sized u32 buffers, 4 FIFO slots
-    one lane tile wider, the radix hist accumulator, and ~3 buffers of
+    """split_pass / level_pass: 2 chunk-sized u32 buffers (the DMA's
+    landing buffer and the re-aligned chunk's tile-addressable home), 4
+    FIFO slots one lane tile wider, the two open tiles the drain carries
+    from block to block, the radix hist accumulator, and ~3 buffers of
     Mosaic's own staging for the re-aligned chunk value and the dynamic
     rolls around it (the compiler's stack reads 44.1 MB at WPA 40,
     E 16512). The partition adds two [E / 128, 128] control planes and
-    otherwise works tile by tile in registers."""
+    otherwise works tile by tile in registers; the drain holds nothing:
+    it DMAs whole tiles from the slots and reads no payload back."""
     return int(min(96 << 20,
-                   3 * WPA * E * 4 + 4 * WPA * (E + 128) * 4
+                   2 * WPA * E * 4 + 4 * WPA * (E + 128) * 4
+                   + 2 * WPA * 128 * 4
                    + G * 16 * 64 * 4 + (20 << 20)
                    + 3 * WPA * E * 4 + 2 * _ceil8(E // 128) * 128 * 4))
 
@@ -203,18 +213,35 @@ def _compact_tiles(xs, cs):
 
 
 def _partition_chunk(src, R: int, gl, m, base_l, base_r, dst_l, dst_r,
-                     ctl, cnt):
+                     carry, ctl, cnt):
     """Stable two-sided partition of one chunk into a FIFO slot pair.
 
     src: VMEM ref [>= R, E] u32, chunk rows at lanes [0, m), m <= E - 128
     (the last lane tile is the DMA's alignment slack and holds no row);
     gl: [E] bool, the valid lanes that go left (the other valid lanes go
-    right). dst_l / dst_r: VMEM refs [>= R, E + 128]; afterwards dst_l
-    lanes [base_l, base_l + nL) hold the left rows in order and dst_r
-    lanes [base_r, base_r + nR) the right rows in order, every other lane
-    is undefined. The caller passes as bases the lane offsets the drain
-    will write the blocks at, so the drain blends a slot without rolling
-    it. ctl / cnt: VMEM scratch [>= E / 128, 128] i32.
+    right). dst_l / dst_r: VMEM refs [>= R, E + 128]. The caller passes as
+    bases the sub-tile lane offsets the drain will write the blocks at, so
+    a slot's tiles ARE payload tiles and the drain copies them whole:
+    slot lane 0 stands for the payload lane tile that holds the block's
+    first row. ctl / cnt: VMEM scratch [>= E / 128, 128] i32.
+
+    carry: VMEM ref [2, >= R, 128] u32, the tile each side has open.
+    carry[0] holds, in its lanes below base_l, what lies before the left
+    block in its first tile: the left rows of the blocks before it, and
+    below those the neighbouring leaf's rows. carry[1] holds, from lane
+    (base_r + nR) & 127 on, what lies behind the right block in its last
+    tile: the right blocks before it (they fill top-down) and the
+    neighbour above. Afterwards
+      - dst_l tiles [0, (base_l + nL) >> 7) are final payload tiles (the
+        first one continued from carry[0]); the tile still open is NOT in
+        the slot: it is the new carry[0], rows in lanes
+        [0, (base_l + nL) & 127);
+      - dst_r lanes [base_r, ...) hold the right rows in order and the
+        block's last tile ends in the old carry[1]: tiles
+        [1, (base_r + nR + 127) >> 7) are final, and tile 0 too where
+        base_r is 0; tile 0, whose lanes below base_r the next right
+        block fills, is the new carry[1].
+    Every other lane is undefined.
 
     One prefix sum serves both sides: with P the inclusive count of left
     rows inside the tile, a left row at tile lane j has j + 1 - P holes
@@ -250,9 +277,9 @@ def _partition_chunk(src, R: int, gl, m, base_l, base_r, dst_l, dst_r,
         full = (jnp.zeros_like(lane) + (d + n)) >= 128
         return off + n, jnp.where(full, rot, merged)
 
-    def group(t0, k, carry):
+    def group(t0, k, state):
         """Tiles t0 .. t0 + k - 1 (t0 traced, k static)."""
-        off_l, acc_l, off_r, acc_r = carry
+        off_l, acc_l, off_r, acc_r = state
         tile = [pl.ds(pl.multiple_of((t0 + s) * 128, 128), 128)
                 for s in range(k)]
         packed = _compact_tiles([src[0:R, tile[s]] for s in range(k)],
@@ -269,16 +296,19 @@ def _partition_chunk(src, R: int, gl, m, base_l, base_r, dst_l, dst_r,
     # payloads spill at 16 and read the same at 8
     G = 16 if R <= 16 else 8
     tiles = T - 1
-    zero = jnp.zeros((R, 128), U32)
-    carry = (base_l, zero, base_r, zero)
+    # the left side goes on in the tile the block before left open; what
+    # the right side's first tile holds below base_r is the next block's
+    state = (base_l, carry[0, 0:R, :], base_r, jnp.zeros((R, 128), U32))
     if tiles // G:
-        carry = jax.lax.fori_loop(
-            0, tiles // G, lambda gi, c: group(gi * G, G, c), carry)
+        state = jax.lax.fori_loop(
+            0, tiles // G, lambda gi, c: group(gi * G, G, c), state)
     if tiles % G:
-        carry = group(jnp.int32(tiles // G * G), tiles % G, carry)
-    off_l, acc_l, off_r, acc_r = carry
-    dst_l[0:R, pl.ds(pl.multiple_of((off_l >> 7) * 128, 128), 128)] = acc_l
-    dst_r[0:R, pl.ds(pl.multiple_of((off_r >> 7) * 128, 128), 128)] = acc_r
+        state = group(jnp.int32(tiles // G * G), tiles % G, state)
+    off_l, acc_l, off_r, acc_r = state
+    carry[0, 0:R, :] = acc_l
+    dst_r[0:R, pl.ds(pl.multiple_of((off_r >> 7) * 128, 128), 128)] = (
+        jnp.where(lane < (off_r & 127), acc_r, carry[1, 0:R, :]))
+    carry[1, 0:R, :] = dst_r[0:R, 0:128]
 
 
 def _unpack_group_bins(pay_block, plan):
@@ -375,6 +405,247 @@ def _align128(ptr):
 # split_pass
 # ---------------------------------------------------------------------------
 
+def _make_segment_step(C: int, G: int, plan, nbw: int, WP_LIVE: int,
+                       skip_hist: bool, skip_pack: bool):
+    """One grid step of the in-place partition of one payload segment:
+    the body split_pass and level_pass share.
+
+    Returns step(lo, sc, pay, hist, wbuf, obuf, slots, carry, ctl, tcnt,
+    st, sem_r, sem_w): ``lo`` is the step's number within the segment
+    (0 .. nch + 1), ``sc(k)`` its S_* scalar k, ``pay`` the payload in HBM
+    (read and written in place), ``hist`` the [G, 16, >= 64] accumulator.
+    Scratch: wbuf / obuf [WPA, E] u32, slots [4, R, E + 128] (2 x L / R),
+    carry [2, R, 128], ctl / tcnt [>= E / 128, 128] i32, st SMEM [11] i32,
+    sem_r one DMA semaphore, sem_w two (one a side); E = C + 128,
+    R = ceil8(WP_LIVE). The R - WP_LIVE pad rows of the last live sublane
+    tile ride the partition (the same vregs; nothing reads them), payload
+    rows from R on are never touched.
+
+    st: 0 fr, 1 br (read frontiers), 2 lf, 3 rf (write frontiers),
+        4 vl, 5 vr (the write frontiers with the blocks waiting in the
+        FIFO counted in: where the next left block starts and the next
+        right block ends), 6 nleft,
+        7+2p nL(slot pair p), 8+2p nR(slot pair p)
+
+    Step lo reads chunk lo (lo < nch) into slot pair lo % 2 and drains
+    block lo - 2 (2 <= lo < nch + 2) out of the same pair first. A left
+    block covers payload lanes [lf, lf + nL), a right block
+    [rf - nR, rf); _partition_chunk has laid each in its slot at the
+    sub-tile offset it has in the payload and has closed the tile it
+    shares with the block before it, so the drain is DMAs of whole tiles
+    from the slot to the payload and nothing else: the left side writes
+    the tiles below floor128(lf + nL), the right side those from
+    ceil128(rf - nR) up to ceil128(rf), each tile once. The tile a side
+    still has open (it holds the segment's edge and a neighbouring
+    leaf's rows at first: both edge tiles are read at lo == 0, before
+    any write, and nothing else of the old payload is ever read back)
+    stays in VMEM, in ``carry``, for the next block of that side. When
+    the last block has drained the frontiers meet inside one payload
+    tile, whose two parts are the two open tiles: merged and written
+    once, at lo == nch + 1. A run of 0 .. C / 128 + 1 tiles goes out as
+    the binary digits of its length, one DMA of 128 << k lanes a set bit.
+
+    The writes are started and left in flight; they are waited (the same
+    descriptors under the same conditions) just before this step's
+    partition refills the slot pair they read, or at the step's end when
+    there is no chunk left to read, so no DMA outlives its step. Hazards:
+      - payload lanes: a completed left tile lies wholly below lf + nL
+        <= fr and a right one at or above rf - nR >= br, so no write
+        covers a row not yet read (reads lead writes: the FIFO's
+        invariant); the open tiles are written only after the last read.
+        The chunk read's aligned E-wide window can overlap tiles being
+        written, but only in lanes outside [ptr, ptr + m), which
+        ``valid`` masks (old or new, a word there is some row's). The
+        two sides' runs are disjoint (lf + nL <= rf - nR), and the
+        closing tile is written after every other write has been
+        waited;
+      - VMEM: a slot pair is refilled only after the waits; the carry
+        tiles are read by no DMA but the closing one, which is waited
+        at once; the edge reads are waited before the first partition.
+      - between segments of one level_pass launch: every step has
+        waited its own DMAs, so the next segment's edge reads see the
+        neighbour's closed tile.
+    The Pallas interpreter copies at start(); tests/test_split_drain.py
+    also runs under InterpretParams(dma_execution_mode="on_wait"), where
+    a copy happens at its wait.
+    """
+    E = C + 128
+    R = _ceil8(WP_LIVE)
+    grad_row = nbw + 2
+    # a side completes at most C / 128 + 1 tiles a block
+    run_bits = range((C // 128 + 1).bit_length())
+
+    def step(lo, sc, pay, hist, wbuf, obuf, slots, carry, ctl, tcnt, st,
+             sem_r, sem_w):
+        nch = sc(S_NCH)
+        lane = _lane_iota(E)[0]
+
+        @pl.when(lo == 0)
+        def _init():
+            s0 = sc(S_S0)
+            s1 = s0 + sc(S_NL)
+            for k in (0, 2, 4):
+                st[k] = s0
+                st[k + 1] = s1
+            for k in range(6, 11):
+                st[k] = 0
+            hist[...] = jnp.zeros_like(hist)
+
+            @pl.when(nch > 0)
+            def _edges():
+                # the two tiles the segment shares with its neighbours
+                # (one tile twice where the segment is that short)
+                edges = [pltpu.make_async_copy(
+                    pay.at[0:R, pl.ds(_align128(x), 128)], carry.at[k],
+                    sem_r) for k, x in enumerate((s0, s1))]
+                for cp in edges:
+                    cp.start()
+                for cp in edges:
+                    cp.wait()
+
+        fr, br, lf, rf, vl, vr = (st[k] for k in range(6))
+        reading = lo < nch
+        draining = (lo >= 2) & (lo < nch + 2)
+        p = jax.lax.rem(lo, jnp.int32(2))   # this step's slot pair
+
+        # the chunk to read: from the end with the smaller write-space gap
+        m = jnp.minimum(jnp.int32(C), jax.lax.sub(br, fr))
+        use_front = (fr - vl) <= (vr - br)
+        ptr = jnp.where(use_front, fr, br - m)
+        al = _align128(ptr)
+        chunk = pltpu.make_async_copy(pay.at[:, pl.ds(al, E)], wbuf, sem_r)
+
+        # block lo - 2, waiting in slot pair p: its whole tiles, as
+        # (first slot tile, payload lane of slot lane 0, tiles) a side
+        nL_ = st[7 + 2 * p]
+        nR_ = st[8 + 2 * p]
+        dL = lf & 127
+        rs = rf - nR_
+        dR = rs & 127
+        t0R = (dR + 127) >> 7   # the first tile is the next block's too
+        sides = ((0, lf - dL, (dL + nL_) >> 7),
+                 (t0R, rs - dR, ((dR + nR_ + 127) >> 7) - t0R))
+        runs = []
+        for side, (t0, a0, cnt) in enumerate(sides):
+            for k in run_bits:
+                t = (t0 + ((cnt >> (k + 1)) << (k + 1))) * 128
+                runs.append((((cnt >> k) & 1) == 1, pltpu.make_async_copy(
+                    slots.at[2 * p + side, :,
+                             pl.ds(pl.multiple_of(t, 128), 128 << k)],
+                    pay.at[0:R, pl.ds(pl.multiple_of(a0 + t, 128),
+                                      128 << k)],
+                    sem_w.at[side])))
+
+        def wait_writes():
+            for on, cp in runs:
+                pl.when(on)(cp.wait)
+
+        @pl.when(reading)
+        def _start_read():
+            st[0] = jnp.where(use_front, fr + m, fr)
+            st[1] = jnp.where(use_front, br, br - m)
+            chunk.start()
+
+        @pl.when(draining)
+        def _drain():
+            for on, cp in runs:
+                pl.when(on)(cp.start)
+            st[2] = lf + nL_
+            st[3] = rs
+
+        @pl.when(reading)
+        def _read():
+            chunk.wait()
+            d = ptr - al
+            w = pltpu.roll(wbuf[...], jax.lax.sub(jnp.int32(E), d), 1)   # chunk rows at lanes 0..m
+            valid = lane < m
+
+            # decision (numerical; dense_bin.hpp:112 semantics). Bundled
+            # (EFB) features read the group byte: values outside the
+            # feature's [LS, LE) range belong to another bundle member or
+            # the sentinel — the row is at this feature's most_freq bin
+            word = w[0, :] * U32(0)
+            for r_ in range(nbw):
+                word = jnp.where(sc(S_WG) == r_, w[r_, :], word)
+            b_raw = ((word >> sc(S_SH).astype(U32))
+                     & sc(S_MASK).astype(U32)).astype(I32)
+            in_r = (b_raw >= sc(S_LS)) & (b_raw < sc(S_LE))
+            b = jnp.where(in_r, b_raw - sc(S_LS), sc(S_MF))
+            cmp_left = b <= sc(S_THR)
+            is_na = (sc(S_MT) == 2) & (b == sc(S_NB) - 1)
+            is_zero = (sc(S_MT) == 1) & (b == sc(S_DB))
+            # dl as a VECTOR compare: a scalar-bool broadcast lowers to an
+            # unsupported i8->i1 truncation in Mosaic
+            dlv = (jnp.zeros_like(b) + sc(S_DL)) > 0
+            gd = is_na | is_zero
+            go_left = (gd & dlv) | ((~gd) & cmp_left)
+
+            gl = valid & go_left
+            nL = jnp.sum(gl.astype(F32), dtype=F32).astype(I32)
+            nR = m - nL
+            st[6] = st[6] + nL
+
+            # smaller-child histogram
+            hm = (valid & (go_left == (sc(S_SMALL_L) > 0))).astype(F32)
+            grad = _f32r(w[grad_row, :]) * hm
+            hess = _f32r(w[grad_row + 1, :]) * hm
+            if not skip_hist:
+                bins_g = _unpack_group_bins(w, plan)
+                _hist_accum(hist, bins_g, grad, hess, G)
+
+            # slot pair p is refilled below: its drain has to be out
+            pl.when(draining)(wait_writes)
+            if skip_pack:
+                slots[2 * p, :, 0:E] = w[:R]
+                slots[2 * p + 1, :, 0:E] = w[:R]
+            else:
+                # obuf lends the chunk a tile-addressable home; the
+                # blocks land where the drain two steps on will write
+                # them: after the blocks still pending
+                obuf[...] = w
+                _partition_chunk(
+                    obuf, R, gl, m, vl & 127, (vr - nR) & 127,
+                    slots.at[2 * p], slots.at[2 * p + 1], carry, ctl, tcnt)
+            st[7 + 2 * p] = nL
+            st[8 + 2 * p] = nR
+            st[4] = vl + nL
+            st[5] = vr - nR
+
+        pl.when(draining & ~reading)(wait_writes)
+
+        @pl.when((lo == nch + 1) & (nch > 0))
+        def _close():
+            # lf == rf: the two open tiles are two parts of one
+            end = st[2]
+            l128 = jax.lax.broadcasted_iota(I32, (R, 128), 1)
+            carry[0] = jnp.where(l128 < (end & 127), carry[0], carry[1])
+            cp = pltpu.make_async_copy(
+                carry.at[0], pay.at[0:R, pl.ds(_align128(end), 128)],
+                sem_w.at[0])
+            cp.start()
+            cp.wait()
+
+    return step
+
+
+def _segment_scratch(WPA: int, E: int, R: int):
+    """scratch_shapes of _make_segment_step, in its argument order."""
+    TP = _ceil8(E // 128)
+    return [
+        pltpu.VMEM((WPA, E), U32),          # wbuf: the chunk as DMAed
+        pltpu.VMEM((WPA, E), U32),          # obuf: the chunk re-aligned
+        # FIFO slots (2 x L/R): whole sublane tiles, and one lane tile
+        # past E for the placement's last store
+        pltpu.VMEM((4, R, E + 128), U32),
+        pltpu.VMEM((2, R, 128), U32),       # carry: each side's open tile
+        pltpu.VMEM((TP, 128), I32),         # ctl: control words
+        pltpu.VMEM((TP, 128), I32),         # tcnt: rows left a tile
+        pltpu.SMEM((11,), I32),             # st
+        pltpu.SemaphoreType.DMA,            # sem_r
+        pltpu.SemaphoreType.DMA((2,)),      # sem_w: one a side
+    ]
+
+
 def make_split_pass(WPA: int, NP: int, G: int, plan, nbw: int,
                     C: int = 8192, interpret: bool = False,
                     wp_live: int = 0,
@@ -387,38 +658,24 @@ def make_split_pass(WPA: int, NP: int, G: int, plan, nbw: int,
     wp_live: how many leading payload rows carry per-row state that must
     PERMUTE with the partition (bins + label/rid/grad/hess + all score and
     snapshot rows — everything multiclass adds); defaults to the
-    single-score layout nbw + 5. Rows past wp_live are padding and pass
-    through untouched.
+    single-score layout nbw + 5. Rows past wp_live are padding: those of
+    the last live sublane tile ride along, the others are never touched.
 
     Returns fn(pay, scalars_i32) -> (pay', hist [G*256, 2] f32, n_left).
     """
     assert WPA % 8 == 0, "payload row count must be padded to 8"
     E = C + 128
-    TP = _ceil8(E // 128)
-    grad_row = nbw + 2
     WP_LIVE = wp_live or (nbw + 5)
     assert WP_LIVE <= WPA
+    step = _make_segment_step(C, G, plan, nbw, WP_LIVE, _skip_hist,
+                              _skip_pack)
 
-    def kernel(ns, pay_in, pay_out, hist_ref, cnt_ref,
-               wbuf, obuf, rbuf, slots, ctl, tcnt, st, sem_r, sem_w,
-               sem_rmw):
-        # st (SMEM i32): 0 fr, 1 br, 2 lf, 3 rf, 4 pendL, 5 pendR,
-        #                6 nleft, 7+2p nL(slot p), 8+2p nR(slot p)
+    def kernel(ns, pay_in, pay_out, hist_ref, cnt_ref, *scratch):
+        st, sem_r = scratch[-3:-1]
         i = pl.program_id(0)
-        nch = ns[S_NCH]
-        nch2 = jax.lax.add(nch, jnp.int32(2))
-        lane = _lane_iota(E)[0]
 
         @pl.when(i == 0)
-        def _init():
-            st[0] = ns[S_S0]
-            st[1] = ns[S_S0] + ns[S_NL]
-            st[2] = ns[S_S0]
-            st[3] = ns[S_S0] + ns[S_NL]
-            st[4] = 0
-            st[5] = 0
-            st[6] = 0
-            hist_ref[...] = jnp.zeros_like(hist_ref)
+        def _seed():
             if interpret:
                 # on hardware pay_out IS pay_in (input_output_aliases) and
                 # every read below goes through pay_out; interpreter mode
@@ -427,141 +684,14 @@ def make_split_pass(WPA: int, NP: int, G: int, plan, nbw: int,
                 cpi.start()
                 cpi.wait()
 
-        # ---- drain phase first: write slot (i-2)%2 ----------------------
-        # (drain before read so the read below may refill the same slot)
-        @pl.when((i >= 2) & (i < nch2))
-        def _drain():
-            p = jax.lax.rem(i, jnp.int32(2))  # == (i-2) % 2
-            nL_ = jnp.where(p == 0, st[7], st[9])
-            nR_ = jnp.where(p == 0, st[8], st[10])
-            src_l = jnp.where(p == 0, slots[0, 0:WP_LIVE, 0:E],
-                              slots[2, 0:WP_LIVE, 0:E])
-            src_r = jnp.where(p == 0, slots[1, 0:WP_LIVE, 0:E],
-                              slots[3, 0:WP_LIVE, 0:E])
+        step(i, lambda k: ns[k], pay_out, hist_ref, *scratch)
 
-            # left block: slot lanes [dL, dL+nL) -> payload [lf, lf+nL)
-            lf = st[2]
-            al = _align128(lf)
-            dL = lf - al
-            cp = pltpu.make_async_copy(
-                pay_out.at[:, pl.ds(al, E)], rbuf, sem_rmw)
-            cp.start()
-            cp.wait()
-            sel = (lane >= dL) & (lane < dL + nL_)
-            obuf[:WP_LIVE] = jnp.where(sel[None, :], src_l,
-                                       rbuf[:WP_LIVE])
-            if WP_LIVE < WPA:
-                obuf[WP_LIVE:] = rbuf[WP_LIVE:]
-            cpw = pltpu.make_async_copy(
-                obuf, pay_out.at[:, pl.ds(al, E)], sem_w)
-            cpw.start()
-            cpw.wait()
-            st[2] = lf + nL_
-            st[4] = st[4] - nL_
-
-            # right block: slot lanes [dR, dR+nR) -> payload [rf-nR, rf)
-            rf = st[3]
-            rs = rf - nR_
-            al2 = _align128(rs)
-            dR = rs - al2
-            cp2 = pltpu.make_async_copy(
-                pay_out.at[:, pl.ds(al2, E)], rbuf, sem_rmw)
-            cp2.start()
-            cp2.wait()
-            sel2 = (lane >= dR) & (lane < dR + nR_)
-            obuf[:WP_LIVE] = jnp.where(sel2[None, :], src_r,
-                                       rbuf[:WP_LIVE])
-            if WP_LIVE < WPA:
-                obuf[WP_LIVE:] = rbuf[WP_LIVE:]
-            cpw2 = pltpu.make_async_copy(
-                obuf, pay_out.at[:, pl.ds(al2, E)], sem_w)
-            cpw2.start()
-            cpw2.wait()
-            st[3] = rf - nR_
-            st[5] = st[5] - nR_
-
-        # ---- read + process phase (steps 0 .. nch-1) --------------------
-        @pl.when(i < nch)
-        def _read():
-            fr = st[0]
-            br = st[1]
-            front_gap = fr - st[2] - st[4]   # virtual: pending included
-            back_gap = st[3] - st[5] - br
-            m = jnp.minimum(jnp.int32(C), jax.lax.sub(br, fr))
-            use_front = front_gap <= back_gap
-            ptr = jnp.where(use_front, fr, br - m)
-            st[0] = jnp.where(use_front, fr + m, fr)
-            st[1] = jnp.where(use_front, br, br - m)
-
-            al = _align128(ptr)
-            cp = pltpu.make_async_copy(
-                pay_out.at[:, pl.ds(al, E)], wbuf, sem_r)
-            cp.start()
-            cp.wait()
-            d = ptr - al
-            w = pltpu.roll(wbuf[...], jax.lax.sub(jnp.int32(E), d), 1)   # chunk rows at lanes 0..m
-            valid = lane < m
-
-            # decision (numerical; dense_bin.hpp:112 semantics). Bundled
-            # (EFB) features read the group byte: values outside the
-            # feature's [LS, LE) range belong to another bundle member or
-            # the sentinel — the row is at this feature's most_freq bin
-            word = w[0, :] * U32(0)
-            for r_ in range(nbw):
-                word = jnp.where(ns[S_WG] == r_, w[r_, :], word)
-            b_raw = ((word >> ns[S_SH].astype(U32))
-                     & ns[S_MASK].astype(U32)).astype(I32)
-            in_r = (b_raw >= ns[S_LS]) & (b_raw < ns[S_LE])
-            b = jnp.where(in_r, b_raw - ns[S_LS], ns[S_MF])
-            cmp_left = b <= ns[S_THR]
-            is_na = (ns[S_MT] == 2) & (b == ns[S_NB] - 1)
-            is_zero = (ns[S_MT] == 1) & (b == ns[S_DB])
-            # dl as a VECTOR compare: a scalar-bool broadcast lowers to an
-            # unsupported i8->i1 truncation in Mosaic
-            dlv = (jnp.zeros_like(b) + ns[S_DL]) > 0
-            gd = is_na | is_zero
-            go_left = (gd & dlv) | ((~gd) & cmp_left)
-
-            gl = valid & go_left
-            nL = jnp.sum(gl.astype(F32), dtype=F32).astype(I32)
-            nR = m - nL
-            st[6] = st[6] + nL
-
-            # smaller-child histogram
-            hm = (valid & (go_left == (ns[S_SMALL_L] > 0))).astype(F32)
-            grad = _f32r(w[grad_row, :]) * hm
-            hess = _f32r(w[grad_row + 1, :]) * hm
-            if not _skip_hist:
-                bins_g = _unpack_group_bins(w, plan)
-                _hist_accum(hist_ref, bins_g, grad, hess, G)
-
-            # pack both sides into this step's FIFO slot pair; obuf is
-            # idle between drains and lends the chunk a tile-addressable
-            # home
-            pr = jax.lax.rem(i, jnp.int32(2))
-            if _skip_pack:
-                slots[2 * pr, 0:WP_LIVE, 0:E] = w[:WP_LIVE]
-                slots[2 * pr + 1, 0:WP_LIVE, 0:E] = w[:WP_LIVE]
-            else:
-                obuf[...] = w
-                # the blocks land where the drain two steps on will
-                # write them: after the blocks still pending
-                _partition_chunk(
-                    obuf, WP_LIVE, gl, m,
-                    (st[2] + st[4]) & 127, (st[3] - st[5] - nR) & 127,
-                    slots.at[2 * pr], slots.at[2 * pr + 1], ctl, tcnt)
-            st[7 + 2 * pr] = nL
-            st[8 + 2 * pr] = nR
-            st[4] = st[4] + nL
-            st[5] = st[5] + nR
-
-        @pl.when(i == jax.lax.add(nch, jnp.int32(1)))
+        @pl.when(i == jax.lax.add(ns[S_NCH], jnp.int32(1)))
         def _fin():
             cnt_ref[0] = st[6]
 
-    E_ = C + 128
     _cparams = CompilerParams(
-        vmem_limit_bytes=split_pass_vmem_bytes(WPA, E_, G))
+        vmem_limit_bytes=split_pass_vmem_bytes(WPA, E, G))
 
     @jax.jit
     def split_pass(pay, scalars):
@@ -592,20 +722,7 @@ def make_split_pass(WPA: int, NP: int, G: int, plan, nbw: int,
                     pl.BlockSpec((1,), lambda i, s: (i * 0,),
                                  memory_space=pltpu.SMEM),
                 ],
-                scratch_shapes=[
-                    pltpu.VMEM((WPA, E), U32),     # wbuf
-                    pltpu.VMEM((WPA, E), U32),     # obuf
-                    pltpu.VMEM((WPA, E), U32),     # rbuf
-                    # FIFO slots (2 x L/R): whole sublane tiles, and
-                    # one lane tile past E for the placement's last store
-                    pltpu.VMEM((4, _ceil8(WP_LIVE), E + 128), U32),
-                    pltpu.VMEM((TP, 128), I32),    # ctl: control words
-                    pltpu.VMEM((TP, 128), I32),    # tcnt: rows left a tile
-                    pltpu.SMEM((12,), I32),        # st
-                    pltpu.SemaphoreType.DMA,
-                    pltpu.SemaphoreType.DMA,
-                    pltpu.SemaphoreType.DMA,
-                ],
+                scratch_shapes=_segment_scratch(WPA, E, _ceil8(WP_LIVE)),
             ),
             out_shape=[
                 jax.ShapeDtypeStruct((WPA, NP), U32),
@@ -632,20 +749,21 @@ def make_level_pass(WPA: int, NP: int, G: int, plan, nbw: int,
 
     One pallas_call partitions the payload segments of up to ``S_max``
     splitting leaves (slots) and accumulates each slot's smaller-child
-    histogram — the per-split kernel's logic with the slot id derived
-    per grid step from prefetched step tables, so a whole tree level
-    costs ONE device-program launch instead of one per split (the
-    launch/dispatch overhead that dominated EFB-bundled shapes like
-    Expo: ~254 launches per 255-leaf tree).
+    histogram — the per-split kernel's steps (_make_segment_step) with
+    the slot id derived per grid step from prefetched step tables, so a
+    whole tree level costs ONE device-program launch instead of one per
+    split (the launch/dispatch overhead that dominated EFB-bundled
+    shapes like Expo: ~254 launches per 255-leaf tree).
 
     Per-slot scalars arrive as one [S_max, 16] i32 matrix in S_* column
     order (columns 15 unused); ``slot_of_step`` [T_max] and
     ``base_of_slot`` [S_max] map the flat dynamic grid onto (slot,
     local step): slot j owns steps [base[j], base[j] + nch_j + 2) and
     runs init / read / 2-deep-FIFO drain / fin exactly like
-    make_split_pass. Slots' segments are disjoint and the grid is
-    sequential, so the in-place two-ended writeback stays safe; the
-    payload keeps its input_output_aliases (in-place contract).
+    make_split_pass. Slots' segments are disjoint, the grid is
+    sequential and a step leaves no DMA in flight, so the in-place
+    two-ended writeback stays safe; the payload keeps its
+    input_output_aliases (in-place contract).
 
     Returns fn(pay, scal_mat, slot_of_step, base_of_slot, grid) ->
     (pay', hist [S_max, G, 16, 64] raw accumulator, n_left [S_max]).
@@ -654,19 +772,16 @@ def make_level_pass(WPA: int, NP: int, G: int, plan, nbw: int,
     """
     assert WPA % 8 == 0, "payload row count must be padded to 8"
     E = C + 128
-    TP = _ceil8(E // 128)
-    grad_row = nbw + 2
     WP_LIVE = wp_live or (nbw + 5)
     assert WP_LIVE <= WPA
+    step = _make_segment_step(C, G, plan, nbw, WP_LIVE, _skip_hist, False)
 
     def kernel(sm, so, bo, pay_in, pay_out, hist_out, cnt_ref,
-               hacc, wbuf, obuf, rbuf, slots, ctl, tcnt, st, sem_r, sem_w,
-               sem_rmw, sem_h):
+               hacc, sem_h, *scratch):
+        st, sem_r = scratch[-3:-1]
         i = pl.program_id(0)
         j = so[i]                       # slot of this step
         lo = i - bo[j]                  # local step within the slot
-        nch = sm[j, S_NCH]
-        lane = _lane_iota(E)[0]
 
         @pl.when(i == 0)
         def _seed():
@@ -677,136 +792,17 @@ def make_level_pass(WPA: int, NP: int, G: int, plan, nbw: int,
                 cpi.start()
                 cpi.wait()
 
-        @pl.when(lo == 0)
-        def _init():
-            st[0] = sm[j, S_S0]
-            st[1] = sm[j, S_S0] + sm[j, S_NL]
-            st[2] = sm[j, S_S0]
-            st[3] = sm[j, S_S0] + sm[j, S_NL]
-            st[4] = 0
-            st[5] = 0
-            st[6] = 0
-            hacc[...] = jnp.zeros_like(hacc)
+        step(lo, lambda k: sm[j, k], pay_out, hacc, *scratch)
 
-        # ---- drain phase first: write FIFO slot (lo-2)%2 ----------------
-        @pl.when((lo >= 2) & (lo < nch + 2))
-        def _drain():
-            p = jax.lax.rem(lo, jnp.int32(2))
-            nL_ = jnp.where(p == 0, st[7], st[9])
-            nR_ = jnp.where(p == 0, st[8], st[10])
-            src_l = jnp.where(p == 0, slots[0, 0:WP_LIVE, 0:E],
-                              slots[2, 0:WP_LIVE, 0:E])
-            src_r = jnp.where(p == 0, slots[1, 0:WP_LIVE, 0:E],
-                              slots[3, 0:WP_LIVE, 0:E])
-
-            lf = st[2]
-            al = _align128(lf)
-            dL = lf - al
-            cp = pltpu.make_async_copy(
-                pay_out.at[:, pl.ds(al, E)], rbuf, sem_rmw)
-            cp.start()
-            cp.wait()
-            sel = (lane >= dL) & (lane < dL + nL_)
-            obuf[:WP_LIVE] = jnp.where(sel[None, :], src_l,
-                                       rbuf[:WP_LIVE])
-            if WP_LIVE < WPA:
-                obuf[WP_LIVE:] = rbuf[WP_LIVE:]
-            cpw = pltpu.make_async_copy(
-                obuf, pay_out.at[:, pl.ds(al, E)], sem_w)
-            cpw.start()
-            cpw.wait()
-            st[2] = lf + nL_
-            st[4] = st[4] - nL_
-
-            rf = st[3]
-            rs = rf - nR_
-            al2 = _align128(rs)
-            dR = rs - al2
-            cp2 = pltpu.make_async_copy(
-                pay_out.at[:, pl.ds(al2, E)], rbuf, sem_rmw)
-            cp2.start()
-            cp2.wait()
-            sel2 = (lane >= dR) & (lane < dR + nR_)
-            obuf[:WP_LIVE] = jnp.where(sel2[None, :], src_r,
-                                       rbuf[:WP_LIVE])
-            if WP_LIVE < WPA:
-                obuf[WP_LIVE:] = rbuf[WP_LIVE:]
-            cpw2 = pltpu.make_async_copy(
-                obuf, pay_out.at[:, pl.ds(al2, E)], sem_w)
-            cpw2.start()
-            cpw2.wait()
-            st[3] = rf - nR_
-            st[5] = st[5] - nR_
-
-        # ---- read + process phase (local steps 0 .. nch-1) --------------
-        @pl.when(lo < nch)
-        def _read():
-            fr = st[0]
-            br = st[1]
-            front_gap = fr - st[2] - st[4]
-            back_gap = st[3] - st[5] - br
-            m = jnp.minimum(jnp.int32(C), jax.lax.sub(br, fr))
-            use_front = front_gap <= back_gap
-            ptr = jnp.where(use_front, fr, br - m)
-            st[0] = jnp.where(use_front, fr + m, fr)
-            st[1] = jnp.where(use_front, br, br - m)
-
-            al = _align128(ptr)
-            cp = pltpu.make_async_copy(
-                pay_out.at[:, pl.ds(al, E)], wbuf, sem_r)
-            cp.start()
-            cp.wait()
-            d = ptr - al
-            w = pltpu.roll(wbuf[...], jax.lax.sub(jnp.int32(E), d), 1)
-            valid = lane < m
-
-            word = w[0, :] * U32(0)
-            for r_ in range(nbw):
-                word = jnp.where(sm[j, S_WG] == r_, w[r_, :], word)
-            b_raw = ((word >> sm[j, S_SH].astype(U32))
-                     & sm[j, S_MASK].astype(U32)).astype(I32)
-            in_r = (b_raw >= sm[j, S_LS]) & (b_raw < sm[j, S_LE])
-            b = jnp.where(in_r, b_raw - sm[j, S_LS], sm[j, S_MF])
-            cmp_left = b <= sm[j, S_THR]
-            is_na = (sm[j, S_MT] == 2) & (b == sm[j, S_NB] - 1)
-            is_zero = (sm[j, S_MT] == 1) & (b == sm[j, S_DB])
-            dlv = (jnp.zeros_like(b) + sm[j, S_DL]) > 0
-            gd = is_na | is_zero
-            go_left = (gd & dlv) | ((~gd) & cmp_left)
-
-            gl = valid & go_left
-            nL = jnp.sum(gl.astype(F32), dtype=F32).astype(I32)
-            nR = m - nL
-            st[6] = st[6] + nL
-
-            hm = (valid & (go_left == (sm[j, S_SMALL_L] > 0))).astype(F32)
-            grad = _f32r(w[grad_row, :]) * hm
-            hess = _f32r(w[grad_row + 1, :]) * hm
-            if not _skip_hist:
-                bins_g = _unpack_group_bins(w, plan)
-                _hist_accum(hacc, bins_g, grad, hess, G)
-
-            pr = jax.lax.rem(lo, jnp.int32(2))
-            obuf[...] = w
-            _partition_chunk(
-                obuf, WP_LIVE, gl, m,
-                (st[2] + st[4]) & 127, (st[3] - st[5] - nR) & 127,
-                slots.at[2 * pr], slots.at[2 * pr + 1], ctl, tcnt)
-            st[7 + 2 * pr] = nL
-            st[8 + 2 * pr] = nR
-            st[4] = st[4] + nL
-            st[5] = st[5] + nR
-
-        @pl.when(lo == jax.lax.add(nch, jnp.int32(1)))
+        @pl.when(lo == jax.lax.add(sm[j, S_NCH], jnp.int32(1)))
         def _fin():
             cnt_ref[j] = st[6]
             cph = pltpu.make_async_copy(hacc, hist_out.at[j], sem_h)
             cph.start()
             cph.wait()
 
-    E_ = C + 128
     _cparams = CompilerParams(
-        vmem_limit_bytes=split_pass_vmem_bytes(WPA, E_, G))
+        vmem_limit_bytes=split_pass_vmem_bytes(WPA, E, G))
 
     @jax.jit
     def level_pass(pay, scal_mat, slot_of_step, base_of_slot, grid):
@@ -831,18 +827,8 @@ def make_level_pass(WPA: int, NP: int, G: int, plan, nbw: int,
                 ],
                 scratch_shapes=[
                     pltpu.VMEM((G, 16, HIST_LANES_PAD), F32),  # hist acc
-                    pltpu.VMEM((WPA, E), U32),      # wbuf
-                    pltpu.VMEM((WPA, E), U32),      # obuf
-                    pltpu.VMEM((WPA, E), U32),      # rbuf
-                    pltpu.VMEM((4, _ceil8(WP_LIVE), E + 128), U32),  # FIFO slots
-                    pltpu.VMEM((TP, 128), I32),     # ctl
-                    pltpu.VMEM((TP, 128), I32),     # tcnt
-                    pltpu.SMEM((12,), I32),         # st
-                    pltpu.SemaphoreType.DMA,
-                    pltpu.SemaphoreType.DMA,
-                    pltpu.SemaphoreType.DMA,
-                    pltpu.SemaphoreType.DMA,
-                ],
+                    pltpu.SemaphoreType.DMA,                   # sem_h
+                ] + _segment_scratch(WPA, E, _ceil8(WP_LIVE)),
             ),
             out_shape=[
                 jax.ShapeDtypeStruct((WPA, NP), U32),

@@ -8,7 +8,8 @@ each ``pallas_call`` site at the geometry the code itself builds for a real
 configuration — the persist grower's kernels for HIGGS (10.5M x 28,
 255 bins, 255 leaves), the same payload with a finite ``max_depth`` for the
 level kernels, an EFB-bundled Expo-like payload (11M rows) for the
-block scan, and the MS-LTR payload (137 features: 40 live rows, whole
+block scan, the benchmark's Expo cell's own split_pass (9 live rows, a
+nibble slot, the histogram in the pass), and the MS-LTR payload (137 features: 40 live rows, whole
 sublane tiles, so split_pass has no spare sublane) — plus the whole fused
 k=16 scan driver. Nothing runs, so they
 say nothing about results or times; ``chip_smoke.py`` does that on the chip.
@@ -35,7 +36,7 @@ from lightgbm_tpu.data.synth import (make_expo_like, make_higgs_like,
                                       make_ltr_like)
 from lightgbm_tpu.objectives import create_objective
 from lightgbm_tpu.ops import grow_persist as gp
-from lightgbm_tpu.ops.pallas_grow import N_SCALARS
+from lightgbm_tpu.ops.pallas_grow import N_SCALARS, make_split_pass
 from lightgbm_tpu.ops.pallas_histogram import hist_window
 from lightgbm_tpu.ops.pallas_scan import (ScanLayout, build_block_scan_meta,
                                           scan_blocks, scan_pair)
@@ -44,6 +45,7 @@ from lightgbm_tpu.treelearner.serial import SerialTreeLearner
 HIGGS_ROWS = 10_500_000     # docs/Experiments.rst: HIGGS
 EXPO_ROWS = 11_000_000      # docs/Experiments.rst: Expo
 MSLTR_ROWS = 2_270_296      # docs/Experiments.rst: MS LTR
+EXPO_CELL_ROWS = 16_500_000  # benchmark/configs/expo.json: 1.5 x Expo
 LEVEL_DEPTH = 8             # max_depth; with num_leaves = 2^8 the level
 #                             phase engages (bench.py's level configuration)
 SAMPLE_ROWS = 20_000        # rows actually binned: the bin structure only
@@ -175,6 +177,26 @@ def _split_pass_msltr(higgs, expo, S, msltr):
         S(msltr.pay, jnp.uint32), S((N_SCALARS,), jnp.int32))
 
 
+def _split_pass_expo(higgs, expo, S):
+    """expo.train_steady's own kernel, at the storage groups EFB finds on
+    the cell's rows (PERF.md, section 4: 16 groups of these level counts,
+    two of them narrow enough for a nibble): 9 live payload rows in 16,
+    the smaller child's histogram inside the pass. The `expo` fixture's
+    20,000 sampled rows bundle into fewer groups and a payload half as
+    tall, so the kernel is built here from the widths."""
+    widths = [256, 256, 8, 13, 23, 32, 128, 128,
+              28, 32, 36, 40, 44, 48, 52, 56]
+    plan, nbw = gp._payload_plan(widths)
+    WPA, C, NP = gp._payload_geometry(EXPO_CELL_ROWS, nbw, 0, 16384)
+    wp_live = gp.payload_weight_row(nbw, 1)
+    assert (WPA, C, nbw, wp_live) == (16, 16384, 4, 9)
+    assert any(mk == 15 for _, _, mk in plan)
+    assert len(widths) <= gp.SEG_HIST_MIN_GROUPS
+    return make_split_pass(WPA, NP, len(widths), plan, nbw, C=C,
+                           wp_live=wp_live), (
+        S((WPA, NP), jnp.uint32), S((N_SCALARS,), jnp.int32))
+
+
 def _seg_hist(higgs, expo, S):
     return higgs.growers[-1]._seg_hist, (
         S(higgs.pay, jnp.uint32), S((), jnp.int32), S((), jnp.int32))
@@ -227,7 +249,7 @@ def _fused_driver(higgs, expo, S):
 
 @pytest.mark.parametrize("case", [
     _hist_window, _scan_pair, _scan_blocks, _split_pass, _split_pass_msltr,
-    _level_pass, _level_pass_inpass, _level_seg_hist, _seg_hist, _root_hist,
+    _split_pass_expo, _level_pass, _level_pass_inpass, _level_seg_hist, _seg_hist, _root_hist,
     _fused_driver,
 ], ids=lambda f: f.__name__.lstrip("_"))
 def test_compiles_for_v5e(case, one_chip, higgs, expo, request):

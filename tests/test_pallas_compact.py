@@ -3,7 +3,11 @@ _partition_chunk), alone, in the Pallas interpreter: for any mask the left
 rows must land packed from lane base_l of the left slot and the right rows
 from lane base_r of the right slot, each side in row order, every word bit
 for bit — the numpy stable partition. Trees are bit-identical only because
-this permutation is."""
+this permutation is. The tile a side has open is carried from chunk to
+chunk: the left block goes on in the tile handed in and hands on the one
+it leaves open, the right block's last tile ends in the tile handed in
+and its first tile is handed on (tests/test_split_drain.py runs the whole
+writeback)."""
 import functools
 
 import numpy as np
@@ -52,25 +56,28 @@ def _call(R, T):
     E = T * 128
     TP = -(-T // 8) * 8
 
-    def kernel(ms, w_ref, keep_ref, out_l, out_r, ctl, cnt):
+    def kernel(ms, w_ref, keep_ref, open_ref, out_l, out_r, carry, ctl,
+               cnt):
         m = ms[0]
         lane = jax.lax.broadcasted_iota(I32, (1, E), 1)[0]
         gl = (lane < m) & (keep_ref[0, :] > 0)
         out_l[...] = jnp.zeros_like(out_l)
         out_r[...] = jnp.zeros_like(out_r)
+        carry[...] = open_ref[...]
         pg._partition_chunk(w_ref, R, gl, m, ms[1], ms[2], out_l, out_r,
-                            ctl, cnt)
+                            carry, ctl, cnt)
 
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
-    return jax.jit(lambda m, w, keep: pl.pallas_call(
+    return jax.jit(lambda m, w, keep, tiles: pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(1,),
-            in_specs=[vmem, vmem], out_specs=[vmem, vmem],
+            in_specs=[vmem, vmem, vmem], out_specs=[vmem, vmem, vmem],
             scratch_shapes=[pltpu.VMEM((TP, 128), I32),
                             pltpu.VMEM((TP, 128), I32)]),
-        out_shape=[jax.ShapeDtypeStruct((R, E + 128), U32)] * 2,
-        interpret=True)(m, w, keep))
+        out_shape=[jax.ShapeDtypeStruct((R, E + 128), U32)] * 2
+        + [jax.ShapeDtypeStruct((2, R, 128), U32)],
+        interpret=True)(m, w, keep, tiles))
 
 
 @functools.lru_cache(maxsize=None)
@@ -79,9 +86,11 @@ def _partitioned(R, T, mask, bases):
     rng = np.random.default_rng(1000 * R + T)
     m, keep = _masks(E, rng)[mask]
     w = rng.integers(0, 2 ** 32, (R, E), dtype=np.uint32)
-    out_l, out_r = _call(R, T)(jnp.array((m,) + bases, I32), jnp.asarray(w),
-                               jnp.asarray(keep[None, :].astype(np.int32)))
-    return m, keep, w, np.asarray(out_l), np.asarray(out_r)
+    tiles = rng.integers(0, 2 ** 32, (2, R, 128), dtype=np.uint32)
+    out_l, out_r, carry = map(np.asarray, _call(R, T)(
+        jnp.array((m,) + bases, I32), jnp.asarray(w),
+        jnp.asarray(keep[None, :].astype(np.int32)), jnp.asarray(tiles)))
+    return m, keep, w, tiles, out_l, out_r, carry
 
 
 @pytest.mark.parametrize("side", ["left", "right"])
@@ -93,8 +102,24 @@ def test_chunk_partition_is_the_stable_partition(geometry, mask, bases,
                                                  side):
     R, T = geometry
     E = T * 128
-    m, keep, w, out_l, out_r = _partitioned(R, T, mask, bases)
+    m, keep, w, tiles, out_l, out_r, carry = _partitioned(R, T, mask, bases)
     valid = np.arange(E) < m
     goes = valid & (keep if side == "left" else ~keep)
-    out, base = (out_l, bases[0]) if side == "left" else (out_r, bases[1])
-    np.testing.assert_array_equal(out[:, base:base + goes.sum()], w[:, goes])
+    n = int(goes.sum())
+    if side == "left":
+        base, end = bases[0], bases[0] + n
+        q = end // 128 * 128
+        # the slot's closed tiles, then the tile handed on
+        out = np.concatenate([out_l[:, :q], carry[0]], axis=1)
+        # the tile handed in goes on below the block
+        np.testing.assert_array_equal(out[:, :base], tiles[0][:, :base])
+    else:
+        base, end = bases[1], bases[1] + n
+        out = out_r
+        # the block's last tile ends in the tile handed in
+        np.testing.assert_array_equal(
+            out[:, end:end // 128 * 128 + 128], tiles[1][:, end % 128:])
+        # and its first tile is handed on
+        np.testing.assert_array_equal(carry[1][:, base:],
+                                      out[:, base:128])
+    np.testing.assert_array_equal(out[:, base:end], w[:, goes])
