@@ -31,9 +31,7 @@ package machine-checks them on every run:
   :mod:`collective_audit` verifies every rank-role issues the same DCN
   collective sequence (a collective under a rank-dependent branch is a
   deadlock finding) and that every site rides the resilience retry
-  guard (lint twin: rule JG009); :mod:`resource_audit` computes static
-  per-kernel VMEM footprints and per-shape HBM tallies over the bench
-  geometries against the :mod:`telemetry.devices` profiles;
+  guard (lint twin: rule JG009);
   :mod:`compile_audit` bounds the distinct-compile count across the
   jitted entry points and fails on unbounded static args;
   :mod:`precision_audit` requires every float narrowing in the traced

@@ -4,8 +4,8 @@ Exit codes: 0 clean (no unsuppressed findings, all audits pass),
 1 findings/audit failures, 2 bad usage or parse errors.
 
 The audit phase runs BOTH engines: the jaxpr audits (traced programs)
-and the whole-program auditors (collective order, VMEM/HBM budgets,
-recompile surface — see :mod:`auditors`).
+and the whole-program auditors (collective order, recompile surface,
+precision flow — see :mod:`auditors`).
 
 Common invocations::
 
@@ -15,10 +15,7 @@ Common invocations::
     python -m lightgbm_tpu.analysis lightgbm_tpu/ops --rules JG003
     python -m lightgbm_tpu.analysis --write-baseline  # re-grandfather
     python -m lightgbm_tpu.analysis --prune-baseline  # drop stale entries
-    python -m lightgbm_tpu.analysis --budgets         # resource tables
     python -m lightgbm_tpu.analysis --list-audits     # audit registry
-    python -m lightgbm_tpu.analysis --perf --json     # perf sentinel
-    python -m lightgbm_tpu.analysis --perf-advisory   # report, never block
 """
 from __future__ import annotations
 
@@ -27,8 +24,7 @@ import json
 import sys
 
 from . import (auditors, collective_audit, compile_audit,
-               concurrency_audit, perf_gate, quant_audit,
-               resource_audit)
+               concurrency_audit, quant_audit)
 from .config import load_config
 from . import jaxpr_audit
 from .jaxpr_audit import run_audits
@@ -58,16 +54,6 @@ def _build_parser() -> argparse.ArgumentParser:
                    dest="prune_baseline",
                    help="drop baseline entries no current finding "
                         "matches (stale suppressions), then exit 0")
-    p.add_argument("--budgets", action="store_true",
-                   help="print the VMEM/HBM budget tables and exit 0")
-    p.add_argument("--perf", action="store_true",
-                   help="also run the perf-regression sentinel over the "
-                        "BENCH_r*/MULTICHIP_r* round series (gates)")
-    p.add_argument("--perf-advisory", action="store_true",
-                   dest="perf_advisory",
-                   help="run the perf sentinel in advisory mode: report "
-                        "verdicts, never affect the exit code (the "
-                        "pre-commit hook mode)")
     p.add_argument("--no-audit", action="store_true",
                    help="skip the jaxpr/HLO audits")
     p.add_argument("--audit-only", action="store_true",
@@ -79,15 +65,14 @@ def _build_parser() -> argparse.ArgumentParser:
     p.add_argument("--list-audits", action="store_true",
                    dest="list_audits",
                    help="print the audit registry (jaxpr audits + "
-                        "whole-program auditors + perf sentinel) and "
-                        "exit")
+                        "whole-program auditors) and exit")
     return p
 
 
 def _list_audits() -> None:
     """Mirror of --list-rules for the audit side of the gate: every
-    jaxpr audit, every registered whole-program auditor, and the
-    opt-in perf sentinel, with one-line descriptions."""
+    jaxpr audit and every registered whole-program auditor, with
+    one-line descriptions."""
     def first_line(doc):
         return (doc or "").strip().splitlines()[0] if doc else ""
     for fn in jaxpr_audit.AUDITS:
@@ -95,11 +80,6 @@ def _list_audits() -> None:
                                      first_line(fn.__doc__)))
     for name, mod in sorted(auditors.all_auditors().items()):
         print("auditor  %-18s %s" % (name, first_line(mod.__doc__)))
-    print("auditor  %-18s %s" % (
-        "perf_sentinel",
-        "Perf-regression sentinel over the BENCH_r*/MULTICHIP_r* "
-        "round series (opt-in: --perf gates, --perf-advisory "
-        "reports)."))
 
 
 def main(argv=None) -> int:
@@ -113,10 +93,6 @@ def main(argv=None) -> int:
         return 0
 
     config = load_config()
-    if args.budgets:
-        print(resource_audit.render_tables(
-            resource_audit.tables(config=config)))
-        return 0
     rule_ids = ([r.strip() for r in args.rules.split(",") if r.strip()]
                 if args.rules else None)
 
@@ -164,19 +140,7 @@ def main(argv=None) -> int:
     audits = [] if not run_auditors \
         else run_audits() + auditors.run_all(config, artifacts=artifacts)
 
-    # the perf sentinel is opt-in (--perf gates, --perf-advisory reports
-    # without blocking — the pre-commit mode: a clone with no recorded
-    # rounds must still be able to commit)
-    perf_rep = None
-    perf_results = []
-    if args.perf or args.perf_advisory:
-        perf_rep = perf_gate._resolve_rounds(config)
-        perf_results = perf_gate.run(config, artifact=perf_rep)
-        audits = audits + perf_results
-
     bad_audits = [a for a in audits if not a.ok]
-    if args.perf_advisory and not args.perf:
-        bad_audits = [a for a in bad_audits if a not in perf_results]
     n_unsup = len(report.unsuppressed) if report else 0
     n_parse = len(report.parse_errors) if report else 0
     exit_code = 2 if n_parse else (1 if (n_unsup or bad_audits) else 0)
@@ -189,13 +153,11 @@ def main(argv=None) -> int:
         }
         if run_auditors:
             # the whole-program auditors' full artifacts: the abstract
-            # collective trace, the budget tables, the compile surface
+            # collective trace, the compile surface
             art = artifacts or {}
             payload["collective_trace"] = \
                 collective_audit.extract_repo_trace(
                     config, artifact=art.get("collective_order"))
-            payload["resource_tables"] = resource_audit.tables(
-                config=config, artifact=art.get("resource_budget"))
             payload["compile_surface"] = compile_audit.compile_surface(
                 config, artifact=art.get("compile_surface"))
             # the machine-checkable quantization certificate the
@@ -209,9 +171,6 @@ def main(argv=None) -> int:
             # collective_trace)
             payload["concurrency_trace"] = concurrency_audit.extract_trace(
                 config, artifact=art.get("concurrency"))
-        if perf_rep is not None:
-            payload["perf_tables"] = perf_gate.tables(
-                config, artifact=perf_rep)
         print(json.dumps(payload, indent=1))
         return exit_code
 
@@ -228,15 +187,10 @@ def main(argv=None) -> int:
             print("autofixed %d import statement(s)" % report.autofixed)
     for a in audits:
         status = "SKIP" if a.skipped else ("ok" if a.ok else "FAIL")
-        if (args.perf_advisory and not args.perf
-                and a in perf_results and not a.ok):
-            status = "ADVISORY-FAIL"
         line = "audit %-24s %s" % (a.name, status)
         if a.detail:
             line += "  (%s)" % a.detail
         print(line)
-    if perf_rep is not None:
-        print(perf_gate.render_report(perf_rep))
     if report:
         print("graft-lint: %d file(s), %d finding(s) "
               "(%d suppressed), %d audit failure(s)"

@@ -1,13 +1,11 @@
 """Registry of the whole-program auditors behind the analysis gate.
 
-Eight source/program-level audit engines complement the jaxpr audits
+Seven source/program-level audit engines complement the jaxpr audits
 (:mod:`jaxpr_audit` traces real programs; these reason about the
 source/geometry/dataflow statically):
 
 * ``collective_order`` — rank-consistent DCN collective sequences +
   guard coverage (:mod:`collective_audit`);
-* ``resource_budget`` — static VMEM/HBM budgets for the Pallas kernel
-  fleet over the bench shapes (:mod:`resource_audit`);
 * ``compile_surface`` — the analytic distinct-compile bound across the
   jitted entry points (:mod:`compile_audit`);
 * ``precision_flow`` — every float narrowing in the traced ops/predict
@@ -39,13 +37,12 @@ from typing import Dict, List, Optional
 
 from . import (collective_audit, compile_audit, concurrency_audit,
                health_audit, precision_audit, quant_audit,
-               resource_audit, transfer_audit)
+               transfer_audit)
 from .config import GraftlintConfig
 from .jaxpr_audit import AuditResult
 
 AUDITORS: Dict[str, object] = {
     "collective_order": collective_audit,
-    "resource_budget": resource_audit,
     "compile_surface": compile_audit,
     "precision_flow": precision_audit,
     "transfer": transfer_audit,
@@ -64,14 +61,11 @@ def compute_artifacts(config: Optional[GraftlintConfig] = None
     """One pass over the repo per auditor, keyed by registry name.
 
     The --json CLI needs both the pass/fail verdicts AND the full
-    artifacts (trace, tables, surface, certificates); computing these
+    artifacts (trace, surface, certificates); computing these
     here and passing them to :func:`run_all` + the payload builders
     keeps that to a single walk instead of one per consumer."""
-    profile = resource_audit._resolve_profile(config)
-    kernels, hbm = resource_audit.estimate_all(profile)
     return {
         "collective_order": collective_audit.audit_repo(config),
-        "resource_budget": (profile, kernels, hbm),
         "compile_surface": compile_audit.iter_jit_sites(config),
         "precision_flow": precision_audit.compute_artifact(config),
         "transfer": transfer_audit.compute_artifact(config),

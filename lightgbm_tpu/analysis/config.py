@@ -72,15 +72,8 @@ class GraftlintConfig:
     concurrency_paths: List[str] = field(default_factory=lambda: [
         "lightgbm_tpu/serving/", "lightgbm_tpu/predict/serve.py",
         "lightgbm_tpu/resilience/", "lightgbm_tpu/telemetry/"])
-    # resource auditor: device profile the VMEM/HBM budgets come from
-    # (telemetry/devices.py; "auto" = detect attached accelerator)
-    audit_device: str = "v5e"
     # compile auditor: ceiling on the analytic distinct-compile bound
     compile_ceiling: int = 64
-    # perf sentinel (--perf): relative noise band a headline bench key
-    # may move within before counting as a regression, when the rounds
-    # being compared carry no recorded BENCH_REPEATS spread
-    perf_band: float = 0.15
     # baseline suppression file, relative to the repo root
     baseline: str = "lightgbm_tpu/analysis/baseline.json"
     root: str = "."

@@ -72,6 +72,15 @@ H_CHILD_FRAC = 0.25
 # per-decision failure probability of the Hoeffding accumulation bound
 CONFIDENCE = 1e-9
 
+# total rows of the two geometries the gate certifies int16 planes at
+# (cut over 8 ranks in default_specs): the reference's HIGGS experiment
+# and the 2M-row one-hot shape the quantized exchange was built against.
+# The certificate needs nothing else of a shape (bins and caps come from
+# the histogram's input contract), and these are its own domain, not a
+# registry: a real run is certified at config time from its own rows
+# (parallel/distributed.resolve_hist_quant).
+CERTIFIED_ROWS = {"higgs": 10_500_000, "expo": 2_000_000}
+
 _BITS = {"int8": 8, "int16": 16}
 _F16_REL = 2.0 ** -11
 
@@ -84,12 +93,10 @@ def default_specs(config: Optional[GraftlintConfig] = None
     tensors (predict/compile.quant_spec defaults)."""
     from ..ops.pallas_histogram import hist_input_contract
     from ..predict.compile import quant_spec
-    from .resource_audit import BENCH_SHAPES
     specs = []
-    for name in ("higgs", "expo"):
-        shape = BENCH_SHAPES[name]
+    for name, rows in CERTIFIED_ROWS.items():
         ranks = 8
-        rows_shard = shape.rows // ranks
+        rows_shard = rows // ranks
         contract = hist_input_contract(w=256, rows=rows_shard)
         specs.append({
             "name": "hist_int16_%s" % name,

@@ -191,16 +191,13 @@ PARAMS: List[_P] = [
     # ---- TPU (new; analog of the reference's gpu_* block) ----
     _P("tpu_use_dp", bool, False),          # f64-emulated histograms vs f32
     _P("tpu_num_devices", int, 0),           # 0 = all local devices
-    _P("tpu_mesh_axis", str, "data"),        # mesh axis name for row sharding
     _P("tpu_rows_per_chunk", int, 0),        # 0 = auto; histogram kernel chunking
     _P("tpu_histogram_impl", str, "auto"),   # auto | xla | pallas
-    _P("tpu_donate_buffers", bool, True),
     _P("tpu_window_chunk", int, 0),          # 0 = auto; partitioned-grower chunk rows
     _P("tpu_hist_dtype", str, "auto"),       # auto | f32 | f64 | bf16x2
     #                                        # (auto: f64 bins on CPU —
     #                                        # reference double hist_t —
     #                                        # bf16x2 MXU on TPU)
-    _P("tpu_pack_impl", str, "sort"),        # sort | matmul (partition pack)
     _P("tpu_scan_impl", str, "auto"),        # auto | xla | pallas (split scan)
     _P("tpu_persist_scan", str, "auto"),     # auto | off | force (persistent-payload scan; force = XLA kernel emulation off-TPU)
     _P("tpu_level_grow", str, "auto"),       # auto | off (level-parallel persist growth: one fused program per tree level when max_depth is set)

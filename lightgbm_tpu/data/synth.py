@@ -1,12 +1,12 @@
-"""Synthetic benchmark datasets (the canonical home; bench.py re-exports).
+"""Synthetic datasets in the reference's experiment shapes.
 
 The shapes mirror the reference's experiment sets (docs/Experiments.rst):
 HIGGS-like continuous kinematics for the throughput north star, the
 MS-LTR and Yahoo-LTR ranking shapes, the Expo EFB-bundled one-hot shape,
 and the Allstate sparse wide-one-hot shape. Kept inside the package so
-the bench scripts, the profiling CLI (``python -m lightgbm_tpu.profile``)
-and tests all draw the same data without duplicating generator logic at
-the repo top level.
+the profiling CLI (``python -m lightgbm_tpu.profile``), ``chip_smoke.py``
+and the tests all draw the same data. The benchmark has generators of its
+own (``benchmark/generators/``).
 """
 from __future__ import annotations
 

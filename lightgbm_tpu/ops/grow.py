@@ -78,7 +78,6 @@ class GrowConfig(NamedTuple):
     use_l1: bool = True     # lambda_l1 > 0 (USE_L1 template analog)
     use_mds: bool = True    # max_delta_step > 0 (USE_MAX_OUTPUT analog)
     hist_dtype: str = "f32"  # "f32" | "bf16x2" (hi/lo split bf16 MXU)
-    pack_impl: str = "sort"  # "sort" (lax.sort, exact) | "matmul" (one-hot)
     extra_trees: bool = False   # USE_RAND: one random threshold per feature
     bynode_k: int = 0           # >0: feature_fraction_bynode sample size
     use_cegb: bool = False      # CEGB split/coupled gain penalties
@@ -1228,17 +1227,6 @@ class _PartState(NamedTuple):
 U32 = jnp.uint32
 
 
-def _pack_matmul(slot, payload, C):
-    """Permute `payload` rows into their target `slot` via a one-hot matmul
-    at Precision.HIGHEST (the TPU default truncates f32 operands to bf16,
-    which would corrupt row ids/grads in the permuted payload)."""
-    slots = jnp.arange(C, dtype=I32)
-    onehot = (slot[None, :] == slots[:, None]).astype(jnp.float32)  # [C, C]
-    return jax.lax.dot(onehot, payload,
-                       precision=jax.lax.Precision.HIGHEST,
-                       preferred_element_type=jnp.float32)
-
-
 def _bits_of(bdt) -> int:
     return jnp.dtype(bdt).itemsize * 8
 
@@ -1544,25 +1532,9 @@ def _grow_tree_partitioned_jit(layout: DataLayout, grad: jnp.ndarray,
             # pack orders the chunk [left | dropped | right]; writing the
             # whole packed block at lf puts the left block in place, writing
             # it again at rf - C puts the right block's end exactly at rf
-            if gc.pack_impl == "sort":
-                key = jnp.where(gl, U32(0), jnp.where(gr, U32(2), U32(1)))
-                pb, pg, ph, prb = _pack_sort(key, bw, gw, hw, rbw,
-                                             _bits_of(bdt))
-            else:
-                posl = jnp.cumsum(gl, dtype=I32) - 1
-                posr = (C - nR) + jnp.cumsum(gr, dtype=I32) - 1
-                slot = jnp.where(gl, posl, jnp.where(gr, posr, C))
-                rb_hi = (rbw >> U32(12)).astype(jnp.float32)
-                rb_lo = (rbw & U32(4095)).astype(jnp.float32)
-                payload = jnp.concatenate([
-                    bw.astype(jnp.float32), gw[:, None], hw[:, None],
-                    rb_hi[:, None], rb_lo[:, None]], axis=1)
-                packed = _pack_matmul(slot, payload, C)
-                pb = packed[:, :G].astype(bdt)
-                pg = packed[:, G]
-                ph = packed[:, G + 1]
-                prb = ((packed[:, G + 2].astype(U32) << U32(12))
-                       | packed[:, G + 3].astype(U32))
+            key = jnp.where(gl, U32(0), jnp.where(gr, U32(2), U32(1)))
+            pb, pg, ph, prb = _pack_sort(key, bw, gw, hw, rbw,
+                                         _bits_of(bdt))
 
             # scratch layout: left blocks stack up from 0, right blocks
             # stack down from n+2C; the 2C padding keeps the two whole-[C]

@@ -105,11 +105,12 @@ def _ceil8(x: int) -> int:
 
 
 # -- scoped-vmem requests ----------------------------------------------------
-# One formula per kernel family, shared with analysis/resource_audit.py:
-# the kernels run with these limits and the static budget gate checks the
-# same numbers against the device profiles (telemetry/devices.py), so an
-# over-budget geometry fails `python -m lightgbm_tpu.analysis` instead of
-# OOMing the first real-TPU run. The default 16MB scoped-VMEM limit forces
+# One formula per kernel family, and the only place a kernel's footprint
+# is written down: the helper is the `vmem_limit_bytes` the kernel runs
+# with, and the proof that it suffices is the compiler's, in the
+# described-topology compiles of tests/test_chip_compile.py (a request
+# too small is refused there, without a chip; a geometry that test does
+# not hold is unproven). The default 16MB scoped-VMEM limit forces
 # small chunks whose cost is DMA latency and per-step scalar work (every
 # chunk waits for its read); v5e cores carry 128MB of VMEM, so the limits
 # are sized to each kernel's actual footprint (buffers + Mosaic
@@ -136,9 +137,8 @@ def seg_hist_vmem_bytes(WPA: int, E: int, G: int) -> int:
     """seg_hist / level_seg_hist / root_hist: one streaming chunk buffer
     (+1 working copy) + the radix hist accumulator + the [G, E] decoded
     group-bin planes and one-hot rhs `_hist_accum` materializes per
-    chunk. The decode terms were missing before the static budget gate
-    (analysis/resource_audit.py) flagged the 700-group unbundled shape:
-    at G=700, E=8320 they are 24MB the old request did not cover."""
+    chunk. The decode terms count: at G=700, E=8320 (a 700-group
+    unbundled shape) they are 24MB."""
     return int(min(96 << 20,
                    2 * WPA * E * 4 + G * 16 * 64 * 4
                    + G * E * 4 + 64 * E * 2 + (20 << 20)))

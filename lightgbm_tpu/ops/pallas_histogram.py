@@ -148,9 +148,8 @@ def hist_vmem_plan(w: int, G: int, C: int) -> dict:
 
     One place derives the impl choice, the grid stripe, and the
     scoped-vmem limit the kernel requests: the kernel runs with these
-    numbers and ``analysis/resource_audit.py`` gates them against the
-    device profile budgets, so an over-budget geometry fails the static
-    gate instead of OOMing the first real-TPU run. The limit covers the
+    numbers, and tests/test_chip_compile.py proves them by compiling the
+    kernel for a described v5e. The limit covers the
     double-buffered in/out blocks plus the one-hot temporaries (the
     16MB slack is Mosaic's own working set); many-group shapes (a
     700-feature unbundled dataset) exceed the 16MB Mosaic default,

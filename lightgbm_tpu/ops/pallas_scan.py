@@ -48,9 +48,9 @@ def _round_up(x: int, m: int) -> int:
 def scan_pair_vmem_bytes(Fp: int, Wp: int) -> int:
     """Scoped-vmem limit :func:`scan_pair` requests at padded geometry
     (Fp, Wp): ~12 staged [Fp, Wp] f32 blocks + the cumsum stack + Mosaic
-    temporaries. The kernel runs with this number and
-    analysis/resource_audit.py gates it against the device profile, so
-    keep the formula here — one source of truth for both. The default
+    temporaries. The kernel runs with this number; the described-topology
+    compiles of tests/test_chip_compile.py prove it (28 and 137
+    features). The default
     scoped-vmem budget OOMs past ~450 features at Wp=256 (v5e carries
     128MB of VMEM, so size the limit to the footprint)."""
     return int(min(100 << 20, 16 * Fp * Wp * 4 + (20 << 20)))
@@ -59,8 +59,8 @@ def scan_pair_vmem_bytes(Fp: int, Wp: int) -> int:
 def scan_blocks_vmem_bytes(Gp: int, Wp: int) -> int:
     """Scoped-vmem limit :func:`scan_blocks` requests: ~14 [Gp, Wp]
     staging planes + the [Wp, Wp] triangle + fill temporaries (small
-    next to the per-feature kernel's footprint). Shared with the
-    resource audit like :func:`scan_pair_vmem_bytes`."""
+    next to the per-feature kernel's footprint). Requested and proven
+    like :func:`scan_pair_vmem_bytes`."""
     return int(min(100 << 20, 48 * Gp * Wp * 4 + Wp * Wp * 4 + (20 << 20)))
 
 

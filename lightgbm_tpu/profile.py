@@ -10,13 +10,6 @@ Usage: python -m lightgbm_tpu.profile [--shape NAME] [rows] [iters]
                                       [key=value ...]
        python -m lightgbm_tpu.profile --merge DIR [--run NAME]
                                       [--out PATH] [--json]
-       python -m lightgbm_tpu.profile --perf-card SHAPE [PATH] [--json]
-
-``--perf-card SHAPE [PATH]`` does no training either: it prints the
-roofline report card (achieved-fraction-of-peak + bound category,
-:mod:`lightgbm_tpu.telemetry.perfmodel`) for one bench shape from an
-EXISTING phase-snapshot file or directory (``BENCH_r*_phases.json`` /
-``BENCH_phases.json`` / a ``phases_out=`` snapshot from this CLI).
 
 ``--merge DIR`` does no training: it merges the rank-suffixed Chrome
 traces a multihost run left in DIR (``telemetry_out=`` writes
@@ -26,16 +19,16 @@ clocks via the recorded collective barrier spans
 (:mod:`lightgbm_tpu.telemetry.merge`). ``--json`` prints the merge
 summary as JSON instead of text.
 
-``--shape`` (or ``shape=NAME``) picks the benchmark workload the bench
-suite also trains: ``higgs`` (default), ``expo`` (EFB-bundled one-hot —
-the bundle fast-path attribution target), ``allstate`` (sparse wide
-one-hot), ``yahoo`` / ``msltr`` (lambdarank). Extra ``key=value`` tokens
-are passed through as training params (e.g. ``tree_learner=data
-num_leaves=511``), except:
+``--shape`` (or ``shape=NAME``) picks one of the ``data/synth.py``
+generators: ``higgs`` (default), ``expo`` (EFB-bundled one-hot),
+``allstate`` (sparse wide one-hot), ``yahoo`` / ``msltr`` (lambdarank).
+Extra ``key=value`` tokens are passed through as training params (e.g.
+``tree_learner=data num_leaves=511``), except:
 
-  * ``phases_out=PATH`` — write a BENCH_phases.json-style telemetry
-    category/scope snapshot for the traced run, keyed by the shape name,
-    so the bench's phase breakdown reproduces without the full bench;
+  * ``phases_out=PATH`` — write the traced run's telemetry snapshot
+    (:func:`lightgbm_tpu.telemetry.export.phase_snapshot`: category
+    totals, scope table, histograms, path counters), keyed by the shape
+    name;
   * ``xplane=0`` — skip the device xplane trace (host spans + phase
     snapshot only; the CI smoke test runs this on CPU).
 
@@ -75,73 +68,6 @@ def _make_shape(shape: str, rows: int):
         return X, y, g, "lambdarank"
     raise SystemExit("unknown --shape %r (expected higgs|expo|allstate|"
                      "yahoo|msltr)" % shape)
-
-
-def _phase_stats(events, work=None):
-    """Shared snapshot layout + roofline-card stamping; the path
-    counters ride along so fast-path engagement stays visible."""
-    from lightgbm_tpu.telemetry import perfmodel
-    return perfmodel.phase_snapshot(work=work, include_counters=True)
-
-
-def _main_perf_card(argv) -> int:
-    """--perf-card SHAPE [PATH] [--json]: the roofline report card for
-    one bench shape from an EXISTING phase-snapshot file (or a directory
-    holding one) — no training, no re-run, no accelerator needed. PATH
-    defaults to ./BENCH_phases.json; a directory picks the newest
-    ``BENCH_r*_phases.json`` (falling back to ``BENCH_phases.json``).
-    The device profile comes from the attached accelerator or the
-    ``LGBTPU_DEVICE_PROFILE`` override (telemetry/devices.py)."""
-    import os
-
-    from lightgbm_tpu.telemetry import perfmodel
-    i = argv.index("--perf-card")
-    if i + 1 >= len(argv) or argv[i + 1].startswith("-"):
-        print("--perf-card needs a shape (higgs|expo|allstate|yahoo|"
-              "msltr)", file=sys.stderr)
-        return 2
-    shape = argv[i + 1].lower()
-    rest = [a for a in argv[i + 2:] if not a.startswith("-")]
-    path = rest[0] if rest else "."
-    if os.path.isdir(path):
-        found = perfmodel.find_phase_snapshot(path)
-        if found is None:
-            print("no BENCH_r*_phases.json / BENCH_phases.json in %s"
-                  % path, file=sys.stderr)
-            return 2
-        path = found
-    try:
-        with open(path, "r", encoding="utf-8") as f:
-            snaps = json.load(f)
-    except (OSError, ValueError) as exc:
-        print("cannot read phase snapshot %s: %s" % (path, exc),
-              file=sys.stderr)
-        return 2
-    if not isinstance(snaps, dict):
-        print("phase snapshot %s is not a JSON object (got %s)"
-              % (path, type(snaps).__name__), file=sys.stderr)
-        return 2
-    # the snapshot is keyed by bench phase name; find the one that maps
-    # to the requested shape (bench: higgs/ltr/expo/... ; profile CLI:
-    # the shape name itself)
-    snap = None
-    for phase_key, shape_name in perfmodel.PHASE_SHAPES.items():
-        if shape_name == shape and isinstance(snaps.get(phase_key),
-                                              dict):
-            snap = snaps[phase_key]
-            break
-    if snap is None:
-        print("no phase in %s maps to shape %r (have: %s)"
-              % (path, shape, ", ".join(sorted(snaps))),
-              file=sys.stderr)
-        return 2
-    card = perfmodel.report_card(snap, shape)
-    if "--json" in argv:
-        print(json.dumps(card.to_dict(), sort_keys=True))
-    else:
-        print(perfmodel.render_cards([card]))
-        print("  (snapshot: %s)" % path)
-    return 0
 
 
 def _main_merge(argv) -> int:
@@ -199,8 +125,6 @@ def main(argv=None) -> int:
         return 0
     if "--merge" in argv:
         return _main_merge(argv)
-    if "--perf-card" in argv:
-        return _main_perf_card(argv)
     shape = "higgs"
     if "--shape" in argv:
         i = argv.index("--shape")
@@ -218,6 +142,7 @@ def main(argv=None) -> int:
     import lightgbm_tpu as lgb
     from lightgbm_tpu.config import kv2map
     from lightgbm_tpu.telemetry import events, maybe_export, xplane
+    from lightgbm_tpu.telemetry.export import phase_snapshot
 
     # objective comes from the SHAPE (lambdarank for the LTR ones) unless
     # the caller overrides it via key=value
@@ -264,18 +189,11 @@ def main(argv=None) -> int:
           % (shape, wall, n_rows, iters, n_rows * iters / wall / 1e6))
 
     if phases_out:
-        # the bench's BENCH_phases.json layout, keyed by shape, plus the
-        # path counters (persist_scan_trees vs v1_grow_trees) so fast-path
-        # engagement is visible next to the attribution
-        try:
-            nl = int(params.get("num_leaves", 255))
-        except (TypeError, ValueError):
-            nl = 255
+        # the path counters (persist_scan_trees vs v1_grow_trees) ride
+        # along so fast-path engagement is visible next to the attribution
         with open(phases_out, "w") as f:
-            json.dump({shape: _phase_stats(
-                events, work={"phase": shape, "rows": n_rows,
-                              "iters": iters, "num_leaves": nl})},
-                f, indent=1, sort_keys=True)
+            json.dump({shape: phase_snapshot()}, f, indent=1,
+                      sort_keys=True)
         print("telemetry phase snapshot written to %s" % phases_out,
               file=sys.stderr)
 

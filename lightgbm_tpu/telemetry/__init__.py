@@ -18,9 +18,8 @@ real observability layer:
   * :mod:`xplane`  — op-level device profiles and idle gaps by program
     span from a jax profiler trace, read with ``jax.profiler.ProfileData``
     (``python -m lightgbm_tpu.profile``);
-  * :mod:`devices` — static TPU device profiles (per-core VMEM, per-chip
-    HBM budgets) consumed by the ``analysis/resource_audit`` budget gate
-    and the kernel ``vmem_limit_bytes`` sizing comments;
+  * :mod:`devices` — ``on_tpu()``, the one backend test of the package,
+    and the TPU generations known by ``device_kind``;
   * :mod:`histo`  — log-bucketed fixed-memory mergeable streaming
     histograms (p50/p95/p99/p99.9): per-collective DCN latency+bytes,
     persist program wall, serving latency/queue-wait;

@@ -151,12 +151,28 @@ def maybe_export(out: Optional[str] = None):
     return trace_path, metrics_path
 
 
-def format_report(snap=None, perf_cards=None) -> str:
-    """Sorted-by-time table, like Timer::Print (common.h:1059).
+def phase_snapshot() -> dict:
+    """The registry as one JSON-able dict (category totals, per-scope
+    table, histograms, counters, truncation signals): what
+    ``python -m lightgbm_tpu.profile ... phases_out=PATH`` writes."""
+    return {
+        "categories": {k: round(v, 3)
+                       for k, v in events.category_totals().items()},
+        "scopes": {name: {"seconds": round(sec, 3), "count": n,
+                          "category": cat}
+                   for name, (sec, n, cat)
+                   in events.snapshot_full().items()},
+        "histograms": {k: h.to_dict(with_buckets=False)
+                       for k, h in histo.histograms_snapshot().items()},
+        # silent truncation is a lie in a snapshot: say what was dropped
+        "dropped_events": events.dropped_events(),
+        "histo_saturation": histo.saturation_total(),
+        "counters": dict(events.counts_snapshot()),
+    }
 
-    ``perf_cards`` (a list of :class:`perfmodel.ShapeCard`) appends the
-    roofline "perf report card" table — callers that know the workload
-    geometry (bench, profile --perf-card) pass the cards they built."""
+
+def format_report(snap=None) -> str:
+    """Sorted-by-time table, like Timer::Print (common.h:1059)."""
     if snap is None:
         snap = events.snapshot_full()
     lines = []
@@ -173,11 +189,6 @@ def format_report(snap=None, perf_cards=None) -> str:
                             100.0 * sec / max(total, 1e-12), cat))
         lines.append("  %-*s %10.3fs" % (width, "(sum)", total))
     lines.extend(histogram_report_lines())
-    if perf_cards:
-        from . import perfmodel
-        card_text = perfmodel.render_cards(perf_cards)
-        if card_text:
-            lines.append(card_text)
     # silent-truncation visibility: a trace that dropped events or a
     # histogram that saturated is an INCOMPLETE record, and the report
     # must say so rather than present clipped numbers as the whole story

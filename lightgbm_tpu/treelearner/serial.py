@@ -342,7 +342,6 @@ class SerialTreeLearner:
             use_dp=resolve_use_dp(config),
             window_chunk=window_chunk,
             hist_dtype=hist_dtype,
-            pack_impl=str(config.tpu_pack_impl).lower(),
             packed_4bit=bool(getattr(dataset, "device_packed", False)),
             multival=bool(getattr(dataset, "is_multival", False)),
             **_config_grow_kwargs(config, dataset.num_features),

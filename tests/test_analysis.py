@@ -756,15 +756,11 @@ def test_cli_smoke(capsys):
     assert main(["--list-audits"]) == 0
     out = capsys.readouterr().out
     for name in ("hist_window", "precision_flow", "transfer",
-                 "quant_certify", "perf_sentinel"):
+                 "quant_certify"):
         assert name in out, name
     # lint-only over one file: exits 0 and prints the summary line
     assert main(["lightgbm_tpu/analysis/lint.py", "--no-audit"]) == 0
     assert "graft-lint:" in capsys.readouterr().out
-    # the budget tables render without running the gate
-    assert main(["--budgets"]) == 0
-    out = capsys.readouterr().out
-    assert "resource budgets" in out and "hist_window" in out
 
 
 # ---------------------------------------------------------------------------
@@ -805,14 +801,6 @@ AUDITOR_FIXTURES = {
                     print(agg)
                 return agg
             """,
-    },
-    "resource_budget": {
-        # a 4000-group unbundled monster: kernels blow VMEM, planes
-        # blow HBM
-        "positive": {"rows": 50_000_000, "features": 4000,
-                     "groups": 4000, "bundled": False},
-        "negative": {"rows": 1_000_000, "features": 28, "groups": 28,
-                     "bundled": False},
     },
     "compile_surface": {
         # a per-iteration Python int marked static: unbounded recompiles
@@ -1043,30 +1031,6 @@ def test_collective_trace_extracts_repo_sites():
         in by_path["lightgbm_tpu/ops/grow_persist.py"]
 
 
-def test_resource_audit_tracks_kernel_formulas():
-    """The request column must come from the kernels' own helpers — if a
-    kernel formula changes, the audit sees the new number without
-    edits here."""
-    from lightgbm_tpu.analysis import resource_audit as ra
-    from lightgbm_tpu.ops.pallas_scan import scan_pair_vmem_bytes
-    from lightgbm_tpu.telemetry.devices import get_profile
-    est = ra.estimate_scan_pair(ra.BENCH_SHAPES["yahoo"],
-                                get_profile("v5e"))
-    assert est.request == scan_pair_vmem_bytes(704, 256)
-    assert est.ok
-
-
-def test_resource_audit_profile_budgets_differ():
-    """v4's 32MB VMEM cannot host the 100MB-class kernel requests the
-    v5e tuning assumes — the per-profile budget check must say so."""
-    from lightgbm_tpu.analysis import resource_audit as ra
-    from lightgbm_tpu.telemetry.devices import get_profile
-    kernels, _ = ra.estimate_all(profile=get_profile("v4"))
-    assert any(not k.ok for k in kernels)
-    kernels5, hbm5 = ra.estimate_all(profile=get_profile("v5e"))
-    assert all(k.ok for k in kernels5) and all(h.ok for h in hbm5)
-
-
 def test_compile_audit_enumerates_known_entry_points():
     """The AST walk must see the real jit surface: the kernel entry
     points, the predict runtime's static raw flag, and the factories."""
@@ -1087,8 +1051,7 @@ def test_auditors_all_green_on_repo():
     results the CLI gate appends to the jaxpr audits."""
     results = {r.name: r for r in run_auditors()}
     assert set(results) == {"collective_order", "collective_guarded",
-                            "collective_observed", "vmem_budget",
-                            "hbm_budget", "compile_surface",
+                            "collective_observed", "compile_surface",
                             "precision_flow", "transfer",
                             "quant_certify", "health_covered",
                             "concurrency_discipline",
@@ -1150,12 +1113,11 @@ def test_cli_gate_json_green(capsys):
     assert code == 0 and payload["exit_code"] == 0
     audit_names = {a["name"] for a in payload["audits"]}
     assert {"collective_order", "collective_guarded",
-            "collective_observed", "vmem_budget", "hbm_budget",
+            "collective_observed",
             "compile_surface", "precision_flow", "transfer",
             "quant_certify", "health_covered"} <= audit_names
     assert payload["lint"]["counts"]["unsuppressed"] == 0
     assert payload["collective_trace"]["findings"] == []
-    assert payload["resource_tables"]["vmem"]
     assert payload["compile_surface"]["total_bound"] <= 64
     # the machine-checkable quantization certificate: every spec green,
     # and the int16 histogram bound within the pinned decision budget
@@ -1227,8 +1189,7 @@ def test_auditor_artifacts_single_pass_matches_fresh():
     single-pass path — must produce the same verdicts and payload as
     fresh per-consumer computation."""
     from lightgbm_tpu.analysis import auditors
-    from lightgbm_tpu.analysis import (collective_audit, compile_audit,
-                                       resource_audit)
+    from lightgbm_tpu.analysis import collective_audit, compile_audit
     config = load_config()
     art = auditors.compute_artifacts(config)
     assert set(art) == set(auditors.all_auditors())
@@ -1239,9 +1200,6 @@ def test_auditor_artifacts_single_pass_matches_fresh():
     assert collective_audit.extract_repo_trace(
         config, artifact=art["collective_order"]) \
         == collective_audit.extract_repo_trace(config)
-    assert resource_audit.tables(
-        config=config, artifact=art["resource_budget"]) \
-        == resource_audit.tables(config=config)
     assert compile_audit.compile_surface(
         config, artifact=art["compile_surface"]) \
         == compile_audit.compile_surface(config)

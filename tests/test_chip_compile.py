@@ -9,10 +9,19 @@ configuration — the persist grower's kernels for HIGGS (10.5M x 28,
 255 bins, 255 leaves), the same payload with a finite ``max_depth`` for the
 level kernels, an EFB-bundled Expo-like payload (11M rows) for the
 block scan, the benchmark's Expo cell's own split_pass (9 live rows, a
-nibble slot, the histogram in the pass), and the MS-LTR payload (137 features: 40 live rows, whole
-sublane tiles, so split_pass has no spare sublane) — plus the whole fused
-k=16 scan driver. Nothing runs, so they
-say nothing about results or times; ``chip_smoke.py`` does that on the chip.
+nibble slot, the histogram in the pass), the MS-LTR payload (137 features:
+40 live rows, whole sublane tiles, so split_pass has no spare sublane; its
+histogram kernels and its pair scan at 137 groups), and the HIGGS rows under
+the other static shapes every persist configuration can take: a weight row
+(13 live rows), three classes (17 live rows in 24), ``max_bin=15`` (every
+group a nibble, 9 live rows in 16) — plus the whole fused k=16 scan driver.
+Nothing runs, so they say nothing about results or times; ``chip_smoke.py``
+does that on the chip.
+
+These compiles are the proof of each kernel's scoped-VMEM request (the
+``*_vmem_bytes`` / ``hist_vmem_plan`` helper beside the kernel): a request
+too small for what Mosaic allocates is refused here. A geometry without a
+case here is unproven.
 
 All cases live in this one file: the topology is described inside a
 module-scoped fixture (never at import, in a skipif or in a parametrize),
@@ -47,7 +56,7 @@ EXPO_ROWS = 11_000_000      # docs/Experiments.rst: Expo
 MSLTR_ROWS = 2_270_296      # docs/Experiments.rst: MS LTR
 EXPO_CELL_ROWS = 16_500_000  # benchmark/configs/expo.json: 1.5 x Expo
 LEVEL_DEPTH = 8             # max_depth; with num_leaves = 2^8 the level
-#                             phase engages (bench.py's level configuration)
+#                             phase engages
 SAMPLE_ROWS = 20_000        # rows actually binned: the bin structure only
 
 
@@ -78,20 +87,26 @@ class _Built:
     GrowConfig on a TPU backend, and persist growers whose payload
     geometry is that of the full row count."""
 
-    def __init__(self, X, y, rows, max_depths):
-        params = {"objective": "binary", "num_leaves": 255, "max_bin": 255}
+    def __init__(self, X, y, rows, max_depths, weight=None, **more):
+        params = dict({"objective": "binary", "num_leaves": 255,
+                       "max_bin": 255}, **more)
         cfg = lgb.Config(params)
-        self.ds = BinnedDataset.from_matrix(X, cfg, label=y)
-        small = gp.build_assets(self.ds, self.ds.metadata.label)
-        G, plan, nbw, CR = (small.geometry[2], small.geometry[3],
-                            small.geometry[4], small.geometry[7])
-        WPA, C, NP = gp._payload_geometry(rows, nbw, 0, CR)
+        self.ds = BinnedDataset.from_matrix(X, cfg, label=y, weight=weight)
+        self.objective = create_objective(params["objective"], cfg)
+        self.objective.init(self.ds.metadata, self.ds.num_data)
+        K = self.objective.num_model_per_iteration
+        small = gp.build_assets(self.ds, self.ds.metadata.label,
+                                num_scores=K)
+        G, plan, nbw, CR, has_w = (small.geometry[2], small.geometry[3],
+                                   small.geometry[4], small.geometry[7],
+                                   small.geometry[9])
+        assert has_w == (weight is not None)
+        WPA, C, NP = gp._payload_geometry(rows, nbw, 0, CR, K, has_w)
         self.assets = small._replace(
             pay0=None,
-            geometry=(WPA, NP, G, plan, nbw, rows, C, CR, 1, False, False))
+            geometry=(WPA, NP, G, plan, nbw, rows, C, CR, K, has_w, False))
         self.pay = (WPA, NP)
-        self.objective = create_objective("binary", cfg)
-        self.objective.init(self.ds.metadata, self.ds.num_data)
+        self.wp_live = gp.payload_weight_row(nbw, K) + int(has_w)
         self.learners, self.growers = {}, {}
         # the learner reads the backend to choose f32/bf16x2 and the
         # Mosaic scan; steer it here, in the test, to what it does on TPU
@@ -128,6 +143,27 @@ def msltr():
     return _Built(X, (y > 1).astype(np.float64), MSLTR_ROWS, (-1,))
 
 
+@pytest.fixture(scope="module")
+def higgs_weighted():
+    X, y = make_higgs_like(SAMPLE_ROWS)
+    w = np.random.default_rng(0).uniform(0.5, 2.0, SAMPLE_ROWS)
+    return _Built(X, y, HIGGS_ROWS, (-1,), weight=w)
+
+
+@pytest.fixture(scope="module")
+def higgs_3class():
+    X, y = make_higgs_like(SAMPLE_ROWS)
+    y3 = y + (X[:, 0] > np.median(X[:, 0]))
+    return _Built(X, y3, HIGGS_ROWS, (-1,), objective="multiclass",
+                  num_class=3)
+
+
+@pytest.fixture(scope="module")
+def higgs_15bins():
+    X, y = make_higgs_like(SAMPLE_ROWS)
+    return _Built(X, y, HIGGS_ROWS, (-1,), max_bin=15)
+
+
 def _hist_window(higgs, expo, S):
     learner = higgs.learners[-1]
     G, C = len(higgs.ds.groups), learner.grow_config.window_chunk
@@ -137,16 +173,27 @@ def _hist_window(higgs, expo, S):
              S((C,), jnp.float32)))
 
 
-def _scan_pair(higgs, expo, S):
-    gr = higgs.growers[-1]
-    F = higgs.ds.num_features
+def _scan_pair_of(built, S):
+    gr = built.growers[-1]
+    F = built.ds.num_features
     lay = ScanLayout(gr._pad_meta, jnp.ones(F, bool), F, 256,
-                     len(higgs.ds.groups) * 256)
+                     len(built.ds.groups) * 256)
     f32 = jnp.float32
     plane, mask = (2, lay.Fp, lay.Wp), lay.keep_r.shape
     return scan_pair, (S((2, 8), f32), S(plane, f32), S(plane, f32),
                        S(mask, f32), S(mask, f32), S(mask, f32),
                        S(mask, f32), S(lay.aux.shape, f32))
+
+
+def _scan_pair(higgs, expo, S):
+    return _scan_pair_of(higgs, S)
+
+
+def _scan_pair_msltr(higgs, expo, S, msltr):
+    """137 features: Fp 144, the widest pair scan a published
+    configuration of the reference asks for."""
+    assert msltr.ds.num_features == 137 == len(msltr.ds.groups)
+    return _scan_pair_of(msltr, S)
 
 
 def _scan_blocks(higgs, expo, S):
@@ -163,18 +210,21 @@ def _scan_blocks(higgs, expo, S):
              S(blk["masks"].shape, f32)))
 
 
+def _split_pass_of(built, S, wp_live, wpa):
+    assert (built.wp_live, built.pay[0]) == (wp_live, wpa), (
+        built.wp_live, built.pay)
+    return built.growers[-1]._split_pass, (
+        S(built.pay, jnp.uint32), S((N_SCALARS,), jnp.int32))
+
+
 def _split_pass(higgs, expo, S):
-    return higgs.growers[-1]._split_pass, (
-        S(higgs.pay, jnp.uint32), S((N_SCALARS,), jnp.int32))
+    return _split_pass_of(higgs, S, 12, 16)
 
 
 def _split_pass_msltr(higgs, expo, S, msltr):
     """40 live payload rows: the partition's tiles are five whole
     sublane tiles and its group of tiles is shorter."""
-    nbw = msltr.assets.geometry[4]
-    assert nbw + 5 == 40 == msltr.pay[0], msltr.assets.geometry[:5]
-    return msltr.growers[-1]._split_pass, (
-        S(msltr.pay, jnp.uint32), S((N_SCALARS,), jnp.int32))
+    return _split_pass_of(msltr, S, 40, 40)
 
 
 def _split_pass_expo(higgs, expo, S):
@@ -195,6 +245,39 @@ def _split_pass_expo(higgs, expo, S):
     return make_split_pass(WPA, NP, len(widths), plan, nbw, C=C,
                            wp_live=wp_live), (
         S((WPA, NP), jnp.uint32), S((N_SCALARS,), jnp.int32))
+
+
+def _split_pass_weighted(higgs, expo, S, higgs_weighted):
+    """A weight row rides the partition: 13 live rows, two sublane tiles
+    with three pad rows."""
+    return _split_pass_of(higgs_weighted, S, 13, 16)
+
+
+def _split_pass_3class(higgs, expo, S, higgs_3class):
+    """Three score rows and their iteration-start snapshot: 17 live rows
+    of 24, three sublane tiles."""
+    return _split_pass_of(higgs_3class, S, 17, 24)
+
+
+def _split_pass_15bins(higgs, expo, S, higgs_15bins):
+    """max_bin=15: every group a nibble, 28 groups in 4 bin words, 9 live
+    rows of 16 (the Expo cell's height at HIGGS's group count, with
+    seg_hist after the pass)."""
+    plan = higgs_15bins.assets.geometry[3]
+    assert all(mk == 15 for _, _, mk in plan), plan
+    return _split_pass_of(higgs_15bins, S, 9, 16)
+
+
+def _seg_hist_msltr(higgs, expo, S, msltr):
+    """137 groups: the [G, E] decode planes and the one-hot operand are
+    most of the request (seg_hist_vmem_bytes)."""
+    assert msltr.pay[0] == 40 and len(msltr.ds.groups) == 137
+    return msltr.growers[-1]._seg_hist, (
+        S(msltr.pay, jnp.uint32), S((), jnp.int32), S((), jnp.int32))
+
+
+def _root_hist_msltr(higgs, expo, S, msltr):
+    return msltr.growers[-1]._root_hist, (S(msltr.pay, jnp.uint32),)
 
 
 def _seg_hist(higgs, expo, S):
@@ -248,9 +331,11 @@ def _fused_driver(higgs, expo, S):
 
 
 @pytest.mark.parametrize("case", [
-    _hist_window, _scan_pair, _scan_blocks, _split_pass, _split_pass_msltr,
-    _split_pass_expo, _level_pass, _level_pass_inpass, _level_seg_hist, _seg_hist, _root_hist,
-    _fused_driver,
+    _hist_window, _scan_pair, _scan_pair_msltr, _scan_blocks, _split_pass,
+    _split_pass_msltr, _split_pass_expo, _split_pass_weighted,
+    _split_pass_3class, _split_pass_15bins, _level_pass, _level_pass_inpass,
+    _level_seg_hist, _seg_hist, _seg_hist_msltr, _root_hist,
+    _root_hist_msltr, _fused_driver,
 ], ids=lambda f: f.__name__.lstrip("_"))
 def test_compiles_for_v5e(case, one_chip, higgs, expo, request):
     def S(shape, dtype):

@@ -1,14 +1,12 @@
 """Expo-shaped EFB regression: the bundle fast path must ENGAGE and match.
 
-BENCH_r05 measured the Expo shape at 0.23x the reference CPU anchor; the
-bundle-native rebuild (block scan + in-pass smaller-child histogram +
-cached window masks) is only a win if the fast path actually takes these
+The bundle-native path (block scan + in-pass smaller-child histogram +
+cached window masks) only pays if the fast path actually takes these
 datasets. The regression test pins, via telemetry counters, that a small
 Expo-shaped training runs ENTIRELY on the persist driver (zero v1 trees,
 the block-scan grower built) while predictions still match the v1 grower.
 The profile-CLI smoke test keeps `python -m lightgbm_tpu.profile --shape
-expo` working on CPU so the bench's phase breakdown stays reproducible
-without the full bench.
+expo` working on CPU.
 """
 import json
 
@@ -66,8 +64,8 @@ def test_expo_bundle_fast_path_engages_and_matches_v1():
 @pytest.mark.slow  # tier-1 870s budget: profile --merge --run is covered in tier-1
 def test_profile_cli_expo_smoke(tmp_path):
     """`python -m lightgbm_tpu.profile --shape expo` runs tier-1-safe on
-    CPU (xplane off) and writes a BENCH_phases.json-style snapshot with
-    the per-category attribution + path counters."""
+    CPU (xplane off) and writes the telemetry snapshot with the
+    per-category attribution + path counters."""
     from lightgbm_tpu.profile import main
     out = tmp_path / "phases.json"
     try:

@@ -97,7 +97,7 @@ def test_forced_persist_with_host_only_objective_refuses_loudly():
 
 
 # ---------------------------------------------------------------------------
-# stats layout + perf-gate direction
+# stats layout
 # ---------------------------------------------------------------------------
 
 def test_driver_stats_layout():
@@ -110,11 +110,3 @@ def test_driver_stats_layout():
     # (serial.flush_level_stats) and both drivers index off these
     assert STAT_HEALTH0 == 3
     assert STATS_LEN > STAT_HEALTH0
-
-
-def test_launches_per_iter_gates_lower_better():
-    from lightgbm_tpu.analysis import perf_gate
-    assert "launches_per_iter" in perf_gate.LOWER_BETTER
-    # telemetry-off rounds omit the counter snapshot; the key must not
-    # sever the lineage when it vanishes for that reason
-    assert "launches_per_iter" in perf_gate.MEASUREMENT_CONDITIONAL

@@ -289,11 +289,3 @@ def test_program_cache_is_bucket_keyed_not_width_keyed(higgs):
         key="tree_learner::mm_programs")
     assert d_cold >= 1.0          # the cold call built the program
     assert d_warm == 0.0          # B=3 and B=4 share the bucket-4 program
-
-
-def test_perf_gate_registration():
-    from lightgbm_tpu.analysis import perf_gate
-    assert "models_per_sec" in perf_gate.HIGHER_BETTER
-    assert "sweep_compiles" in perf_gate.LOWER_BETTER
-    assert "sweep_compiles" in perf_gate.MEASUREMENT_CONDITIONAL
-    assert "models_per_sec" not in perf_gate.MEASUREMENT_CONDITIONAL

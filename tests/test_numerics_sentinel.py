@@ -477,48 +477,6 @@ def test_tpu_health_abort_unknown_kind_warns_not_raises():
     assert health.abort_kinds() == frozenset({"stall_burst"})
 
 
-def test_perf_sentinel_knows_margin_key():
-    from lightgbm_tpu.analysis import perf_gate
-    assert "margin_p01" in perf_gate.HIGHER_BETTER
-    assert "margin_p01" not in perf_gate.EXPECTED_KEYS
-    assert "margin_p01" in perf_gate.MEASUREMENT_CONDITIONAL
-
-
-def test_margin_p01_gates_regression_but_not_vanishing():
-    """margin_p01 is telemetry-conditional (BENCH_TELEMETRY is excluded
-    from the lineage fingerprint): a collapse between two rounds that
-    both carry it must gate, its ABSENCE from a telemetry-off round
-    must not read as a crashed phase."""
-    from lightgbm_tpu.analysis.perf_gate import evaluate, validate_round
-    base = {"value": 10.0, "ranking_value": 5.0, "expo_value": 3.0,
-            "expo_level_value": 4.0}
-
-    def rnd(i, parsed):
-        return validate_round({"parsed": parsed},
-                              "BENCH_r%02d.json" % i, i)
-    # collapse: 1.5 -> 0.01 with throughput flat — gates on margin_p01
-    rep = evaluate([rnd(1, dict(base, margin_p01=1.5)),
-                    rnd(2, dict(base, margin_p01=0.01))], 0.15)
-    assert [v.key for v in rep.regressions] == ["margin_p01"]
-    # one 2.0-growth bucket-edge hop (-50%) is quantization noise, not
-    # a regression (the widened KEY_BAND_FLOOR)
-    rep_hop = evaluate([rnd(1, dict(base, margin_p01=1.5)),
-                        rnd(2, dict(base, margin_p01=0.75))], 0.15)
-    assert not rep_hop.regressions
-    # vanish: recorded in r1, absent from r2 — NOT a missing verdict
-    rep2 = evaluate([rnd(1, dict(base, margin_p01=1.5)),
-                     rnd(2, dict(base))], 0.15)
-    assert not rep2.regressions
-    assert not any(v.key == "margin_p01" and v.status == "missing"
-                   for v in rep2.verdicts)
-    # a genuinely-crashed headline phase still gates (the PR11 rule)
-    rep3 = evaluate([rnd(1, dict(base)),
-                     rnd(2, {k: v for k, v in base.items()
-                             if k != "expo_value"})], 0.15)
-    assert any(v.key == "expo_value" and v.status == "missing"
-               for v in rep3.verdicts)
-
-
 def test_sentinel_knobs_are_resume_volatile():
     """Review-finding pin: flipping a numerics-sentinel knob must not
     orphan a run's checkpoints (the knobs observe the computation, they

@@ -286,23 +286,6 @@ def test_batchserver_latency_and_queue_wait_histograms():
     assert histo.histograms_snapshot()["predict::e2e_latency"].count == 1
 
 
-def test_poisson_open_loop_bench_smoke():
-    """The BENCH predict SLO generator on a toy server: pinned key set
-    and p50 <= p99 (plus sane queue-depth accounting)."""
-    import bench
-    server, X = _tiny_server()
-    rng = np.random.default_rng(11)
-    out = bench.poisson_open_loop(server, X, rps=200.0, n_requests=40,
-                                  rng=rng, batch_lo=16, batch_hi=64)
-    assert set(out) == {"requests", "rps", "p50", "p99",
-                       "queue_wait_p99", "qdepth_mean", "qdepth_max"}
-    assert out["requests"] == 40
-    assert 0.0 < out["p50"] <= out["p99"]
-    assert out["qdepth_mean"] >= 1.0          # the in-service request
-    assert out["qdepth_max"] >= out["qdepth_mean"]
-    assert out["queue_wait_p99"] >= 0.0
-
-
 # ---------------------------------------------------------------------------
 # cross-rank trace merge
 # ---------------------------------------------------------------------------

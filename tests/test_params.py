@@ -370,3 +370,48 @@ def test_reset_parameter_on_loaded_model():
     loaded.reset_parameter({"learning_rate": 0.05, "bagging_fraction": 0.5})
     assert loaded._booster.shrinkage_rate == 0.05
     np.testing.assert_allclose(loaded.predict(X), b.predict(X), atol=1e-12)
+
+
+# -- every tpu_* knob has a reader -----------------------------------------
+
+def _tpu_knobs():
+    from lightgbm_tpu.config import PARAMS
+    return [p.name for p in PARAMS if p.name.startswith("tpu_")]
+
+
+@pytest.fixture(scope="module")
+def names_read_by_the_package():
+    """Every identifier, and every string literal that is one bare name
+    (`getattr(config, "tpu_x", ...)`, `params.get("tpu_x")`), in package
+    code outside config.py. Comments and docstrings are neither."""
+    import ast
+    import os
+    import tokenize
+    import lightgbm_tpu
+    root = os.path.dirname(lightgbm_tpu.__file__)
+    seen = set()
+    for dirpath, _, files in os.walk(root):
+        for name in files:
+            path = os.path.join(dirpath, name)
+            if not name.endswith(".py") or path == os.path.join(
+                    root, "config.py"):
+                continue
+            with tokenize.open(path) as f:
+                tokens = list(tokenize.generate_tokens(f.readline))
+            for tok in tokens:
+                if tok.type == tokenize.NAME:
+                    seen.add(tok.string)
+                elif tok.type == tokenize.STRING and len(tok.string) < 64:
+                    try:
+                        seen.add(ast.literal_eval(tok.string))
+                    except (ValueError, SyntaxError):
+                        pass             # f-strings
+    return seen
+
+
+@pytest.mark.parametrize("knob", _tpu_knobs())
+def test_every_tpu_knob_is_read(knob, names_read_by_the_package):
+    """A parameter that config.py declares and nothing reads is a
+    configuration the tests and the benchmark would have to cover for
+    nothing: delete it, or wire it."""
+    assert knob in names_read_by_the_package

@@ -222,47 +222,6 @@ def test_wire_bytes_model_shapes():
     assert gr_1.wire_bytes_model(0, 6, 1) == (0, 0)
 
 
-def test_multichip_round_r07_records_payload_keys():
-    """MULTICHIP_r07 is the first round with the quantized + voting
-    exchange engaged: the payload keys the --perf sentinel gates must be
-    present and the compression must clear the 3x acceptance pin."""
-    import json
-    import os
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "MULTICHIP_r07.json")) as fh:
-        payload = json.load(fh)
-    assert payload["ok"] and payload["rc"] == 0
-    parsed = payload["parsed"]
-    assert parsed["hist_compress_ratio"] >= 3.0
-    assert 0.0 < parsed["reduced_feature_frac"] < 1.0
-    assert parsed["dcn_hist_bytes"] * 3 <= parsed[
-        "dcn_hist_bytes_fullwidth"]
-    # and the sentinel keys are registered with directions
-    from lightgbm_tpu.analysis import perf_gate
-    assert "hist_compress_ratio" in perf_gate.HIGHER_BETTER
-    assert "dcn_hist_bytes" in perf_gate.LOWER_BETTER
-    assert "reduced_feature_frac" in perf_gate.LOWER_BETTER
-
-
-def test_perf_multichip_gates_payload_regression():
-    """A later multichip round whose compression collapses must flip the
-    perf_multichip verdict."""
-    from lightgbm_tpu.analysis import perf_gate
-    good = {"index": 7, "ok": True, "rc": 0,
-            "parsed": {"hist_compress_ratio": 6.0,
-                       "dcn_hist_bytes": 100_000}}
-    bad = {"index": 8, "ok": True, "rc": 0,
-           "parsed": {"hist_compress_ratio": 1.0,
-                      "dcn_hist_bytes": 600_000}}
-    rep = perf_gate.evaluate([], 0.15, multichip=[good, bad])
-    res = {r.name: r for r in perf_gate.run(artifact=rep)}
-    assert not res["perf_multichip"].ok
-    rep_ok = perf_gate.evaluate([], 0.15, multichip=[good, dict(
-        good, index=8)])
-    res_ok = {r.name: r for r in perf_gate.run(artifact=rep_ok)}
-    assert res_ok["perf_multichip"].ok
-
-
 # ---------------------------------------------------------------------------
 # end-to-end sharded training (slow: 8-device shard_map compiles)
 # ---------------------------------------------------------------------------

@@ -381,16 +381,13 @@ def test_checkpoint_counters_pinned(tmp_path):
 
 def test_checkpoint_write_overhead_under_3_percent(tmp_path):
     """The acceptance budget: checkpoint::write seconds < 3% of train
-    wall on a HIGGS-like shape (bench.py's checkpoint phase measures the
-    same ratio at full scale)."""
+    wall on a HIGGS-like shape (on the CPU; not measured at full scale)."""
     from lightgbm_tpu import telemetry
     from lightgbm_tpu.data.synth import make_higgs_like
     X, y = make_higgs_like(6_000)
     # tmpfs when available: this CI box's fsync latency is wildly
     # variable (0.1-1s under IO contention) and would dominate the toy
     # 10s train wall; the pin targets the serialization/write PATH cost
-    # (bench.py's checkpoint phase measures real-disk overhead at the
-    # 2M-row scale where the 3% budget is meant to hold)
     base = "/dev/shm" if os.access("/dev/shm", os.W_OK) else str(tmp_path)
     d = os.path.join(base, "lgbtpu_ck_overhead")
     shutil.rmtree(d, ignore_errors=True)

@@ -553,7 +553,6 @@ class _Dev:
 def test_detect_profile_matches_device_kind_or_raises(monkeypatch):
     import jax
     from lightgbm_tpu.telemetry import devices
-    monkeypatch.delenv("LGBTPU_DEVICE_PROFILE", raising=False)
     # the v5e chip reports itself as "TPU v5 lite", not "v5e"
     monkeypatch.setattr(jax, "devices", lambda *a: [_Dev("TPU v5 lite")])
     assert devices.detect_profile().name == "v5e"
@@ -566,9 +565,10 @@ def test_detect_profile_matches_device_kind_or_raises(monkeypatch):
     monkeypatch.setattr(jax, "devices", no_backend)
     with pytest.raises(RuntimeError):
         devices.detect_profile()
-    # a machine without the accelerator names its device itself
-    monkeypatch.setenv("LGBTPU_DEVICE_PROFILE", "v4")
-    assert devices.detect_profile().name == "v4"
+    # the CPU is not a device with a profile
+    monkeypatch.setattr(jax, "devices", lambda *a: [_Dev("cpu")])
+    with pytest.raises(ValueError, match="no device profile"):
+        devices.detect_profile()
 
 
 @pytest.mark.parametrize("backend,want", [("tpu", True), ("cpu", False),
