@@ -656,11 +656,21 @@ class GBDT:
         import jax
         ntpi = self.num_tree_per_iteration
         kb = int(host_b.num_leaves.shape[0])
+        parent_rows = small_rows = 0
         for i in range(kb):
             cls = i % ntpi
             ha = jax.tree.map(lambda a, i=i: a[i], host_b)
             tree = Tree.from_grower(ha, self.train_data)
             if tree.num_leaves > 1:
+                # run record: the rows the batch's splits moved, and the
+                # rows of each split's smaller child (what its histogram
+                # has to cost), from the counts the tree carries
+                ni = tree.num_leaves - 1
+                kids = [np.where(c >= 0, tree.internal_count[np.maximum(c, 0)],
+                                 tree.leaf_count[np.maximum(~c, 0)])
+                        for c in (tree.left_child[:ni], tree.right_child[:ni])]
+                parent_rows += int(tree.internal_count[:ni].sum(dtype=np.int64))
+                small_rows += int(np.minimum(*kids).sum(dtype=np.int64))
                 if bmode == "rf":
                     # rf.hpp:103-160: no shrinkage, EVERY tree gets
                     # the constant init-score bias (the device dance
@@ -678,6 +688,10 @@ class GBDT:
                     # the boosted-from-average output (gbdt.cpp:396-411)
                     tree.leaf_value[0] = init0s[cls]
             self.models[start + i] = tree
+        telemetry.count("tree_learner::split_parent_rows",
+                        float(parent_rows), category="tree_learner")
+        telemetry.count("tree_learner::split_small_rows",
+                        float(small_rows), category="tree_learner")
 
     def _truncate_if_stopped(self) -> None:
         """Batch entries can contain a 1-leaf tree (no-split stop

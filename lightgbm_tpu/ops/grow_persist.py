@@ -111,16 +111,6 @@ def can_level_grow(gc) -> bool:
             and gc.parallel_mode != "voting"
             and int(gc.n_forced) == 0)
 
-# group count at or below which the smaller-child histogram accumulates
-# IN the split_pass kernel instead of a separate post-partition seg_hist
-# pass: with few (wide) groups the per-row MXU histogram work is cheap and
-# the extra kernel launch per split dominates (the Expo shape: 16 groups,
-# 254 launches/tree saved); with many groups the seg_hist economy (only
-# ~n/2 rows touched per level instead of all n) wins back the launch.
-# Either way the leaf-wise subtraction trick still applies — only WHERE
-# the smaller child's histogram is computed changes.
-SEG_HIST_MIN_GROUPS = 20
-
 
 class PersistPackError(ValueError):
     """A dataset geometry the persist payload pack plan cannot express.
@@ -777,27 +767,28 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
                                          out_dtype=EV)
         root_hist = make_xla_root_hist(WPA, NP, G, plan, nbw, n,
                                        out_dtype=EV)
-        seg_hist = None
-        inpass_hist = True
+        seg_hist = None     # the emulated pass builds the histogram
     else:
         from .pallas_grow import (_unpack_hist as _unpack_hist_v,
                                   make_level_pass, make_level_seg_hist,
                                   make_seg_hist)
         # every score/snapshot/weight row must ride the partition
         wp_live = payload_weight_row(nbw, K, score64) + (1 if has_w else 0)
-        # smaller-child histogram placement (geometry heuristic): with
-        # few (wide) groups it accumulates IN split_pass — the rows are
-        # already in VMEM and the per-split seg_hist launch dominates;
-        # with many groups a SEPARATE post-partition segment pass
-        # (make_seg_hist) touches only the ~n/2 smaller-child rows per
-        # level. Both feed the same parent-minus-smaller subtraction.
-        inpass_hist = G <= SEG_HIST_MIN_GROUPS
+        # the smaller child's histogram is built AFTER the pass, by
+        # seg_hist over the child's contiguous segment, at every group
+        # count. The chip decided it (PERF.md, PR 35; expo.train_steady,
+        # 16 groups): with the histogram inside the pass over all lanes
+        # of the parent's chunks a traced launch spent 12.20 s in
+        # split_pass; over the compacted slot only (pallas_grow.
+        # _slot_hist) 7.22 s; with seg_hist 6.59 + 0.51 s, 4,064 more
+        # launches a launch of 16 trees included, and the launches after
+        # it 1-3% shorter again. Both feed the same parent-minus-smaller
+        # subtraction.
         split_pass = make_split_pass(WPA, NP, G, plan, nbw, C=C,
                                      interpret=interpret, wp_live=wp_live,
-                                     _skip_hist=not inpass_hist)
-        seg_hist = (None if inpass_hist else
-                    make_seg_hist(WPA, NP, G, plan, nbw, C=C,
-                                  interpret=interpret))
+                                     _skip_hist=True)
+        seg_hist = make_seg_hist(WPA, NP, G, plan, nbw, C=C,
+                                 interpret=interpret)
         root_hist = make_root_hist(WPA, NP, G, plan, nbw, n, C=CR,
                                    interpret=interpret)
         if use_level:
@@ -805,12 +796,10 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
             # never constructed per level (JG004's no-pallas-in-loop)
             level_pass = make_level_pass(
                 WPA, NP, G, plan, nbw, S_MAXL, T_MAXL, C=C,
-                interpret=interpret, wp_live=wp_live,
-                _skip_hist=not inpass_hist)
-            level_seg = (None if inpass_hist else
-                         make_level_seg_hist(WPA, NP, G, plan, nbw,
-                                             S_MAXL, T_MAXL, C=C,
-                                             interpret=interpret))
+                interpret=interpret, wp_live=wp_live, _skip_hist=True)
+            level_seg = make_level_seg_hist(WPA, NP, G, plan, nbw,
+                                            S_MAXL, T_MAXL, C=C,
+                                            interpret=interpret)
     grad_row = nbw + 2
     SR = 2 if score64 else 1       # payload rows per score value
     score_row = nbw + 4            # class k's score rows at +SR*k
@@ -1351,18 +1340,17 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
                     so = jnp.minimum(jnp.searchsorted(
                         ends, jnp.arange(T_MAXL, dtype=I32),
                         side="right").astype(I32), S_MAXL - 1)
-                    pay2, hist_raw, n_lefts = level_pass(
+                    pay2, _, n_lefts = level_pass(
                         st.pay, scal_mat, so, base, ends[S_MAXL - 1])
-                    sm_g, sm_h = jax.vmap(_unpack_hist_v)(hist_raw)
                     # zero-step slots (active leaf, empty shard-local
-                    # segment) leave the kernel's hist/count outputs
+                    # segment) leave the kernel's count output
                     # UNDEFINED — the per-split tail's `ran` guard,
                     # mirrored here before anything is summed or psum'd
                     act_h = act & (n_l > 0)
                 n_lefts = jnp.where(act_h, n_lefts, 0)
                 if level_seg is not None:
-                    # many-group geometry: batched post-partition
-                    # smaller-child segment histograms (one launch)
+                    # batched post-partition smaller-child segment
+                    # histograms (one launch)
                     start_sm = jnp.where(smaller_is_left, s0,
                                          s0 + n_lefts)
                     len_sm = jnp.where(
@@ -2038,7 +2026,7 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
     # which mechanisms this grower's splits go through (the learner
     # counts trees by them: blockscan_trees, inpass_hist_trees)
     gr.block_scan = bool(bundled and not wide)
-    gr.inpass_hist = bool(inpass_hist)
+    gr.inpass_hist = seg_hist is None
     gr.use_level = use_level
     gr.S_MAXL = S_MAXL
     gr.health = health

@@ -31,14 +31,19 @@ Mosaic kernels over a single transposed payload matrix:
     splitting leaf's contiguous payload segment once, and per chunk
       - decides go_left per row (DenseBin::Split semantics at the bin
         level, src/io/dense_bin.hpp:112-207; numerical features),
-      - accumulates the SMALLER child's histogram as radix-16 one-hot MXU
-        contractions (the GPU histogram kernel analog,
-        src/treelearner/ocl/histogram256.cl, re-derived for the MXU),
       - partitions the chunk tile by tile: one prefix sum gives both sides'
         control words, 7 hole-shift lane stages compact each 128-lane tile
         in registers, and a dynamic roll appends the kept lanes to the
         FIFO slot at the offset the drain will write them (word moves only,
         bit-exact, no sort, no scratch matmul),
+      - unless ``_skip_hist``, accumulates the SMALLER child's histogram
+        as radix-16 one-hot MXU contractions (the GPU histogram kernel
+        analog, src/treelearner/ocl/histogram256.cl, re-derived for the
+        MXU) over the rows just compacted into that child's slot, block
+        by block, so its cost follows the smaller child and not the chunk
+        (_slot_hist). The persist grower skips it and runs seg_hist after
+        the pass instead, which read 2-3% faster on the chip at 16.5M
+        rows (PERF.md, PR 35),
       - partitions the payload IN PLACE: a two-ended writeback with a
         2-chunk FIFO. Chunks are read from whichever end has the smaller
         write-space gap and drained two steps later, so reads always lead
@@ -52,8 +57,8 @@ Mosaic kernels over a single transposed payload matrix:
     before it is carried in VMEM from one chunk's partition to the next,
     and the old payload is read in two tiles only, the segment's own ends
     (where a neighbouring leaf's rows live), once, before any write. The
-    writes stay in flight under the next chunk's read, decision and
-    histogram (_make_segment_step has the hazard argument).
+    writes stay in flight under the next chunk's read and decision
+    (_make_segment_step has the hazard argument).
 
   * root_hist (static grid): one streaming pass building the root histogram
     and the gradient/hessian totals.
@@ -359,6 +364,42 @@ def _hist_accum(hist_ref, bins_g, grad, hess, G: int):
             oh_hi, bv, dn, preferred_element_type=F32)            # [16, 64]
 
 
+# lanes one block of _slot_hist histograms at a time: a block is the least
+# a chunk's smaller child costs. Chip readings, kernel alone at the Expo
+# cell's geometry (PERF.md, PR 35): 256 and 512 cost a child of 3% of the
+# chunk 0.4-0.5 us a step, 1,024 to 4,096 1-2.5 us more; at half a chunk
+# 512 to 2,048 read alike and 256 loses 2 us
+HIST_BLOCK = 512
+
+
+def _slot_hist(hist_ref, slot, plan, grad_row: int, G: int, B: int, off, n):
+    """hist_ref += histogram of the rows in lanes [off, off + n) of one
+    FIFO slot, the smaller child's rows of the chunk _partition_chunk has
+    just compacted there: B lanes a block, ceil((off + n) / B) blocks, so
+    the cost follows n and not the chunk. slot: VMEM ref [>= grad_row + 2,
+    W]; off < 128 (the block's sub-tile offset); lanes outside the range
+    hold other blocks' rows, already counted, or nothing, and are masked
+    by select (an undefined lane may hold any bit pattern). The last
+    block that can occur is pulled back to end at W and skips the lanes
+    the block before it took.
+    """
+    W = slot.shape[1]
+    end = off + n
+    lane = jax.lax.broadcasted_iota(I32, (1, B), 1)[0]
+
+    def block(k, carry):
+        a = jnp.minimum(k * B, W - B)
+        x = slot[0:grad_row + 2, pl.ds(pl.multiple_of(a, 128), B)]
+        pos = a + lane
+        keep = (pos >= jnp.maximum(off, k * B)) & (pos < end)
+        grad = jnp.where(keep, _f32r(x[grad_row, :]), 0.0)
+        hess = jnp.where(keep, _f32r(x[grad_row + 1, :]), 0.0)
+        _hist_accum(hist_ref, _unpack_group_bins(x, plan), grad, hess, G)
+        return carry
+
+    jax.lax.fori_loop(0, jnp.where(n > 0, (end + B - 1) // B, 0), block, 0)
+
+
 def plane_health(g_plane, h_plane):
     """i32 count of non-finite entries across a (grad, hess) histogram
     plane pair — the ``numerics::inf_hist`` device probe the persist
@@ -428,7 +469,11 @@ def _make_segment_step(C: int, G: int, plan, nbw: int, WP_LIVE: int,
         7+2p nL(slot pair p), 8+2p nR(slot pair p)
 
     Step lo reads chunk lo (lo < nch) into slot pair lo % 2 and drains
-    block lo - 2 (2 <= lo < nch + 2) out of the same pair first. A left
+    block lo - 2 (2 <= lo < nch + 2) out of the same pair first. Unless
+    ``skip_hist``, it then adds the rows of the child S_SMALL_L names to
+    ``hist`` from that child's slot, where the partition has just laid
+    them in order (_slot_hist: as many blocks as the rows fill; reads of
+    VMEM no DMA writes). A left
     block covers payload lanes [lf, lf + nL), a right block
     [rf - nR, rf); _partition_chunk has laid each in its slot at the
     sub-tile offset it has in the payload and has closed the tile it
@@ -585,14 +630,6 @@ def _make_segment_step(C: int, G: int, plan, nbw: int, WP_LIVE: int,
             nR = m - nL
             st[6] = st[6] + nL
 
-            # smaller-child histogram
-            hm = (valid & (go_left == (sc(S_SMALL_L) > 0))).astype(F32)
-            grad = _f32r(w[grad_row, :]) * hm
-            hess = _f32r(w[grad_row + 1, :]) * hm
-            if not skip_hist:
-                bins_g = _unpack_group_bins(w, plan)
-                _hist_accum(hist, bins_g, grad, hess, G)
-
             # slot pair p is refilled below: its drain has to be out
             pl.when(draining)(wait_writes)
             if skip_pack:
@@ -606,6 +643,19 @@ def _make_segment_step(C: int, G: int, plan, nbw: int, WP_LIVE: int,
                 _partition_chunk(
                     obuf, R, gl, m, vl & 127, (vr - nR) & 127,
                     slots.at[2 * p], slots.at[2 * p + 1], carry, ctl, tcnt)
+            if not skip_hist:
+                # the smaller child's histogram, from the rows the
+                # partition has just laid in its slot and from no other
+                # lane. The left side's open tile is not in the slot
+                # (_partition_chunk): put it there
+                base_l = vl & 127
+                slots[2 * p, :, pl.ds(pl.multiple_of(
+                    ((base_l + nL) >> 7) * 128, 128), 128)] = carry[0]
+                small_l = sc(S_SMALL_L) > 0
+                _slot_hist(hist, slots.at[2 * p + jnp.where(small_l, 0, 1)],
+                           plan, grad_row, G, min(HIST_BLOCK, C),
+                           jnp.where(small_l, base_l, (vr - nR) & 127),
+                           jnp.where(small_l, nL, nR))
             st[7 + 2 * p] = nL
             st[8 + 2 * p] = nR
             st[4] = vl + nL
@@ -748,12 +798,12 @@ def make_level_pass(WPA: int, NP: int, G: int, plan, nbw: int,
     """Multi-leaf split_pass: the level-parallel grower's fused partition.
 
     One pallas_call partitions the payload segments of up to ``S_max``
-    splitting leaves (slots) and accumulates each slot's smaller-child
-    histogram — the per-split kernel's steps (_make_segment_step) with
-    the slot id derived per grid step from prefetched step tables, so a
-    whole tree level costs ONE device-program launch instead of one per
-    split (the launch/dispatch overhead that dominated EFB-bundled
-    shapes like Expo: ~254 launches per 255-leaf tree).
+    splitting leaves (slots) and, unless ``_skip_hist`` (the grower
+    skips it, as in make_split_pass), accumulates each slot's
+    smaller-child histogram — the per-split kernel's steps
+    (_make_segment_step) with the slot id derived per grid step from
+    prefetched step tables, so a whole tree level costs ONE
+    device-program launch instead of one per split.
 
     Per-slot scalars arrive as one [S_max, 16] i32 matrix in S_* column
     order (columns 15 unused); ``slot_of_step`` [T_max] and
@@ -848,8 +898,7 @@ def make_level_seg_hist(WPA: int, NP: int, G: int, plan, nbw: int,
                         interpret: bool = False):
     """Batched seg_hist: smaller-child histograms of up to ``S_max``
     contiguous payload segments in ONE launch (the level-parallel
-    companion of make_seg_hist, used when the group count makes the
-    in-partition histogram accumulation uneconomical).
+    companion of make_seg_hist).
 
     Per-slot scalars: [S_max, 4] i32 (nch, start, length, pad); step
     tables as in make_level_pass. Returns fn(pay, scal_mat,
@@ -932,9 +981,11 @@ def make_seg_hist(WPA: int, NP: int, G: int, plan, nbw: int,
     Runs AFTER split_pass has partitioned a leaf: the smaller child's rows
     are contiguous, so the histogram streams exactly those rows — the
     leaf-wise subtraction trick then charges each tree level ~n/2 histogram
-    rows instead of the ~n that in-split masked accumulation pays (the
-    reference's ordered-bin smaller-leaf walk, include/LightGBM/bin.h:229,
-    achieves the same economy row-wise on CPU).
+    rows (the reference's ordered-bin smaller-leaf walk,
+    include/LightGBM/bin.h:229, achieves the same economy row-wise on CPU;
+    split_pass's own in-slot histogram does too, without this launch or a
+    second read of the rows, and yet read slower on the chip: PERF.md,
+    PR 35).
 
     Returns fn(pay, start, length) -> (gh [G*256], hh [G*256]) f32; outputs
     are UNDEFINED when length == 0 (zero grid steps) — callers mask.

@@ -8,8 +8,10 @@ each ``pallas_call`` site at the geometry the code itself builds for a real
 configuration — the persist grower's kernels for HIGGS (10.5M x 28,
 255 bins, 255 leaves), the same payload with a finite ``max_depth`` for the
 level kernels, an EFB-bundled Expo-like payload (11M rows) for the
-block scan, the benchmark's Expo cell's own split_pass (9 live rows, a
-nibble slot, the histogram in the pass), the MS-LTR payload (137 features:
+block scan, the benchmark's Expo cell's geometry (9 live rows, a nibble
+slot) for the seg_hist it runs after each pass and for the split_pass
+that builds the histogram inside the pass, which no grower takes since
+PR 35, the MS-LTR payload (137 features:
 40 live rows, whole sublane tiles, so split_pass has no spare sublane; its
 histogram kernels and its pair scan at 137 groups), and the HIGGS rows under
 the other static shapes every persist configuration can take: a weight row
@@ -45,7 +47,8 @@ from lightgbm_tpu.data.synth import (make_expo_like, make_higgs_like,
                                       make_ltr_like)
 from lightgbm_tpu.objectives import create_objective
 from lightgbm_tpu.ops import grow_persist as gp
-from lightgbm_tpu.ops.pallas_grow import N_SCALARS, make_split_pass
+from lightgbm_tpu.ops.pallas_grow import (N_SCALARS, make_level_pass,
+                                          make_seg_hist, make_split_pass)
 from lightgbm_tpu.ops.pallas_histogram import hist_window
 from lightgbm_tpu.ops.pallas_scan import (ScanLayout, build_block_scan_meta,
                                           scan_blocks, scan_pair)
@@ -227,13 +230,13 @@ def _split_pass_msltr(higgs, expo, S, msltr):
     return _split_pass_of(msltr, S, 40, 40)
 
 
-def _split_pass_expo(higgs, expo, S):
-    """expo.train_steady's own kernel, at the storage groups EFB finds on
+def _expo_cell():
+    """expo.train_steady's payload, from the storage groups EFB finds on
     the cell's rows (PERF.md, section 4: 16 groups of these level counts,
-    two of them narrow enough for a nibble): 9 live payload rows in 16,
-    the smaller child's histogram inside the pass. The `expo` fixture's
-    20,000 sampled rows bundle into fewer groups and a payload half as
-    tall, so the kernel is built here from the widths."""
+    two of them narrow enough for a nibble): 9 live payload rows in 16.
+    The `expo` fixture's 20,000 sampled rows bundle into fewer groups and
+    a payload half as tall, so the cell's kernels are built here from the
+    widths."""
     widths = [256, 256, 8, 13, 23, 32, 128, 128,
               28, 32, 36, 40, 44, 48, 52, 56]
     plan, nbw = gp._payload_plan(widths)
@@ -241,10 +244,27 @@ def _split_pass_expo(higgs, expo, S):
     wp_live = gp.payload_weight_row(nbw, 1)
     assert (WPA, C, nbw, wp_live) == (16, 16384, 4, 9)
     assert any(mk == 15 for _, _, mk in plan)
-    assert len(widths) <= gp.SEG_HIST_MIN_GROUPS
-    return make_split_pass(WPA, NP, len(widths), plan, nbw, C=C,
-                           wp_live=wp_live), (
+    return WPA, NP, len(widths), plan, nbw, C, wp_live
+
+
+def _split_pass_expo(higgs, expo, S):
+    """split_pass with the smaller child's histogram built inside the
+    pass, from the slot the partition has just compacted (_slot_hist), at
+    the cell's geometry. Since PR 35 the grower takes seg_hist after the
+    pass at every group count (PERF.md, section 6), so no grower builds
+    this kernel: it is made here."""
+    WPA, NP, G, plan, nbw, C, wp_live = _expo_cell()
+    return make_split_pass(WPA, NP, G, plan, nbw, C=C, wp_live=wp_live), (
         S((WPA, NP), jnp.uint32), S((N_SCALARS,), jnp.int32))
+
+
+def _seg_hist_expo(higgs, expo, S):
+    """What the cell runs after each pass since PR 35: seg_hist at its
+    geometry (16 groups, one nibble slot, WPA 16); its split_pass is the
+    9-live-row kernel without a histogram, as _split_pass_15bins has it."""
+    WPA, NP, G, plan, nbw, C, _ = _expo_cell()
+    return make_seg_hist(WPA, NP, G, plan, nbw, C=C), (
+        S((WPA, NP), jnp.uint32), S((), jnp.int32), S((), jnp.int32))
 
 
 def _split_pass_weighted(higgs, expo, S, higgs_weighted):
@@ -302,11 +322,17 @@ def _level_pass(higgs, expo, S):
 
 
 def _level_pass_inpass(higgs, expo, S):
-    """<= SEG_HIST_MIN_GROUPS groups: the smaller-child histograms
-    accumulate inside the partition pass (the Expo shape)."""
+    """level_pass with the smaller-child histograms built inside the
+    pass (_slot_hist per slot), at the bundled Expo-like payload. The
+    grower builds level_pass without them (seg route, PR 35), so the
+    kernel is made here from the grower's own geometry."""
     gr = expo.growers[LEVEL_DEPTH]
-    assert gr.use_level and gr._level_seg is None
-    return gr._level_pass, _level_args(expo, gr, S)
+    assert gr.use_level and gr._level_seg is not None
+    WPA, NP, G, plan, nbw = expo.assets.geometry[:5]
+    C = expo.assets.geometry[6]
+    return make_level_pass(WPA, NP, G, plan, nbw, gr.S_MAXL, gr.T_MAXL,
+                           C=C, wp_live=expo.wp_live), _level_args(
+                               expo, gr, S)
 
 
 def _level_seg_hist(higgs, expo, S):
@@ -334,8 +360,8 @@ def _fused_driver(higgs, expo, S):
     _hist_window, _scan_pair, _scan_pair_msltr, _scan_blocks, _split_pass,
     _split_pass_msltr, _split_pass_expo, _split_pass_weighted,
     _split_pass_3class, _split_pass_15bins, _level_pass, _level_pass_inpass,
-    _level_seg_hist, _seg_hist, _seg_hist_msltr, _root_hist,
-    _root_hist_msltr, _fused_driver,
+    _level_seg_hist, _seg_hist, _seg_hist_msltr, _seg_hist_expo,
+    _root_hist, _root_hist_msltr, _fused_driver,
 ], ids=lambda f: f.__name__.lstrip("_"))
 def test_compiles_for_v5e(case, one_chip, higgs, expo, request):
     def S(shape, dtype):
