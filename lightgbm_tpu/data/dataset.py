@@ -20,6 +20,8 @@ src/io/dataset.cpp, feature_group.h:21). Differences by design:
 """
 from __future__ import annotations
 
+import os
+from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, List, Optional, Sequence
 
 import numpy as np
@@ -106,6 +108,10 @@ class SampleCols:
         self.values = values
         self.rows = rows
         self.total = total
+
+
+# columns from which BinMapper.find_bin runs on a thread pool
+_FIND_BIN_THREADS_MIN_FEATURES = 64
 
 
 def _sample_data(X: np.ndarray, sample_cnt: int, seed: int) -> np.ndarray:
@@ -277,8 +283,8 @@ class BinnedDataset:
         if mbbf and len(mbbf) != nf:
             Log.fatal("max_bin_by_feature has %d entries for %d features"
                       % (len(mbbf), nf))
-        ds.bin_mappers = []
-        for f in range(nf):
+
+        def _mapper(f):
             col = _col(f)
             nonzero = col[(np.abs(col) > kZeroThreshold) | np.isnan(col)]
             m = BinMapper()
@@ -291,7 +297,17 @@ class BinnedDataset:
                 use_missing=config.use_missing,
                 zero_as_missing=config.zero_as_missing,
                 forced_upper_bounds=forced.get(f, ()))
-            ds.bin_mappers.append(m)
+            return m
+
+        if nf >= _FIND_BIN_THREADS_MIN_FEATURES:
+            # a feature's mapper hangs on its own column alone, and most of
+            # its time is a sort of the sampled values, which numpy runs
+            # without the interpreter's lock: 2,000 dense columns of a
+            # 200,000-row sample take a minute on one thread
+            with ThreadPoolExecutor(min(os.cpu_count() or 1, 16)) as pool:
+                ds.bin_mappers = list(pool.map(_mapper, range(nf)))
+        else:
+            ds.bin_mappers = [_mapper(f) for f in range(nf)]
 
         ds.used_features = [f for f in range(nf) if not ds.bin_mappers[f].is_trivial]
         if not ds.used_features:

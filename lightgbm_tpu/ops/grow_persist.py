@@ -51,10 +51,12 @@ from ..telemetry.health import (H_INF_HIST, H_NAN_GRAD, H_NAN_HESS,
                                 HEALTH_LEN, NUM_HEALTH)
 from ..utils.log import Log
 from .grow import TreeArrays
-from .pallas_grow import (N_SCALARS, S_DB, S_DL, S_LE, S_LS, S_MASK, S_MF,
-                          S_MT, S_NB, S_NCH, S_NL, S_S0, S_SH, S_SMALL_L,
-                          S_THR, S_WG, make_root_hist, make_split_pass,
-                          plane_health)
+from .pallas_grow import (HIST_UNROLL_MAX_GROUPS, N_SCALARS, S_DB, S_DL,
+                          S_LE, S_LS, S_MASK, S_MF, S_MT, S_NB, S_NCH, S_NL,
+                          S_S0, S_SH, S_SMALL_L, S_THR, S_WG, VMEM_CAP,
+                          hist_loops_groups, make_root_hist, make_split_pass,
+                          plane_health, seg_hist_vmem_bytes,
+                          split_pass_vmem_bytes)
 from .pallas_scan import (ScanLayout, margin_bucket_index, scan_pair,
                           topk_vote_indices)
 from .quantize import plane_psum, quant_tag, vote_allgather
@@ -230,13 +232,21 @@ def payload_weight_row(nbw: int, num_scores: int,
     return nbw + 4 + SR * K + (SR * K if K > 1 else 0)
 
 
-def _payload_geometry(n: int, nbw: int, C: int, CR: int,
+def _fit_chunk(lanes: int, footprint) -> int:
+    """``lanes`` halved (floor 1,024) until ``footprint(lanes)``, a
+    kernel's unclipped scoped-VMEM request in bytes, is under the cap."""
+    while lanes > 1024 and footprint(lanes) >= VMEM_CAP:
+        lanes //= 2
+    return lanes
+
+
+def _payload_geometry(n: int, nbw: int, G: int, C: int = 0, CR: int = 0,
                       num_scores: int = 1, has_weight: bool = False,
-                      score64: bool = False):
-    """Payload rows: bins words | label | rid | grad | hess | score*K
-    [| snapshot*K when K > 1] [| weight]. nbw comes from the pack plan
-    (_payload_plan — nibble-packed narrow groups shrink it below the
-    historical (G+3)//4). Multiclass (K = num_class trees
+                      score64: bool = False, loop_groups=None):
+    """(WPA, C, CR, NP). Payload rows: bins words | label | rid | grad |
+    hess | score*K [| snapshot*K when K > 1] [| weight]. nbw comes from
+    the pack plan (_payload_plan — nibble-packed narrow groups shrink it
+    below the historical (G+3)//4). Multiclass (K = num_class trees
     per iteration) carries one score row per class plus an iteration-start
     snapshot block: the reference computes all K classes' gradients from
     the PRE-iteration scores (GBDT::Boosting once per TrainOneIter,
@@ -244,21 +254,35 @@ def _payload_geometry(n: int, nbw: int, C: int, CR: int,
     the snapshot while per-class score updates land in the live rows.
     Weighted datasets append one f32 weight row that rides the partition;
     unweighted payloads pay nothing. score64 widens the score rows to
-    u32 pairs (the XLA kernel mode's f64 boosting state)."""
+    u32 pairs (the XLA kernel mode's f64 boosting state).
+
+    C (the chunk of split_pass and seg_hist) and CR (root_hist's) follow
+    from the row's width and the group count when not given: the kernels
+    raise the Mosaic scoped-VMEM limit to their footprint (v5e carries
+    128MB), so chunks are sized for DMA-latency amortization, not the
+    16MB default — every chunk waits for its read and pays a step's fixed
+    scalar work — and start at 16384 lanes (8192 past 56 payload words:
+    split_pass holds nine chunk-sized buffers); a row too wide for that
+    halves them until the kernels' own footprint formulas
+    (pallas_grow.split_pass_vmem_bytes, seg_hist_vmem_bytes) fit the cap
+    unclipped: 2,048 lanes for C at 512 words (2,000 dense byte columns).
+    loop_groups: pallas_grow.hist_loops_groups of the plan (None: of a
+    byte plan in group order)."""
     K = num_scores
     WP = payload_weight_row(nbw, K, score64) + (1 if has_weight else 0)
     WPA = ((WP + 7) // 8) * 8
+    looped = (G > HIST_UNROLL_MAX_GROUPS if loop_groups is None
+              else bool(loop_groups))
     if C <= 0:
-        # split_pass VMEM scales with WPA (6 chunk-sized u32 buffers + the
-        # hist accumulator + compaction temporaries). The kernel raises the
-        # Mosaic scoped-VMEM limit to its footprint (v5e carries 128MB),
-        # so chunks are sized for DMA-latency amortization, not the 16MB
-        # default: every chunk waits for its read and pays a step's fixed
-        # scalar work
-        C = 16384 if WPA <= 56 else 8192
+        C = _fit_chunk(16384 if WPA <= 56 else 8192, lambda c: max(
+            split_pass_vmem_bytes(WPA, c + 128, G, cap=None),
+            seg_hist_vmem_bytes(WPA, c + 128, G, looped, cap=None)))
+    if CR <= 0:
+        CR = _fit_chunk(16384, lambda c: seg_hist_vmem_bytes(
+            WPA, c, G, looped, cap=None))
     NP = max(((n + 127) // 128 + 2) * 128 + C + 256,
              ((n + CR - 1) // CR) * CR)
-    return WPA, C, NP
+    return WPA, C, CR, NP
 
 
 def _pack_payload(binned: np.ndarray, labels: np.ndarray, n: int,
@@ -292,7 +316,7 @@ def _pack_payload(binned: np.ndarray, labels: np.ndarray, n: int,
 @telemetry.timed("ops::BuildPersistPayload(pack)", category="ops",
                  always=True)
 def build_assets(dataset, labels: np.ndarray, C: int = 0,
-                 CR: int = 16384, num_shards: int = 1,
+                 CR: int = 0, num_shards: int = 1,
                  num_scores: int = 1,
                  use_weight_row: bool = True,
                  score64: bool = False) -> PersistAssets:
@@ -330,8 +354,15 @@ def build_assets(dataset, labels: np.ndarray, C: int = 0,
     weight = dataset.metadata.weight if use_weight_row else None
     weight = None if weight is None else np.asarray(weight)
     has_w = weight is not None
-    WPA, C, NP = _payload_geometry(n, nbw, C, CR, num_scores, has_w,
-                                   score64)
+    WPA, C, CR, NP = _payload_geometry(
+        n, nbw, G, C, CR, num_scores, has_w, score64,
+        loop_groups=hist_loops_groups(G, plan))
+    # run record: the newest payload's geometry (set, not summed)
+    telemetry.clear_counts_prefix(
+        ("ops::payload_words", "ops::chunk_lanes", "ops::root_chunk_lanes"))
+    telemetry.count("ops::payload_words", WPA, category="ops")
+    telemetry.count("ops::chunk_lanes", C, category="ops")
+    telemetry.count("ops::root_chunk_lanes", CR, category="ops")
     K = num_scores
     weight_row = payload_weight_row(nbw, K, score64)
     blocks = []
@@ -2027,6 +2058,9 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
     # counts trees by them: blockscan_trees, inpass_hist_trees)
     gr.block_scan = bool(bundled and not wide)
     gr.inpass_hist = seg_hist is None
+    # past 56 payload words the chunk follows from the row's width
+    # (_payload_geometry): wide_payload_trees
+    gr.wide_payload = WPA > 56
     gr.use_level = use_level
     gr.S_MAXL = S_MAXL
     gr.health = health

@@ -121,7 +121,10 @@ def _ceil8(x: int) -> int:
 # are sized to each kernel's actual footprint (buffers + Mosaic
 # temporaries scale with E) and C grows instead.
 
-def split_pass_vmem_bytes(WPA: int, E: int, G: int) -> int:
+VMEM_CAP = 96 << 20
+
+
+def split_pass_vmem_bytes(WPA: int, E: int, G: int, cap=VMEM_CAP) -> int:
     """split_pass / level_pass: 2 chunk-sized u32 buffers (the DMA's
     landing buffer and the re-aligned chunk's tile-addressable home), 4
     FIFO slots one lane tile wider, the two open tiles the drain carries
@@ -130,23 +133,37 @@ def split_pass_vmem_bytes(WPA: int, E: int, G: int) -> int:
     rolls around it (the compiler's stack reads 44.1 MB at WPA 40,
     E 16512). The partition adds two [E / 128, 128] control planes and
     otherwise works tile by tile in registers; the drain holds nothing:
-    it DMAs whole tiles from the slots and reads no payload back."""
-    return int(min(96 << 20,
-                   2 * WPA * E * 4 + 4 * WPA * (E + 128) * 4
-                   + 2 * WPA * 128 * 4
-                   + G * 16 * 64 * 4 + (20 << 20)
-                   + 3 * WPA * E * 4 + 2 * _ceil8(E // 128) * 128 * 4))
+    it DMAs whole tiles from the slots and reads no payload back.
+
+    ``cap=None`` gives the footprint unclipped: what
+    grow_persist._payload_geometry sizes the chunk by, so that no kernel
+    runs with a request the cap has cut."""
+    need = (2 * WPA * E * 4 + 4 * WPA * (E + 128) * 4
+            + 2 * WPA * 128 * 4
+            + G * 16 * 64 * 4 + (20 << 20)
+            + 3 * WPA * E * 4 + 2 * _ceil8(E // 128) * 128 * 4)
+    return int(need if cap is None else min(cap, need))
 
 
-def seg_hist_vmem_bytes(WPA: int, E: int, G: int) -> int:
+def seg_hist_vmem_bytes(WPA: int, E: int, G: int, looped: bool = False,
+                        cap=VMEM_CAP) -> int:
     """seg_hist / level_seg_hist / root_hist: one streaming chunk buffer
-    (+1 working copy) + the radix hist accumulator + the [G, E] decoded
-    group-bin planes and one-hot rhs `_hist_accum` materializes per
-    chunk. The decode terms count: at G=700, E=8320 (a 700-group
-    unbundled shape) they are 24MB."""
-    return int(min(96 << 20,
-                   2 * WPA * E * 4 + G * 16 * 64 * 4
-                   + G * E * 4 + 64 * E * 2 + (20 << 20)))
+    (+1 working copy) + the radix hist accumulator + what the group loop
+    holds at once. Unrolled, that is the [G, E] decoded group-bin planes
+    `_hist_accum` is handed and its one-hot rhs: at G=700, E=8320 (a
+    700-group unbundled shape) they are 24MB. ``looped``
+    (hist_loops_groups) the kernels decode one sublane tile of word rows
+    at a time (_hist_accum_words) and the [G, E] plane never exists, but
+    the accumulator is counted as VMEM holds it: [16, 64] tiles padded to
+    128 lanes, and twice, for the output window's two buffers (16.4 MB
+    each at 2,000 groups). ``cap`` as in split_pass_vmem_bytes."""
+    if looped:
+        need = (2 * WPA * E * 4 + 2 * G * 16 * 128 * 4
+                + 8 * E * 4 + 64 * E * 2 + (20 << 20))
+    else:
+        need = (2 * WPA * E * 4 + G * 16 * 64 * 4
+                + G * E * 4 + 64 * E * 2 + (20 << 20))
+    return int(need if cap is None else min(cap, need))
 
 
 def grow_input_contract(NP: int, w: int = 256) -> dict:
@@ -330,6 +347,32 @@ def _unpack_group_bins(pay_block, plan):
     return jnp.stack(rows, axis=0)
 
 
+def _hist_values(grad, hess):
+    """The four bf16 value rows of the radix contraction: (grad_hi,
+    hess_hi, grad_lo, hess_lo), hi + lo exact to f32."""
+    g_hi = grad.astype(jnp.bfloat16)
+    h_hi = hess.astype(jnp.bfloat16)
+    g_lo = (grad - g_hi.astype(F32)).astype(jnp.bfloat16)
+    h_lo = (hess - h_hi.astype(F32)).astype(jnp.bfloat16)
+    return (g_hi, h_hi, g_lo, h_lo)
+
+
+def _hist_group(hist_ref, g, b, n16, vt):
+    """hist_ref[g] += one group's contraction; b: [E] i32 group-local
+    bins, g static or traced."""
+    oh_hi = (n16 == (b >> 4)[None, :]).astype(jnp.bfloat16)   # [16, E]
+    oh_lo = (n16 == (b & 15)[None, :]).astype(jnp.bfloat16)
+    # 64-sublane one-hots can't be built directly (i1 relayout at 64
+    # rows breaks Mosaic); concatenating four known-good [16, E]
+    # scaled one-hots gives the same [64, E] rhs
+    bv = jnp.concatenate([oh_lo * v[None, :] for v in vt], axis=0)
+    # lanes [0, 64) only: the level kernels' accumulators carry
+    # HIST_LANES_PAD lanes (see below), the others exactly 64
+    hist_ref[g, :, 0:64] = hist_ref[g, :, 0:64] + jax.lax.dot_general(
+        oh_hi, bv, (((1,), (1,)), ((), ())),
+        preferred_element_type=F32)                           # [16, 64]
+
+
 def _hist_accum(hist_ref, bins_g, grad, hess, G: int):
     """hist_ref[g] += radix-16 one-hot MXU contraction of one chunk.
 
@@ -344,24 +387,98 @@ def _hist_accum(hist_ref, bins_g, grad, hess, G: int):
     """
     E = bins_g.shape[1]
     n16 = jax.lax.broadcasted_iota(I32, (16, E), 0)
-    g_hi = grad.astype(jnp.bfloat16)
-    h_hi = hess.astype(jnp.bfloat16)
-    g_lo = (grad - g_hi.astype(F32)).astype(jnp.bfloat16)
-    h_lo = (hess - h_hi.astype(F32)).astype(jnp.bfloat16)
-    vt = (g_hi, h_hi, g_lo, h_lo)
-    dn = (((1,), (1,)), ((), ()))
+    vt = _hist_values(grad, hess)
     for g in range(G):
-        b = bins_g[g, :]
-        oh_hi = (n16 == (b >> 4)[None, :]).astype(jnp.bfloat16)   # [16, E]
-        oh_lo = (n16 == (b & 15)[None, :]).astype(jnp.bfloat16)
-        # 64-sublane one-hots can't be built directly (i1 relayout at 64
-        # rows breaks Mosaic); concatenating four known-good [16, E]
-        # scaled one-hots gives the same [64, E] rhs
-        bv = jnp.concatenate([oh_lo * v[None, :] for v in vt], axis=0)
-        # lanes [0, 64) only: the level kernels' accumulators carry
-        # HIST_LANES_PAD lanes (see below), the others exactly 64
-        hist_ref[g, :, 0:64] = hist_ref[g, :, 0:64] + jax.lax.dot_general(
-            oh_hi, bv, dn, preferred_element_type=F32)            # [16, 64]
+        _hist_group(hist_ref, g, bins_g[g, :], n16, vt)
+
+
+# Up to this many groups the histogram kernels unroll their group loop
+# (_hist_accum over a [G, E] decoded plane); past it, on a payload of byte
+# slots in group order, they loop over sublane tiles of word rows
+# (_hist_accum_words), so kernel size and compile time stop growing with
+# G. Chip-less compile readings for the v5e (CHANGES.md, PR 36): unrolled,
+# seg_hist takes 72-75 s at 137 groups and 283 s at 2,000 groups with a
+# chunk of 2,048 lanes; looped, 13 s and 4 s (11.9k bundles: the 32
+# groups of one tile at that chunk, whatever G). The benchmark's narrow
+# cells (16 and 28 groups) stay unrolled, program for program; two tiles'
+# worth of groups is where the loop starts to have iterations to save.
+HIST_UNROLL_MAX_GROUPS = 64
+
+
+def hist_loops_groups(G: int, plan, forced=None) -> bool:
+    """Do the histogram kernels loop over word rows for this payload?
+    They do past HIST_UNROLL_MAX_GROUPS when every group has a byte slot
+    and the slots are in group order (what _payload_plan gives when no
+    group fits a nibble): group g is byte g % 4 of word row g // 4.
+    ``forced``: the kernels' ``_loop_groups``, by which a test takes
+    either loop to hold the two bit-equal."""
+    if forced is not None:
+        return bool(forced)
+    return G > HIST_UNROLL_MAX_GROUPS and all(
+        tuple(p) == (g // 4, (g % 4) * 8, 255) for g, p in enumerate(plan))
+
+
+def _hist_accum_words(hist_ref, wbuf, back, grad, hess, G: int):
+    """_hist_accum with the group loop as a loop, for a payload of byte
+    slots in group order (hist_loops_groups): a sublane tile of eight
+    word rows an iteration, its 32 groups unrolled; the tile that holds
+    the last bin words (and the label and row id beside them) is static.
+
+    wbuf: VMEM ref [WPA, E] u32, the chunk as DMAed; ``back``: the lane
+    rotation that brings its rows to lane 0 (None where it is aligned
+    already). A tile is rotated as the unrolled kernels rotate the whole
+    chunk, so each group's operands, and hence its sums, are theirs bit
+    for bit.
+    """
+    E = wbuf.shape[1]
+    n16 = jax.lax.broadcasted_iota(I32, (16, E), 0)
+    vt = _hist_values(grad, hess)
+
+    def tile(r0, g0, groups):
+        x = wbuf[pl.ds(r0, 8), :]
+        if back is not None:
+            x = pltpu.roll(x, back, 1)
+        for k in range(groups):
+            b = ((x[k // 4, :] >> U32((k % 4) * 8)) & U32(255)).astype(I32)
+            _hist_group(hist_ref, g0 + k, b, n16, vt)
+
+    def full(i, c):
+        tile(pl.multiple_of(i * 8, 8), i * 32, 32)
+        return c
+
+    jax.lax.fori_loop(0, G // 32, full, 0)
+    if G % 32:
+        tile(G // 32 * 8, G // 32 * 32, G % 32)
+
+
+def _chunk_hist(hist_ref, wbuf, d, m, plan, nbw: int, G: int,
+                looped: bool):
+    """hist_ref += histogram of the chunk in ``wbuf``, whose rows lie in
+    lanes [d, d + m()) (d None: 0, no rotation needed). Returns the
+    chunk's masked (grad, hess) rows. The one body seg_hist,
+    level_seg_hist and root_hist share; ``m`` is a thunk so that the
+    unrolled kernels trace op for op as they did before they shared it.
+    """
+    E = wbuf.shape[1]
+    grad_row = nbw + 2
+    if looped:
+        w = wbuf[grad_row:grad_row + 2, :]
+        grad_row = 0
+    else:
+        w = wbuf[...]
+    back = None
+    if d is not None:
+        back = jax.lax.sub(jnp.int32(E), d)
+        w = pltpu.roll(w, back, 1)   # chunk rows at lanes 0..m
+    lane = _lane_iota(E)[0]
+    valid = (lane < m()).astype(F32)
+    grad = _f32r(w[grad_row, :]) * valid
+    hess = _f32r(w[grad_row + 1, :]) * valid
+    if looped:
+        _hist_accum_words(hist_ref, wbuf, back, grad, hess, G)
+    else:
+        _hist_accum(hist_ref, _unpack_group_bins(w, plan), grad, hess, G)
+    return grad, hess
 
 
 # lanes one block of _slot_hist histograms at a time: a block is the least
@@ -907,7 +1024,7 @@ def make_level_seg_hist(WPA: int, NP: int, G: int, plan, nbw: int,
     """
     assert WPA % 8 == 0
     E = C + 128
-    grad_row = nbw + 2
+    looped = hist_loops_groups(G, plan)
 
     def kernel(sm, so, bo, pay_hbm, hist_out, hacc, wbuf, sem_r, sem_h):
         i = pl.program_id(0)
@@ -926,13 +1043,7 @@ def make_level_seg_hist(WPA: int, NP: int, G: int, plan, nbw: int,
         cp.start()
         cp.wait()
         d = ptr - al
-        w = pltpu.roll(wbuf[...], jax.lax.sub(jnp.int32(E), d), 1)
-        lane = _lane_iota(E)[0]
-        valid = (lane < m).astype(F32)
-        grad = _f32r(w[grad_row, :]) * valid
-        hess = _f32r(w[grad_row + 1, :]) * valid
-        bins_g = _unpack_group_bins(w, plan)
-        _hist_accum(hacc, bins_g, grad, hess, G)
+        _chunk_hist(hacc, wbuf, d, lambda: m, plan, nbw, G, looped)
 
         @pl.when(lo == sm[j, 0] - 1)
         def _fin():
@@ -941,7 +1052,7 @@ def make_level_seg_hist(WPA: int, NP: int, G: int, plan, nbw: int,
             cph.wait()
 
     _cparams = CompilerParams(
-        vmem_limit_bytes=seg_hist_vmem_bytes(WPA, E, G))
+        vmem_limit_bytes=seg_hist_vmem_bytes(WPA, E, G, looped))
 
     @jax.jit
     def level_seg_hist(pay, scal_mat, slot_of_step, base_of_slot, grid):
@@ -975,7 +1086,8 @@ def make_level_seg_hist(WPA: int, NP: int, G: int, plan, nbw: int,
 # ---------------------------------------------------------------------------
 
 def make_seg_hist(WPA: int, NP: int, G: int, plan, nbw: int,
-                  C: int = 16384, interpret: bool = False):
+                  C: int = 16384, interpret: bool = False,
+                  _loop_groups=None):
     """Histogram of one contiguous payload segment (dynamic start/length).
 
     Runs AFTER split_pass has partitioned a leaf: the smaller child's rows
@@ -989,10 +1101,12 @@ def make_seg_hist(WPA: int, NP: int, G: int, plan, nbw: int,
 
     Returns fn(pay, start, length) -> (gh [G*256], hh [G*256]) f32; outputs
     are UNDEFINED when length == 0 (zero grid steps) — callers mask.
+
+    _loop_groups: hist_loops_groups' ``forced``.
     """
     assert WPA % 8 == 0
     E = C + 128
-    grad_row = nbw + 2
+    looped = hist_loops_groups(G, plan, _loop_groups)
 
     def kernel(ns, pay_hbm, hist_ref, wbuf, sem_r):
         i = pl.program_id(0)
@@ -1009,16 +1123,10 @@ def make_seg_hist(WPA: int, NP: int, G: int, plan, nbw: int,
         cp.start()
         cp.wait()
         d = ptr - al
-        w = pltpu.roll(wbuf[...], jax.lax.sub(jnp.int32(E), d), 1)
-        lane = _lane_iota(E)[0]
-        valid = (lane < m).astype(F32)
-        grad = _f32r(w[grad_row, :]) * valid
-        hess = _f32r(w[grad_row + 1, :]) * valid
-        bins_g = _unpack_group_bins(w, plan)
-        _hist_accum(hist_ref, bins_g, grad, hess, G)
+        _chunk_hist(hist_ref, wbuf, d, lambda: m, plan, nbw, G, looped)
 
     _cparams = CompilerParams(
-        vmem_limit_bytes=seg_hist_vmem_bytes(WPA, E, G))
+        vmem_limit_bytes=seg_hist_vmem_bytes(WPA, E, G, looped))
 
     @jax.jit
     def seg_hist(pay, start, length):
@@ -1055,14 +1163,15 @@ def make_seg_hist(WPA: int, NP: int, G: int, plan, nbw: int,
 # ---------------------------------------------------------------------------
 
 def make_root_hist(WPA: int, NP: int, G: int, plan, nbw: int, n: int,
-                   C: int = 16384, interpret: bool = False):
+                   C: int = 16384, interpret: bool = False,
+                   _loop_groups=None):
     """One streaming pass: padded root histogram + grad/hess totals.
 
     Returns fn(pay) -> (hist [G*256, 2] f32, sums [2] f32).
     Totals are f32 chunk-partial sums (deterministic order).
     """
     assert WPA % 8 == 0
-    grad_row = nbw + 2
+    looped = hist_loops_groups(G, plan, _loop_groups)
     nch = (n + C - 1) // C
     assert NP >= nch * C, "payload lanes must cover whole root chunks"
 
@@ -1079,13 +1188,8 @@ def make_root_hist(WPA: int, NP: int, G: int, plan, nbw: int, n: int,
             pay_hbm.at[:, pl.ds(i * C, C)], wbuf, sem_r)
         cp.start()
         cp.wait()
-        w = wbuf[...]
-        lane = jax.lax.broadcasted_iota(I32, (1, C), 1)[0]
-        valid = (lane < (n - i * C)).astype(F32)
-        grad = _f32r(w[grad_row, :]) * valid
-        hess = _f32r(w[grad_row + 1, :]) * valid
-        bins_g = _unpack_group_bins(w, plan)
-        _hist_accum(hist_ref, bins_g, grad, hess, G)
+        grad, hess = _chunk_hist(hist_ref, wbuf, None, lambda: n - i * C,
+                                 plan, nbw, G, looped)
         acc[0] = acc[0] + jnp.sum(grad)
         acc[1] = acc[1] + jnp.sum(hess)
 
@@ -1103,7 +1207,7 @@ def make_root_hist(WPA: int, NP: int, G: int, plan, nbw: int, n: int,
     # the streaming chunk buffer alone (WPA*C u32) outgrows the 16MB
     # Mosaic default on wide unbundled payloads (~180 words at C=16384)
     _cparams = CompilerParams(
-        vmem_limit_bytes=seg_hist_vmem_bytes(WPA, C, G))
+        vmem_limit_bytes=seg_hist_vmem_bytes(WPA, C, G, looped))
 
     def _call(pay):
         return pl.pallas_call(

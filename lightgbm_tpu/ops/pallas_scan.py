@@ -49,11 +49,15 @@ def scan_pair_vmem_bytes(Fp: int, Wp: int) -> int:
     """Scoped-vmem limit :func:`scan_pair` requests at padded geometry
     (Fp, Wp): ~12 staged [Fp, Wp] f32 blocks + the cumsum stack + Mosaic
     temporaries. The kernel runs with this number; the described-topology
-    compiles of tests/test_chip_compile.py prove it (28 and 137
-    features). The default
+    compiles of tests/test_chip_compile.py prove it (28, 137 and 2,000
+    features). Past some 290k lanes the blocks outgrow the fixed 20 MB:
+    at Fp 2000, Wp 256 (the persist grower's padded group planes of 2,000
+    features) the compiler's stack is 60.7 MB, 31 blocks, where 16 blocks
+    + 20 MB are 51; 34 blocks cover it. The default
     scoped-vmem budget OOMs past ~450 features at Wp=256 (v5e carries
     128MB of VMEM, so size the limit to the footprint)."""
-    return int(min(100 << 20, 16 * Fp * Wp * 4 + (20 << 20)))
+    block = Fp * Wp * 4
+    return int(min(100 << 20, max(16 * block + (20 << 20), 34 * block)))
 
 
 def scan_blocks_vmem_bytes(Gp: int, Wp: int) -> int:

@@ -143,9 +143,13 @@ def resolve_scan_impl(config: Config, gc_kwargs: dict) -> str:
     impl = str(config.tpu_scan_impl).lower()
     if impl == "xla":
         return "xla"
-    # the fused kernel stages ~12 [Fp, Wp] f32 blocks in VMEM at once;
-    # wide-feature datasets (Fp*Wp beyond ~256k lanes ~= 12MB) overflow
-    # the 16MB scoped-vmem budget and must use the XLA scan
+    # the fused kernel stages ~12 [Fp, Wp] f32 blocks in VMEM at once
+    # and runs under its own scoped-VMEM request (pallas_scan.
+    # scan_pair_vmem_bytes: 36 MB at 2,000 features x 128 lanes, and 70 MB
+    # where the persist grower hands it the same features as padded
+    # [F, 256] group planes: the widest shape compiled for the chip,
+    # tests/test_chip_compile.py); wider feature planes (Fp*Wp beyond
+    # 256k lanes) use the XLA scan
     Fp = -(-max(gc_kwargs["num_features"], 8) // 8) * 8
     Wp = -(-max(gc_kwargs["scan_width"], 128) // 128) * 128
     vmem_ok = Fp * Wp <= 256 * 1024
@@ -645,7 +649,8 @@ class SerialTreeLearner:
     def _count_persist_trees(gr, k: int):
         """Run record: k trees on the persist path, and by which of the
         grower's mechanisms (split scan over the bundled group planes;
-        smaller-child histogram built inside split_pass)."""
+        smaller-child histogram built inside split_pass; a payload row
+        wide enough for its width to size the kernels' chunks)."""
         telemetry.count("tree_learner::persist_scan_trees", float(k),
                         category="tree_learner")
         if gr.block_scan:
@@ -653,6 +658,9 @@ class SerialTreeLearner:
                             category="tree_learner")
         if gr.inpass_hist:
             telemetry.count("tree_learner::inpass_hist_trees", float(k),
+                            category="tree_learner")
+        if gr.wide_payload:
+            telemetry.count("tree_learner::wide_payload_trees", float(k),
                             category="tree_learner")
 
     @staticmethod

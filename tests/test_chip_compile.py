@@ -13,7 +13,13 @@ slot) for the seg_hist it runs after each pass and for the split_pass
 that builds the histogram inside the pass, which no grower takes since
 PR 35, the MS-LTR payload (137 features:
 40 live rows, whole sublane tiles, so split_pass has no spare sublane; its
-histogram kernels and its pair scan at 137 groups), and the HIGGS rows under
+histogram kernels, which loop over word rows past 64 groups, and its pair
+scan at 137 groups), the Epsilon payload (benchmark/configs/epsilon.json:
+400,000 x 2,000 dense columns at 63 bins — a 2 KB row of 505 live words, the
+chunks `_payload_geometry` sizes from that width (C 2048, CR 8192),
+split_pass at 64 sublane tiles, seg_hist / root_hist over 2,000 groups,
+scan_pair over [2000, 256] planes and the fused k=16 driver with its
+1.04 GB of per-leaf planes), and the HIGGS rows under
 the other static shapes every persist configuration can take: a weight row
 (13 live rows), three classes (17 live rows in 24), ``max_bin=15`` (every
 group a nibble, 9 live rows in 16) — plus the whole fused k=16 scan driver.
@@ -47,8 +53,9 @@ from lightgbm_tpu.data.synth import (make_expo_like, make_higgs_like,
                                       make_ltr_like)
 from lightgbm_tpu.objectives import create_objective
 from lightgbm_tpu.ops import grow_persist as gp
-from lightgbm_tpu.ops.pallas_grow import (N_SCALARS, make_level_pass,
-                                          make_seg_hist, make_split_pass)
+from lightgbm_tpu.ops.pallas_grow import (N_SCALARS, hist_loops_groups,
+                                          make_level_pass, make_seg_hist,
+                                          make_split_pass)
 from lightgbm_tpu.ops.pallas_histogram import hist_window
 from lightgbm_tpu.ops.pallas_scan import (ScanLayout, build_block_scan_meta,
                                           scan_blocks, scan_pair)
@@ -58,6 +65,8 @@ HIGGS_ROWS = 10_500_000     # docs/Experiments.rst: HIGGS
 EXPO_ROWS = 11_000_000      # docs/Experiments.rst: Expo
 MSLTR_ROWS = 2_270_296      # docs/Experiments.rst: MS LTR
 EXPO_CELL_ROWS = 16_500_000  # benchmark/configs/expo.json: 1.5 x Expo
+EPSILON_ROWS = 400_000      # docs/GPU-Performance.rst: Epsilon
+EPSILON_FEATURES = 2_000
 LEVEL_DEPTH = 8             # max_depth; with num_leaves = 2^8 the level
 #                             phase engages
 SAMPLE_ROWS = 20_000        # rows actually binned: the bin structure only
@@ -100,11 +109,12 @@ class _Built:
         K = self.objective.num_model_per_iteration
         small = gp.build_assets(self.ds, self.ds.metadata.label,
                                 num_scores=K)
-        G, plan, nbw, CR, has_w = (small.geometry[2], small.geometry[3],
-                                   small.geometry[4], small.geometry[7],
-                                   small.geometry[9])
+        G, plan, nbw, has_w = (small.geometry[2], small.geometry[3],
+                               small.geometry[4], small.geometry[9])
         assert has_w == (weight is not None)
-        WPA, C, NP = gp._payload_geometry(rows, nbw, 0, CR, K, has_w)
+        WPA, C, CR, NP = gp._payload_geometry(
+            rows, nbw, G, 0, 0, K, has_w,
+            loop_groups=hist_loops_groups(G, plan))
         self.assets = small._replace(
             pay0=None,
             geometry=(WPA, NP, G, plan, nbw, rows, C, CR, K, has_w, False))
@@ -165,6 +175,23 @@ def higgs_3class():
 def higgs_15bins():
     X, y = make_higgs_like(SAMPLE_ROWS)
     return _Built(X, y, HIGGS_ROWS, (-1,), max_bin=15)
+
+
+@pytest.fixture(scope="module")
+def epsilon():
+    """benchmark/configs/epsilon.json: 2,000 dense columns at 63 bins."""
+    rng = np.random.default_rng(36)
+    X = rng.normal(size=(SAMPLE_ROWS, EPSILON_FEATURES)).astype(np.float32)
+    y = (X[:, :8].sum(axis=1) > 0).astype(np.float64)
+    built = _Built(X, y, EPSILON_ROWS, (-1,), max_bin=63,
+                   enable_bundle=False)
+    # a 2 KB payload row: 500 bin words, 505 live rows; the chunks follow
+    # from the width (gp._payload_geometry) and the histogram kernels loop
+    # over word rows
+    G, plan, C, CR = (built.assets.geometry[i] for i in (2, 3, 6, 7))
+    assert (built.wp_live, built.pay[0], G) == (505, 512, EPSILON_FEATURES)
+    assert (C, CR) == (2048, 8192) and hist_loops_groups(G, plan)
+    return built
 
 
 def _hist_window(higgs, expo, S):
@@ -240,9 +267,9 @@ def _expo_cell():
     widths = [256, 256, 8, 13, 23, 32, 128, 128,
               28, 32, 36, 40, 44, 48, 52, 56]
     plan, nbw = gp._payload_plan(widths)
-    WPA, C, NP = gp._payload_geometry(EXPO_CELL_ROWS, nbw, 0, 16384)
+    WPA, C, CR, NP = gp._payload_geometry(EXPO_CELL_ROWS, nbw, len(widths))
     wp_live = gp.payload_weight_row(nbw, 1)
-    assert (WPA, C, nbw, wp_live) == (16, 16384, 4, 9)
+    assert (WPA, C, CR, nbw, wp_live) == (16, 16384, 16384, 4, 9)
     assert any(mk == 15 for _, _, mk in plan)
     return WPA, NP, len(widths), plan, nbw, C, wp_live
 
@@ -289,8 +316,8 @@ def _split_pass_15bins(higgs, expo, S, higgs_15bins):
 
 
 def _seg_hist_msltr(higgs, expo, S, msltr):
-    """137 groups: the [G, E] decode planes and the one-hot operand are
-    most of the request (seg_hist_vmem_bytes)."""
+    """137 groups, past HIST_UNROLL_MAX_GROUPS: four whole sublane tiles
+    of word rows in the loop, the last nine groups in the static tail."""
     assert msltr.pay[0] == 40 and len(msltr.ds.groups) == 137
     return msltr.growers[-1]._seg_hist, (
         S(msltr.pay, jnp.uint32), S((), jnp.int32), S((), jnp.int32))
@@ -307,6 +334,33 @@ def _seg_hist(higgs, expo, S):
 
 def _root_hist(higgs, expo, S):
     return higgs.growers[-1]._root_hist, (S(higgs.pay, jnp.uint32),)
+
+
+def _split_pass_epsilon(higgs, expo, S, epsilon):
+    """505 live payload rows, 64 sublane tiles: the partition's tiles,
+    the FIFO slots [4, 512, 2304] and the carry [2, 512, 128]."""
+    return _split_pass_of(epsilon, S, 505, 512)
+
+
+def _seg_hist_epsilon(higgs, expo, S, epsilon):
+    """2,000 groups: the group loop is a loop over word rows
+    (_hist_accum_words), 32 groups an iteration."""
+    return epsilon.growers[-1]._seg_hist, (
+        S(epsilon.pay, jnp.uint32), S((), jnp.int32), S((), jnp.int32))
+
+
+def _root_hist_epsilon(higgs, expo, S, epsilon):
+    return epsilon.growers[-1]._root_hist, (S(epsilon.pay, jnp.uint32),)
+
+
+def _scan_pair_epsilon(higgs, expo, S, epsilon):
+    """Fp 2000: resolve_scan_impl admits the fused scan by the bins a
+    feature has (2,000 x 128 = 256,000 lanes of 262,144), and the persist
+    grower hands it the padded [F, 256] group planes: Wp 256, 70 MB by
+    scan_pair_vmem_bytes."""
+    fn, args = _scan_pair_of(epsilon, S)
+    assert args[1].shape == (2, 2000, 256)
+    return fn, args
 
 
 def _level_args(built, gr, S):
@@ -340,20 +394,30 @@ def _level_seg_hist(higgs, expo, S):
     return gr._level_seg, _level_args(higgs, gr, S)
 
 
-def _fused_driver(higgs, expo, S):
-    """make_scan_driver's whole k=16 program: the kernels above plus the
-    XLA around them, at the size chip_smoke.py trains."""
-    k, F = 16, higgs.ds.num_features
-    learner, gr = higgs.learners[-1], higgs.growers[-1]
-    mode, grad_fn = higgs.objective.device_gradients()
+def _fused_driver_of(built, S):
+    k, F = 16, built.ds.num_features
+    learner, gr = built.learners[-1], built.growers[-1]
+    mode, grad_fn = built.objective.device_gradients()
     run = gp.make_scan_driver(gr, learner.grow_config, k, grad_fn,
                               grad_mode=mode, wrap_jit=False)
     like = lambda tree: jax.tree.map(  # noqa: E731
         lambda a: S(np.shape(a), jnp.asarray(a).dtype), tree)
-    return run, (S(higgs.pay, jnp.uint32), S((k, F), jnp.bool_),
+    return run, (S(built.pay, jnp.uint32), S((k, F), jnp.bool_),
                  S((k, 2), jnp.uint32), S((k,), jnp.int32),
                  like(learner.params), S((), jnp.float64),
-                 like(higgs.objective.persist_grad_args()))
+                 like(built.objective.persist_grad_args()))
+
+
+def _fused_driver(higgs, expo, S):
+    """make_scan_driver's whole k=16 program: the kernels above plus the
+    XLA around them, at the size chip_smoke.py trains."""
+    return _fused_driver_of(higgs, S)
+
+
+def _fused_driver_epsilon(higgs, expo, S, epsilon):
+    """The same program at Epsilon's geometry: the per-leaf planes are
+    [255, 2000 x 256] f32 twice over."""
+    return _fused_driver_of(epsilon, S)
 
 
 @pytest.mark.parametrize("case", [
@@ -361,7 +425,9 @@ def _fused_driver(higgs, expo, S):
     _split_pass_msltr, _split_pass_expo, _split_pass_weighted,
     _split_pass_3class, _split_pass_15bins, _level_pass, _level_pass_inpass,
     _level_seg_hist, _seg_hist, _seg_hist_msltr, _seg_hist_expo,
-    _root_hist, _root_hist_msltr, _fused_driver,
+    _root_hist, _root_hist_msltr, _fused_driver, _split_pass_epsilon,
+    _seg_hist_epsilon, _root_hist_epsilon, _scan_pair_epsilon,
+    _fused_driver_epsilon,
 ], ids=lambda f: f.__name__.lstrip("_"))
 def test_compiles_for_v5e(case, one_chip, higgs, expo, request):
     def S(shape, dtype):

@@ -1,0 +1,288 @@
+"""Wide dense rows on the persist path: the chunk geometry that follows
+from the row's width, the histogram kernels whose group loop is a loop,
+the partition at 135 live word rows, and a forced-persist train of 520
+dense columns at 63 bins held against the benchmark's plain reference and
+the v1 grower. Small and seeded: the Epsilon configuration
+(benchmark/configs/epsilon.json: 2,000 columns, a 2 KB payload row) is
+compiled for the chip by tests/test_chip_compile.py and run on it by the
+benchmark; here its mechanisms run in interpret mode and through the XLA
+emulation at a width past the loop threshold.
+"""
+import json
+import os
+import sys
+
+import numpy as np
+import pytest
+
+import jax.numpy as jnp
+
+import lightgbm_tpu as lgb
+from lightgbm_tpu.ops import grow_persist as gp
+from lightgbm_tpu.ops import pallas_grow as pg
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+ROWS = 400_000               # Epsilon's, for the geometry cases
+# (bin words, groups) -> today's (WPA, C, CR): HIGGS, the Expo cell,
+# MS-LTR, a 700-group unbundled shape; and Epsilon, whose answer is held by
+# the footprints
+GEOMETRIES = [((7, 28), (16, 16384, 16384)), ((4, 16), (16, 16384, 16384)),
+              ((35, 137), (40, 16384, 16384)),
+              ((175, 700), (184, 8192, 16384)), ((500, 2000), None)]
+
+
+@pytest.mark.parametrize("shape,held", GEOMETRIES,
+                         ids=lambda v: "x".join(map(str, v))
+                         if isinstance(v, tuple) and len(v) == 2 else None)
+def test_chunk_geometry_follows_the_width(shape, held):
+    nbw, G = shape
+    WPA, C, CR, NP = gp._payload_geometry(ROWS, nbw, G)
+    looped = G > pg.HIST_UNROLL_MAX_GROUPS
+    need = (pg.split_pass_vmem_bytes(WPA, C + 128, G, cap=None),
+            pg.seg_hist_vmem_bytes(WPA, C + 128, G, looped, cap=None),
+            pg.seg_hist_vmem_bytes(WPA, CR, G, looped, cap=None))
+    # no kernel runs with a request the cap has cut
+    assert max(need) < pg.VMEM_CAP, need
+    assert need == (pg.split_pass_vmem_bytes(WPA, C + 128, G),
+                    pg.seg_hist_vmem_bytes(WPA, C + 128, G, looped),
+                    pg.seg_hist_vmem_bytes(WPA, CR, G, looped))
+    assert NP >= -(-ROWS // CR) * CR and NP >= ROWS + C + 256
+    if held is not None:
+        assert (WPA, C, CR) == held
+    else:
+        assert WPA == 512 and 1024 <= C < 8192 and 1024 <= CR <= 16384
+        # the next size up does not fit: the chunk is the largest that does
+        assert pg.split_pass_vmem_bytes(WPA, 2 * C + 128, G,
+                                        cap=None) >= pg.VMEM_CAP
+
+
+# -- kernels at a wide small geometry ----------------------------------------
+
+N, F, BINS = 2048, 520, 63
+CH = 512                     # chunk lanes: quick to interpret
+PLAN, NBW = gp._payload_plan([BINS + 1] * F)
+WPA, _, _, _ = gp._payload_geometry(N, NBW, F)
+NPAD = 4096
+GRAD = NBW + 2
+LIVE = gp.payload_weight_row(NBW, 1)
+
+
+def test_the_wide_geometry_is_past_the_loop_threshold():
+    assert (WPA, NBW, LIVE) == (136, 130, 135)
+    assert pg.hist_loops_groups(F, PLAN)
+    assert not pg.hist_loops_groups(28, gp._payload_plan([256] * 28)[0])
+    # a nibble slot among many groups: the unrolled kernels keep it
+    assert not pg.hist_loops_groups(
+        F, gp._payload_plan([BINS + 1] * (F - 2) + [8, 8])[0])
+
+
+@pytest.fixture(scope="module")
+def wide():
+    rng = np.random.default_rng(36)
+    bins = rng.integers(0, BINS, (N, F)).astype(np.uint8)
+    pay = gp._pack_payload(bins, rng.integers(0, 2, N), N, WPA, NPAD, NBW,
+                           0, N, plan=PLAN)
+    gh = rng.normal(size=(2, N)).astype(np.float32)
+    pay[GRAD:GRAD + 2, :N] = gh.view(np.uint32)
+    # lanes past the rows hold anything: the kernels mask them
+    pay[:, N:] = rng.integers(0, 2 ** 32, (WPA, NPAD - N), dtype=np.uint32)
+    pay[NBW + 1, N:] = N
+    return bins, gh, pay
+
+
+def _numpy_hist(bins, gh, rows):
+    out = np.zeros((2, F * 256))
+    flat = bins[rows].astype(np.int64) + np.arange(F) * 256
+    for k in range(2):
+        np.add.at(out[k], flat.ravel(),
+                  np.repeat(gh[k, rows].astype(np.float64), F))
+    return out
+
+
+def _close(planes, want):
+    got = np.stack([np.asarray(p, np.float64) for p in planes])
+    # every bin, the empty ones (63..255 of each group) included, to the
+    # rounding of the kernels' bf16 hi + lo split (16 bits a value, some
+    # 30 values a bin here)
+    np.testing.assert_allclose(got, want, rtol=0, atol=3e-4)
+
+
+def test_root_hist_is_numpys_bin_for_bin(wide):
+    bins, gh, pay = wide
+    fn = pg.make_root_hist(WPA, NPAD, F, PLAN, NBW, N, C=CH, interpret=True)
+    planes, sums = fn(jnp.asarray(pay))
+    _close(planes, _numpy_hist(bins, gh, np.arange(N)))
+    np.testing.assert_allclose(np.asarray(sums), gh.sum(axis=1), rtol=1e-4)
+
+
+@pytest.mark.parametrize("start,length", [(0, N), (133, 700), (640, 511),
+                                          (1000, 1)])
+def test_seg_hist_is_numpys_bin_for_bin(wide, start, length):
+    bins, gh, pay = wide
+    fn = pg.make_seg_hist(WPA, NPAD, F, PLAN, NBW, C=CH, interpret=True)
+    planes = fn(jnp.asarray(pay), jnp.int32(start), jnp.int32(length))
+    _close(planes, _numpy_hist(bins, gh, np.arange(start, start + length)))
+
+
+@pytest.mark.parametrize("kernel", ["root_hist", "seg_hist"])
+def test_the_looped_group_decode_is_the_unrolled_one_bit_for_bit(wide,
+                                                                 kernel):
+    _, _, pay = wide
+    out = []
+    for loop in (True, False):
+        if kernel == "root_hist":
+            fn = pg.make_root_hist(WPA, NPAD, F, PLAN, NBW, N, C=CH,
+                                   interpret=True, _loop_groups=loop)
+            planes, sums = fn(jnp.asarray(pay))
+            out.append([np.asarray(p) for p in planes] + [np.asarray(sums)])
+        else:
+            fn = pg.make_seg_hist(WPA, NPAD, F, PLAN, NBW, C=CH,
+                                  interpret=True, _loop_groups=loop)
+            out.append([np.asarray(p) for p in fn(
+                jnp.asarray(pay), jnp.int32(133), jnp.int32(1500))])
+    for a, b in zip(*out):
+        assert a.tobytes() == b.tobytes()
+
+
+@pytest.mark.parametrize("feature,thr", [(0, 30), (519, 5), (261, 61)])
+def test_split_pass_leaves_every_payload_row_intact(wide, feature, thr):
+    """135 live word rows (17 sublane tiles): after the pass each row id
+    of the segment still carries its own 135 words, the left rows first,
+    and nothing outside the segment has moved."""
+    bins, _, pay = wide
+    s0, n = 133, 1700
+    fn = pg.make_split_pass(WPA, NPAD, F, PLAN, NBW, C=CH, interpret=True,
+                            wp_live=LIVE, _skip_hist=True)
+    w, sh, mk = PLAN[feature]
+    v = np.zeros(pg.N_SCALARS, np.int32)
+    v[pg.S_NCH], v[pg.S_S0], v[pg.S_NL] = -(-n // CH), s0, n
+    v[pg.S_WG], v[pg.S_SH], v[pg.S_MASK] = w, sh, mk
+    v[pg.S_NB], v[pg.S_LE], v[pg.S_THR] = BINS + 1, 256, thr
+    out, _, n_left = fn(jnp.asarray(pay), jnp.asarray(v))
+    out = np.asarray(out)
+    left = bins[s0:s0 + n, feature] <= thr
+    assert int(n_left) == left.sum() and 0 < left.sum() < n
+    rid = out[NBW + 1, s0:s0 + n].astype(np.int64)
+    assert sorted(rid) == list(range(s0, s0 + n))
+    np.testing.assert_array_equal(out[:LIVE, s0:s0 + n], pay[:LIVE, rid])
+    assert left[rid - s0][:int(n_left)].all()
+    assert not left[rid - s0][int(n_left):].any()
+    np.testing.assert_array_equal(out[:, :s0], pay[:, :s0])
+    np.testing.assert_array_equal(out[:, s0 + n:], pay[:, s0 + n:])
+
+
+# -- end to end: a forced-persist train held by the plain reference ----------
+
+BENCH = os.path.join(ROOT, "benchmark")
+E2E_ROWS, E2E_BLOCK, TREES, LEAVES = 8192, 4096, 16, 31
+SEED = 3600000077
+
+
+def _bench_modules():
+    if BENCH not in sys.path:
+        sys.path.insert(0, BENCH)
+    from drivers import train
+    from harness import reference
+    return train, reference
+
+
+def _config(**params):
+    with open(os.path.join(BENCH, "configs", "epsilon.json")) as f:
+        cfg = json.load(f)
+    cfg.update(rows=E2E_ROWS, block_rows=E2E_BLOCK, heldout_rows=E2E_BLOCK)
+    cfg["params"].update(num_leaves=LEAVES, min_sum_hessian_in_leaf=1.0,
+                         **params)
+    return cfg
+
+
+@pytest.fixture(scope="module")
+def wide_run():
+    """(cfg, rows, booster, counters) of 16 trees (the fused driver forms a
+    batch at 16 iterations) on 8,192 x 520 dense
+    columns at 63 bins through the persist path (on the CPU: its XLA
+    emulation), rows from the configuration's own generator cut to 520
+    columns."""
+    from lightgbm_tpu import telemetry
+    train, _ = _bench_modules()
+    cfg = _config(tpu_persist_scan="force")
+    with pytest.MonkeyPatch.context() as mp:
+        from generators import epsilon_like
+        mp.setattr(epsilon_like, "FEATURES", F)
+        rows, X, y, _, _, _ = train.inputs(cfg, SEED)
+        assert X.shape == (E2E_ROWS, F) and X.dtype == np.float32
+        before = telemetry.counts_snapshot()
+        bst = lgb.train(dict(cfg["params"]), lgb.Dataset(X, y), TREES,
+                        verbose_eval=False)
+        text = bst.model_to_string(num_iteration=-1)
+        after = telemetry.counts_snapshot()
+        v1 = lgb.train(dict(cfg["params"], tpu_persist_scan="off"),
+                       lgb.Dataset(X, y), TREES, verbose_eval=False)
+        # the reference makes the rows again: inside the patch
+        _, reference = _bench_modules()
+        trees = reference.parse_model(text)
+        init = reference.binary_init(float(np.mean(y, dtype=np.float64)))
+        sums = train.follow(rows, trees, cfg, init)
+        numbers = reference.compare(trees, sums,
+                                    cfg["params"]["learning_rate"], init)[0]
+        control = train.control(rows, trees, cfg, init, sums)[0]
+    grew = {k: v - before.get(k, 0.0) for k, v in after.items()
+            if k.startswith("tree_learner::")}
+    grew.update({k: v for k, v in after.items() if k.startswith("ops::")})
+    return cfg, text, v1.model_to_string(num_iteration=-1), numbers, \
+        control, grew
+
+
+def test_reference_accepts_the_wide_persist_trees(wide_run):
+    cfg, text, _, numbers, _, _ = wide_run
+    assert text.count("Tree=") == TREES
+    assert numbers["count_mismatch"] == 0, numbers
+    for name, limit in cfg["limits"].items():
+        assert numbers[name] <= limit, (name, numbers)
+
+
+def test_the_bfloat16_control_fails_a_limit(wide_run):
+    cfg, _, _, _, control, _ = wide_run
+    assert any(control[name] > limit
+               for name, limit in cfg["limits"].items()), control
+
+
+def test_the_v1_grower_grows_the_same_trees(wide_run):
+    """Model text equal but for the parameter block (which bakes
+    tpu_persist_scan), as tests/test_known_divergence.py pins for narrow
+    data."""
+    _, text, v1_text, _, _, _ = wide_run
+    strip = lambda t: t.split("\nparameters:")[0]  # noqa: E731
+    assert strip(text) == strip(v1_text)
+
+
+def test_counters_say_the_payload_was_wide(wide_run):
+    _, _, _, _, _, grew = wide_run
+    assert grew.get("tree_learner::persist_scan_trees") == TREES, grew
+    assert grew.get("tree_learner::wide_payload_trees") == TREES, grew
+    assert grew.get("tree_learner::v1_grow_trees", 0) == 0, grew
+    assert grew["ops::payload_words"] >= 136, grew
+    assert grew["ops::chunk_lanes"] == 8192, grew
+    assert grew["ops::root_chunk_lanes"] == 16384, grew
+
+
+def test_bin_finding_on_threads_finds_the_same_bins(monkeypatch):
+    """From 64 columns on, BinMapper.find_bin runs on a thread pool
+    (data/dataset.py:_construct_from_sample); a column's mapper hangs on
+    that column alone, so the bins are those of the plain loop."""
+    from lightgbm_tpu.data import dataset as ds_mod
+    rng = np.random.default_rng(5)
+    X = rng.normal(size=(3000, 96))
+    X[:, 7] = np.round(X[:, 7])                  # few distinct values
+    X[rng.random(3000) < 0.1, 11] = np.nan       # a NaN bin
+    y = (X[:, 0] > 0).astype(np.float64)
+    cfg = lgb.Config({"max_bin": 63, "enable_bundle": False})
+    threaded = ds_mod.BinnedDataset.from_matrix(X, cfg, label=y)
+    monkeypatch.setattr(ds_mod, "_FIND_BIN_THREADS_MIN_FEATURES", 10 ** 9)
+    plain = ds_mod.BinnedDataset.from_matrix(X, cfg, label=y)
+    assert len(threaded.bin_mappers) == 96
+    for a, b in zip(threaded.bin_mappers, plain.bin_mappers):
+        np.testing.assert_array_equal(a.bin_upper_bound, b.bin_upper_bound)
+        assert (a.num_bin, a.missing_type, a.default_bin) == (
+            b.num_bin, b.missing_type, b.default_bin)
+    np.testing.assert_array_equal(threaded.binned, plain.binned)
