@@ -959,17 +959,16 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
                 self.masks = blk_masks0.at[BM_VALID_R:BM_VALID_F + 1] \
                                        .multiply(fm_lane[None])
 
-    def eval_batch_wide(gh, hh, rows, sgs, shs, cnts, depths, params,
+    def eval_batch_wide(g2, h2, sgs, shs, cnts, depths, params,
                         fmask, tag):
         """Widened split-find: the v1 f64 scan, batched over leaves.
 
-        gh/hh are flat [L, TBe] f64 planes; rows: [B] i32 leaf-hist row
-        ids; sgs/shs/cnts/depths: [B]. Returns [B, 12] f64 BC matrix.
+        g2/h2: [B, TBe] f64, the leaves' flat histogram rows themselves
+        (the caller holds them; nothing is read back from the [L, TBe]
+        planes); sgs/shs/cnts/depths: [B]. Returns [B, 12] f64 BC matrix.
         Ordering, tie-breaks, count recovery and leaf outputs come from
         find_best_split_numerical itself, so they match the v1 grower
         bit for bit given identical histograms."""
-        g2 = gh[rows]                                  # [B, TBe] f64
-        h2 = hh[rows]
         sgs = sgs.astype(jnp.float64)
         shs = shs.astype(jnp.float64)
         nd = cnts.astype(I32)
@@ -981,7 +980,7 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
             # voted winners' bin windows are reduced — a compact
             # [B, 2, N_WIN, W_scan] buffer over the wire (int16 codes
             # under quantization), never the full planes.
-            B = rows.shape[0]
+            B = g2.shape[0]
             Sn_f = jax.lax.psum(jnp.asarray(1.0, jnp.float64), axis_name)
             Sn_i = Sn_f.astype(I32)
             local_sg = jnp.sum(g2, axis=1) / jnp.float64(max(F, 1))
@@ -1060,21 +1059,19 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
             cand.left_output.astype(EV), cand.right_output.astype(EV),
         ], axis=1)                                             # [B, 12]
 
-    def eval_batch(gh, hh, rows, sgs, shs, cnts, depths, params,
+    def eval_batch(g2, h2, sgs, shs, cnts, depths, params,
                    layout, tag):
-        """Best splits for a BATCH of leaves from the per-plane hist
-        tensors (gh/hh: [L, TBe] — separate grad/hess planes so no
-        strided channel slices exist anywhere; a fused
-        gather+pad+channel-slice miscompiles on TPU at large G).
+        """Best splits for a BATCH of leaves from their histogram rows
+        (g2/h2: [B, TBe] — separate grad/hess rows so no strided channel
+        slices exist anywhere). The caller hands over the rows it holds:
+        a gather of them by an index vector out of the [L, TBe] planes
+        copies both planes whole on TPU (2 GB a split at 2,000 columns).
 
-        rows: [B] i32 leaf-hist row ids; sgs/shs/cnts/depths: [B].
-        Historically B was the (left, right) pair of one split; the
-        level program feeds every frontier child of a level at once.
-        Returns a [B, 12] EV best-candidate matrix.
+        sgs/shs/cnts/depths: [B]. Historically B was the (left, right)
+        pair of one split; the level program feeds every frontier child
+        of a level at once. Returns a [B, 12] EV best-candidate matrix.
         """
-        B = rows.shape[0]
-        g2 = gh[rows]                                  # [B, TBe]
-        h2 = hh[rows]
+        B = g2.shape[0]
         p32 = params.cast(F32)
         sg = sgs.astype(F32)
         sh = shs.astype(F32) + F32(2e-15)
@@ -1211,15 +1208,16 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
         return finish(gain_b, best_f, t_b, use_f_b, lg, lh, lc,
                       layout.forced_right[best_f])
 
-    def evalB(gh, hh, rows, sgs, shs, cnts, depths, params, layout,
+    def evalB(g2, h2, sgs, shs, cnts, depths, params, layout,
               fmask, tag=None):
         """Eval dispatcher: the widened v1 f64 find in xla mode, the
-        fused Mosaic scan kernels otherwise. ``tag`` seeds the voting
-        winner-window quantization (rank-uniform, per grow stage)."""
+        fused Mosaic scan kernels otherwise. g2/h2: the [B, TBe]
+        histogram rows. ``tag`` seeds the voting winner-window
+        quantization (rank-uniform, per grow stage)."""
         if wide:
-            return eval_batch_wide(gh, hh, rows, sgs, shs, cnts, depths,
+            return eval_batch_wide(g2, h2, sgs, shs, cnts, depths,
                                    params, fmask, tag)
-        return eval_batch(gh, hh, rows, sgs, shs, cnts, depths, params,
+        return eval_batch(g2, h2, sgs, shs, cnts, depths, params,
                           layout, tag)
 
     # quantization-seed stage ids: root 0, level programs 1..md (+1 per
@@ -1267,7 +1265,7 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
             .at[LS_SH].set(sum_hess.astype(ST))
             .at[LS_CNT].set(root_cnt).at[LS_VAL].set(root_out.astype(ST))
             .at[LS_NROWS].set(jnp.asarray(n, ST)))
-        pair0 = evalB(gh, hh, jnp.asarray([0, 0], I32),
+        pair0 = evalB(jnp.stack([gh0, gh0]), jnp.stack([hh0, hh0]),
                       jnp.stack([sum_grad, sum_grad]),
                       jnp.stack([sum_hess, sum_hess]),
                       jnp.stack([root_cnt, root_cnt]),
@@ -1507,13 +1505,14 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
                 tree = st.tree.at[tree_idx].set(rec, mode="drop")
 
                 # batched split-find for EVERY new child of the level
-                rows_b = jnp.concatenate(
-                    [slots, jnp.minimum(new_ids, L - 1)])
+                # (an inactive slot's right row is zeros; its result is
+                # dropped below)
                 sgs_b = jnp.concatenate([bl[:, BC_LSG], bl[:, BC_RSG]])
                 shs_b = jnp.concatenate([bl[:, BC_LSH], bl[:, BC_RSH]])
                 cnts_b = jnp.concatenate([left_cnt, right_cnt])
                 depths_b = jnp.concatenate([depth_child, depth_child])
-                pairs = evalB(gh, hh, rows_b, sgs_b, shs_b,
+                pairs = evalB(jnp.concatenate([vgl, vgr]),
+                              jnp.concatenate([vhl, vhr]), sgs_b, shs_b,
                               cnts_b, depths_b, params,
                               layout, fmask,
                               quant_tag(it_q, 1 + st.levels))  # [2S, 12]
@@ -1634,7 +1633,7 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
 
             depth_child = (ls[LS_DEPTH] + 1.0).astype(ST)
             pair = evalB(
-                gh, hh, jnp.stack([l, s]),
+                jnp.stack([vgl, vgr]), jnp.stack([vhl, vhr]),
                 jnp.stack([bl[BC_LSG], bl[BC_RSG]]),
                 jnp.stack([bl[BC_LSH], bl[BC_RSH]]),
                 jnp.stack([left_cnt, right_cnt]),
@@ -2071,8 +2070,6 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
     gr.wire_bytes_model = wire_bytes_model
     gr.reduced_feature_frac = (N_WIN / max(F, 1) if voting else 1.0)
     gr.grad_health = grad_health
-    gr._eval_batch = evalB             # debug/testing hooks
-    gr._eval_pair = evalB              # historical alias (B = 2)
     gr._root_hist = root_hist
     gr._pad_meta = pad_meta
     # the kernels as built for this geometry (tests/test_chip_compile.py

@@ -282,6 +282,11 @@ class DataParallelTreeLearner(SerialTreeLearner):
             wrapper.comm_overlap = inner.comm_overlap
             wrapper.wire_bytes_model = inner.wire_bytes_model
             wrapper.reduced_feature_frac = inner.reduced_feature_frac
+            # and the mechanisms the run record counts trees by
+            # (treelearner/serial._count_persist_trees)
+            wrapper.block_scan = inner.block_scan
+            wrapper.inpass_hist = inner.inpass_hist
+            wrapper.wide_payload = inner.wide_payload
             wrapper.init_carry = jax.jit(jax.shard_map(
                 inner.init_carry, mesh=mesh,
                 in_specs=(pay_spec, P(AXIS)), out_specs=pay_spec,

@@ -15,6 +15,7 @@ import sys
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 import lightgbm_tpu as lgb
@@ -170,6 +171,49 @@ def test_split_pass_leaves_every_payload_row_intact(wide, feature, thr):
     assert not left[rid - s0][int(n_left):].any()
     np.testing.assert_array_equal(out[:, :s0], pay[:, :s0])
     np.testing.assert_array_equal(out[:, s0 + n:], pay[:, s0 + n:])
+
+
+# -- the split scan is handed the children's rows -----------------------------
+
+@pytest.mark.parametrize("kernel_impl", ["xla", "pallas"])
+@pytest.mark.parametrize("columns", [F, 28])
+def test_no_gather_reads_the_per_leaf_planes(wide, columns, kernel_impl):
+    """A gather by an index vector out of the [L, TBe] per-leaf planes is
+    lowered on TPU through slices of the whole planes (2 GB a split at
+    2,000 columns). The per-split grower reads a parent's row by a scalar
+    (a dynamic_slice) and hands the split scan the children's rows it has
+    just computed: no gather equation takes an operand of the planes' shape."""
+    from lightgbm_tpu.analysis.dataflow import iter_eqns
+    from lightgbm_tpu.data.dataset import BinnedDataset
+    from lightgbm_tpu.treelearner.serial import SerialTreeLearner
+    bins = wide[0][:, :columns]
+    y = (bins[:, 0] > BINS // 2).astype(np.float64)
+    cfg = lgb.Config({"objective": "binary", "num_leaves": 15,
+                      "max_bin": BINS, "enable_bundle": False,
+                      "verbosity": -1})
+    ds = BinnedDataset.from_matrix(bins.astype(np.float32), cfg, label=y)
+    learner = SerialTreeLearner(cfg, ds)
+    assets = gp.build_assets(ds, y, score64=kernel_impl == "xla")
+    gr = gp.make_persist_grower(assets, learner.meta, learner.grow_config,
+                                interpret=True, kernel_impl=kernel_impl,
+                                fix=learner.fix)
+    assert not gr.use_level
+    S = jax.ShapeDtypeStruct
+    jaxpr = jax.make_jaxpr(
+        lambda pay, fmask: gr.grow(pay, learner.params, fmask))(
+            S(assets.geometry[:2], jnp.uint32), S((columns,), jnp.bool_))
+    # the planes: flat [L, total_bins] f64 in the widened XLA mode, the
+    # kernels' [L, G x 256] f32 otherwise
+    planes = (learner.grow_config.num_leaves,
+              ds.total_bins if kernel_impl == "xla" else 256 * columns)
+    in_loops = set()
+    for eqn, loop_depth in iter_eqns(jaxpr.jaxpr):
+        if loop_depth:
+            in_loops.add(eqn.primitive.name)
+        if eqn.primitive.name == "gather":
+            assert eqn.invars[0].aval.shape != planes, eqn
+    # the walk went inside the split loop: the parent's row is read there
+    assert {"dynamic_slice", "gather"} <= in_loops, in_loops
 
 
 # -- end to end: a forced-persist train held by the plain reference ----------
