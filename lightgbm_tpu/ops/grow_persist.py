@@ -40,7 +40,8 @@ plain data-parallel or PV-tree voting (winner-window-only reduction).
 """
 from __future__ import annotations
 
-from typing import NamedTuple
+from concurrent.futures import ThreadPoolExecutor
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -182,6 +183,8 @@ LS_SG, LS_SH, LS_CNT, LS_VAL, LS_DEPTH, LS_START, LS_NROWS, LS_PAD = range(8)
  BC_LCNT, BC_RCNT, BC_LOUT, BC_ROUT) = range(12)
 # split-record matrix columns
 (TR_LEAF, TR_FEAT, TR_THR, TR_DL, TR_GAIN, TR_IVAL, TR_ICNT, TR_PAD) = range(8)
+# integer leaf-state columns (large_counts: the i32 matrix beside lstate)
+LI_CNT, LI_START, LI_NROWS, LI_PAD = range(4)
 
 
 class PersistAssets(NamedTuple):
@@ -288,14 +291,15 @@ def _payload_geometry(n: int, nbw: int, G: int, C: int = 0, CR: int = 0,
 def _pack_payload(binned: np.ndarray, labels: np.ndarray, n: int,
                   WPA: int, NP: int, nbw: int, rid_offset: int,
                   rid_sentinel: int, plan=None, weights=None,
-                  weight_row: int = 0):
+                  weight_row: int = 0, out=None):
     """One shard's payload matrix from its binned rows + labels, packed
     per `plan` (byte or nibble slots — _payload_plan). Row ids
     are GLOBAL (shard offset baked in): the bag transforms hash them, so
     draws must agree between serial and sharded runs; finalize_scores
-    subtracts the shard offset back out."""
+    subtracts the shard offset back out. ``out``: a zeroed [WPA, NP] view
+    to pack into (the shard's lanes of the sharded payload)."""
     G = binned.shape[1]
-    pay = np.zeros((WPA, NP), np.uint32)
+    pay = np.zeros((WPA, NP), np.uint32) if out is None else out
     if plan is None:
         plan = tuple((g // 4, (g % 4) * 8, 255) for g in range(G))
     col = binned.astype(np.uint32)
@@ -365,17 +369,26 @@ def build_assets(dataset, labels: np.ndarray, C: int = 0,
     telemetry.count("ops::root_chunk_lanes", CR, category="ops")
     K = num_scores
     weight_row = payload_weight_row(nbw, K, score64)
-    blocks = []
-    for k in range(num_shards):
-        pay_k = _pack_payload(binned[k * n:(k + 1) * n],
-                              labels[k * n:(k + 1) * n], n, WPA, NP,
-                              nbw, rid_offset=k * n,
-                              rid_sentinel=n_total, plan=plan,
-                              weights=(weight[k * n:(k + 1) * n]
-                                       if has_w else None),
-                              weight_row=weight_row)
-        blocks.append(pay_k)
-    pay = blocks[0] if num_shards == 1 else np.concatenate(blocks, axis=1)
+    # every shard packs into its own lanes of the one matrix, the shards
+    # side by side on threads (numpy's loops release the lock): at 40M x
+    # 67 four shards in turn, then a copy to join them, took a minute
+    pay = np.zeros((WPA, num_shards * NP), np.uint32)
+
+    def pack(k):
+        _pack_payload(binned[k * n:(k + 1) * n],
+                      labels[k * n:(k + 1) * n], n, WPA, NP,
+                      nbw, rid_offset=k * n,
+                      rid_sentinel=n_total, plan=plan,
+                      weights=(weight[k * n:(k + 1) * n]
+                               if has_w else None),
+                      weight_row=weight_row,
+                      out=pay[:, k * NP:(k + 1) * NP])
+
+    if num_shards == 1:
+        pack(0)
+    else:
+        with ThreadPoolExecutor(num_shards) as pool:
+            list(pool.map(pack, range(num_shards)))
     F = dataset.num_features
     # feature f's storage slot lives in plan[group_of[f]]; its bins
     # occupy the group-local range [ls, le) (bundled groups put several
@@ -488,8 +501,7 @@ def make_xla_root_hist(WPA: int, NP: int, G: int, plan, nbw: int, n: int,
             bg = ((pay[w] >> U32(sh)) & U32(mk)).astype(I32) + g * 256
             gh = gh.at[bg].add(grad)
             hh = hh.at[bg].add(hess)
-        sums = jnp.stack([jnp.sum(grad), jnp.sum(hess)]).astype(out_dtype)
-        return (gh.astype(out_dtype), hh.astype(out_dtype)), sums
+        return gh.astype(out_dtype), hh.astype(out_dtype)
 
     return root_hist
 
@@ -503,14 +515,17 @@ class _PState(NamedTuple):
     #                          # path, the flat [total_bins] v1 layout in
     #                          # the widened XLA mode)
     hh: jnp.ndarray            # [L, TBe] EV hessian histogram plane
-    lstate: jnp.ndarray        # [L, 8] ST (f32; f64 when counts can pass
-    #                          # 2^24 — EXACT_F32_ROWS / state_dtype)
+    lstate: jnp.ndarray        # [L, 8] ST
     best: jnp.ndarray          # [L, 12] EV
     tree: jnp.ndarray          # [L, 8] ST
     levels: jnp.ndarray        # i32: level programs run for this tree
     health: jnp.ndarray        # [HEALTH_LEN] i32 numerics health vector
     #                          # (nan/inf counts + split-margin buckets;
     #                          # telemetry/health.py layout)
+    # large_counts only (rows at or past EXACT_F32_ROWS): the integer
+    # columns of lstate and tree, exact in i32; None otherwise
+    lint: Optional[jnp.ndarray] = None    # [L, 4] i32, LI_* columns
+    tint: Optional[jnp.ndarray] = None    # [L] i32 TR_ICNT by split
 
 
 # ---------------------------------------------------------------------------
@@ -670,7 +685,7 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
                         interpret: bool = False, axis_name=None,
                         kernel_impl: str = "pallas",
                         stat_from_scan: bool = False,
-                        state_dtype=None, fix=None,
+                        large_counts=None, fix=None,
                         level_mode: str = "auto",
                         health: bool = True,
                         quant=None, comm_overlap: bool = False):
@@ -733,6 +748,12 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
     equal the statistical ones; grow() then takes the exact in-bag root
     count from the bag transform.
 
+    large_counts: keep row counts and segment positions in i32 beside the
+    f32 leaf state (see the note at ``ST`` below). None: from
+    EXACT_F32_ROWS rows on, by this payload's own n; a sharded caller
+    passes the choice of the GLOBAL row count, since the counts it holds
+    are global.
+
     axis_name: when set, the grower body runs per-shard under shard_map
     over that mesh axis with rows sharded — the data-parallel learner over
     the persist path. Exactly like the v1 sharded grower (and the
@@ -769,21 +790,35 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
     W = 256
     TBp = G * W
     EV = jnp.float64 if wide else F32   # histogram/eval dtype
-    # the leaf-state/tree-record matrices carry exact integer counts and
-    # payload positions; f32 is integer-exact only to 2^24, so larger
-    # payloads switch them to f64 (tiny [L, 8] matrices — the cost is
-    # noise even with emulated f64 on TPU). Sharded callers pass the
-    # GLOBAL row count's choice via state_dtype. The SCAN's hessian-
-    # derived count recovery stays f32 (estimate-grade by design, the
-    # reference's cnt_factor trade): above 2^24 rows its min_data gating
-    # and the bagged stat counts carry ~1e-7 relative rounding on the
-    # largest leaves. The widened XLA mode is f64 throughout (v1 parity
-    # beats the tiny state saving off-TPU).
-    if wide:
-        ST = jnp.float64
-    else:
-        ST = state_dtype if state_dtype is not None else (
-            F32 if n < EXACT_F32_ROWS else jnp.float64)
+    # the leaf-state/tree-record matrices carry integer counts and
+    # payload positions in float lanes; f32 is integer-exact only to
+    # 2^24. From EXACT_F32_ROWS rows on (``large_counts``; sharded
+    # callers pass the GLOBAL row count's choice, since the counts are
+    # global where the positions are the shard's) those columns live in
+    # i32 beside the matrices (_PState.lint / .tint) and nothing reads
+    # their float lanes: no emulated f64 on the chip (PERF.md section 7
+    # row 0b: the f64 state this replaces lost a few rows of the counts
+    # in every tree at 31.5M rows).
+    # EXACT above 2^24: every leaf's and node's row count (partition
+    # counts, psum'd in i32), segment starts and lengths.
+    # ESTIMATE-GRADE above 2^24, as the reference's cnt_factor recovery
+    # is at any size: the scan's hessian-derived counts (f32). They gate
+    # min_data_in_leaf, choose which child is histogrammed and are the
+    # leaf counts under bagging / GOSS. At a 40M-row root a candidate's
+    # small side is a difference of f32 sums of ~1e6 (ulp 0.125) at ~0.03
+    # a row, so min_data_in_leaf=20 is enforced there to within a few
+    # rows; deeper nodes' sums shrink and the gate sharpens with them.
+    # The recorded counts stay the partition's, exact. The widened XLA
+    # mode is f64 throughout (native off the chip, exact to 2^53).
+    ST = jnp.float64 if wide else F32
+    if large_counts is None:
+        large_counts = n >= EXACT_F32_ROWS
+    big = bool(large_counts) and not wide
+
+    def icol(row_f, row_i, col_f, col_i):
+        """An integer column of the leaf state as i32: from the i32
+        matrix's row(s) under large_counts, else from the float lanes."""
+        return row_i[..., col_i] if big else row_f[..., col_f].astype(I32)
     # level-parallel phase sizing: up to S_MAXL splitting leaves per
     # level program (the widest frontier a depth-bounded tree can
     # present), 2*S_MAXL children per batched split-find
@@ -1225,6 +1260,31 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
     # reduce of a tree draws independent rounding noise
     STAGE_SPLIT0 = LEVEL_MAX_DEPTH + 2
 
+    def root_totals(pay, rhist):
+        """The root's (sum_grad, sum_hess) [2] as the root histogram holds
+        them: the sum of the first group's plane (every row lies in one
+        bin of every group); in the widened mode the payload rows' own
+        f64 sums, the v1 grower's. root_hist once kept running f32
+        totals, one add a chunk: on the first tree every hessian is the
+        same number, so every chunk adds the same number and its rounding
+        into the growing total has one sign, step after step. At 31.5M
+        rows that drifted sum_hess by -126 and +216 of 7.67M on two seeds
+        (32 at 15.75M). The scan takes a node's left side as its total
+        less the right side's bins, so a total that is not its own
+        histogram's leaves the difference with the leftmost child at every
+        split, undiminished: it ended as half of a 972-row leaf's hessian
+        (an output of 4.5 where 2.0 was due) and as an empty leaf that
+        passed min_sum_hessian_in_leaf=100 (PERF.md section 7 row 0b). A
+        bin's addends differ from chunk to chunk, so the planes round
+        without a sign, and totals taken from them agree with what the
+        scan subtracts from them."""
+        if wide:
+            live = jnp.arange(NP, dtype=I32) < n
+            return jnp.stack([
+                jnp.sum(jnp.where(live, _f32r(pay[grad_row + r]), 0.0)
+                        .astype(jnp.float64)) for r in (0, 1)])
+        return jnp.stack([jnp.sum(rhist[0][:W]), jnp.sum(rhist[1][:W])])
+
     def grow(pay, params: SplitParams, fmask, bag_cnt=None, it=None):
         """Grow one tree in place; returns (pay', lstate, tree, num_leaves,
         root_value, stats) where stats = [level_programs,
@@ -1236,11 +1296,13 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
         layout = (None if wide else
                   (_BlockTreeLayout(fmask) if bundled
                    else ScanLayout(pad_meta, fmask, F, W, TBp)))
-        rhist, sums = root_hist(pay)
+        rhist = root_hist(pay)
+        sums = root_totals(pay, rhist)
         gh0 = to_flat(rhist[0])
         hh0 = to_flat(rhist[1])
-        root_cnt = (jnp.asarray(n, ST) if bag_cnt is None
-                    else bag_cnt.astype(ST))
+        CT = I32 if big else ST      # dtype of a row count
+        root_cnt = (jnp.asarray(n, CT) if bag_cnt is None
+                    else bag_cnt.astype(CT))
         if axis_name is not None:
             # root Allreduce (data_parallel_tree_learner.cpp:120-145);
             # voting keeps the PLANES local — only scalar stats go global
@@ -1263,7 +1325,8 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
             jnp.asarray([0, 0, 0, 0, 0, 0, 0, 0], ST)
             .at[LS_SG].set(sum_grad.astype(ST))
             .at[LS_SH].set(sum_hess.astype(ST))
-            .at[LS_CNT].set(root_cnt).at[LS_VAL].set(root_out.astype(ST))
+            .at[LS_CNT].set(root_cnt.astype(ST))
+            .at[LS_VAL].set(root_out.astype(ST))
             .at[LS_NROWS].set(jnp.asarray(n, ST)))
         pair0 = evalB(jnp.stack([gh0, gh0]), jnp.stack([hh0, hh0]),
                       jnp.stack([sum_grad, sum_grad]),
@@ -1291,6 +1354,12 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
             levels=jnp.asarray(0, I32),
             health=health0,
         )
+        if big:
+            state = state._replace(
+                lint=jnp.zeros((L, 4), I32).at[0].set(
+                    jnp.zeros((4,), I32).at[LI_CNT].set(root_cnt)
+                    .at[LI_NROWS].set(n)),
+                tint=jnp.zeros((L,), I32))
 
         # ---- level-parallel phase: one fused program per tree level ----
         if use_level:
@@ -1331,9 +1400,10 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
                 act = arS < cntp
                 bl = st.best[slots]                        # [S, 12]
                 lsb = st.lstate[slots]                     # [S, 8]
+                lib = st.lint[slots] if big else None      # [S, 4]
                 feat = jnp.maximum(bl[:, BC_FEAT].astype(I32), 0)
-                s0 = lsb[:, LS_START].astype(I32)
-                n_l = jnp.where(act, lsb[:, LS_NROWS].astype(I32), 0)
+                s0 = icol(lsb, lib, LS_START, LI_START)
+                n_l = jnp.where(act, icol(lsb, lib, LS_NROWS, LI_NROWS), 0)
                 smaller_is_left = bl[:, BC_LCNT] <= bl[:, BC_RCNT]
                 nch = (n_l + C - 1) // C
                 scal_mat = jnp.stack([
@@ -1434,8 +1504,8 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
                 else:
                     left_cnt = (jax.lax.psum(n_lefts, axis_name)
                                 if axis_name is not None else n_lefts)
-                    right_cnt = (jnp.where(act, lsb[:, LS_CNT]
-                                           .astype(I32), 0) - left_cnt)
+                    right_cnt = (jnp.where(
+                        act, icol(lsb, lib, LS_CNT, LI_CNT), 0) - left_cnt)
                 sm_sg = jnp.where(smaller_is_left, bl[:, BC_LSG],
                                   bl[:, BC_RSG])
                 sm_sh = jnp.where(smaller_is_left, bl[:, BC_LSH],
@@ -1503,6 +1573,18 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
                     axis=1)
                 tree_idx = jnp.where(act, st.s - 1 + arS, L)
                 tree = st.tree.at[tree_idx].set(rec, mode="drop")
+                ints = {}
+                if big:
+                    zi = jnp.zeros_like(n_lefts)
+                    irow_l = jnp.stack([left_cnt, s0, n_lefts, zi], axis=1)
+                    irow_s = jnp.stack([right_cnt, s0 + n_lefts,
+                                        n_l - n_lefts, zi], axis=1)
+                    ints = dict(
+                        lint=st.lint.at[slots].set(
+                            jnp.where(actc, irow_l, lib))
+                        .at[new_ids].set(irow_s, mode="drop"),
+                        tint=st.tint.at[tree_idx].set(
+                            lib[:, LI_CNT], mode="drop"))
 
                 # batched split-find for EVERY new child of the level
                 # (an inactive slot's right row is zeros; its result is
@@ -1522,7 +1604,7 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
                 return st._replace(
                     s=st.s + cntp, pay=pay2, gh=gh, hh=hh,
                     lstate=lstate, best=best, tree=tree,
-                    levels=st.levels + 1, health=hv)
+                    levels=st.levels + 1, health=hv, **ints)
 
             state = jax.lax.while_loop(level_cond, level_body, state)
         s_after_level = state.s
@@ -1537,10 +1619,11 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
             s = st.s
             bl = st.best[l]
             ls = st.lstate[l]
+            li = st.lint[l] if big else None
             f = jnp.maximum(bl[BC_FEAT].astype(I32), 0)
             smaller_is_left = bl[BC_LCNT] <= bl[BC_RCNT]
-            s0 = ls[LS_START].astype(I32)
-            n_l = jnp.where(do, ls[LS_NROWS].astype(I32), 0)
+            s0 = icol(ls, li, LS_START, LI_START)
+            n_l = jnp.where(do, icol(ls, li, LS_NROWS, LI_NROWS), 0)
             # one stack in S_* slot order (see pallas_grow) instead of 15
             # chained dynamic updates on the [N_SCALARS] vector
             scal = jnp.stack([
@@ -1597,7 +1680,7 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
             else:
                 left_cnt = (jax.lax.psum(n_left, axis_name)
                             if axis_name is not None else n_left)
-                right_cnt = (jnp.where(do, ls[LS_CNT].astype(I32), 0)
+                right_cnt = (jnp.where(do, icol(ls, li, LS_CNT, LI_CNT), 0)
                              - left_cnt)
             sm_sg = jnp.where(smaller_is_left, bl[BC_LSG], bl[BC_RSG])
             sm_sh = jnp.where(smaller_is_left, bl[BC_LSH], bl[BC_RSH])
@@ -1671,10 +1754,20 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
                 .at[TR_ICNT].set(ls[LS_CNT])
             tree = st.tree.at[s - 1].set(
                 jnp.where(do, rec, st.tree[s - 1]))
+            ints = {}
+            if big:
+                zi = jnp.zeros((), I32)
+                irow_l = jnp.stack([left_cnt, s0, n_left, zi])
+                irow_s = jnp.stack([right_cnt, s0 + n_left, n_right, zi])
+                ints = dict(
+                    lint=st.lint.at[l].set(jnp.where(do, irow_l, li))
+                    .at[s].set(jnp.where(do, irow_s, st.lint[s])),
+                    tint=st.tint.at[s - 1].set(
+                        jnp.where(do, li[LI_CNT], st.tint[s - 1])))
             return st._replace(
                 s=s + do.astype(I32), done=~do, pay=pay,
                 gh=gh, hh=hh, lstate=lstate, best=best, tree=tree,
-                health=hv)
+                health=hv, **ints)
 
         final = jax.lax.while_loop(cond, body, state)
         # the iter-launch slot is the DRIVER's (one bump per compiled
@@ -1683,6 +1776,11 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
             [jnp.stack([final.levels, final.s - s_after_level,
                         jnp.zeros((), I32)]),
              final.health])
+        if big:
+            # the integer columns ride beside their matrices: the pairs
+            # are what apply_scores* and to_tree_arrays take
+            return (final.pay, (final.lstate, final.lint),
+                    (final.tree, final.tint), final.s, root_out, stats)
         return (final.pay, final.lstate, final.tree, final.s, root_out,
                 stats)
 
@@ -1711,6 +1809,8 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
         The widened mode hands f64 leaf values/gains through (v1 f64
         parity); the Mosaic fast path stays f32 (gpu_use_dp=false)."""
         ft = jnp.float64 if wide else F32
+        if big:
+            (lstate, lint), (tree, tint) = lstate, tree
         return TreeArrays(
             num_leaves=num_leaves,
             split_leaf=tree[:L - 1, TR_LEAF].astype(I32),
@@ -1723,12 +1823,21 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
             is_cat=jnp.zeros((L - 1,), BOOL),
             cat_mask=jnp.zeros((L - 1, gc.cat_width), BOOL),
             internal_value=tree[:L - 1, TR_IVAL].astype(ft),
-            internal_count=tree[:L - 1, TR_ICNT].astype(I32),
+            internal_count=(tint[:L - 1] if big else
+                            tree[:L - 1, TR_ICNT].astype(I32)),
             leaf_value=lstate[:, LS_VAL].astype(ft),
-            leaf_count=lstate[:, LS_CNT].astype(I32),
+            leaf_count=(lint[:, LI_CNT] if big else
+                        lstate[:, LS_CNT].astype(I32)),
             leaf_weight=lstate[:, LS_SH].astype(ft),
             row_leaf=jnp.zeros((0,), I32),
         )
+
+    def _int_segments(lint, num_leaves):
+        """large_counts: (segment starts [L] i32 with NP where the slot
+        holds no live segment, so that they sort last; live mask)."""
+        live = ((lint[:, LI_NROWS] > 0)
+                & (jnp.arange(L, dtype=I32) < num_leaves))
+        return jnp.where(live, lint[:, LI_START], NP), live
 
     def apply_scores(pay, lstate, num_leaves, shrink, cls=0):
         """score-row of class `cls` += shrink * leaf_value[leaf_of_position]
@@ -1737,9 +1846,12 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
         (leaf of a position by searchsorted over live segment starts) so
         each row's update is the same leaf_value * shrink product — and
         the same single f64 add — as the v1 score updater."""
-        starts = lstate[:, LS_START]
-        nrows = lstate[:, LS_NROWS]
-        live = (nrows > 0) & (jnp.arange(L, dtype=I32) < num_leaves)
+        if big:
+            lstate, lint = lstate
+        else:
+            starts = lstate[:, LS_START]
+            nrows = lstate[:, LS_NROWS]
+            live = (nrows > 0) & (jnp.arange(L, dtype=I32) < num_leaves)
         if score64:
             vals = lstate[:, LS_VAL] * shrink.astype(ST)
             key = jnp.where(live, starts, jnp.inf)
@@ -1759,7 +1871,11 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
             sc = sc + jnp.where(num_leaves > 1, upd, 0.0)
             return _write_score(pay, sc, cls)
         vals = (lstate[:, LS_VAL] * shrink.astype(ST)).astype(F32)
-        key = jnp.where(live, starts, jnp.inf)
+        if big:
+            starts, live = _int_segments(lint, num_leaves)
+            key = starts
+        else:
+            key = jnp.where(live, starts, jnp.inf)
         order = jnp.argsort(key)
         sv = vals[order]
         live_o = live[order]
@@ -1782,19 +1898,24 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
         multiplies and one add per row as the three ScoreUpdater
         dispatches it replaces. 1-leaf trees leave the average
         untouched (the reference appends a stub and keeps going)."""
-        starts = lstate[:, LS_START]
-        nrows = lstate[:, LS_NROWS]
-        live = (nrows > 0) & (jnp.arange(L, dtype=I32) < num_leaves)
+        if big:
+            lstate, lint = lstate
+            key, live = _int_segments(lint, num_leaves)
+            pos = jnp.arange(NP, dtype=I32)
+        else:
+            starts = lstate[:, LS_START]
+            nrows = lstate[:, LS_NROWS]
+            live = (nrows > 0) & (jnp.arange(L, dtype=I32) < num_leaves)
+            key = jnp.where(live, starts, jnp.inf)
+            pos = jnp.arange(NP, dtype=I32).astype(ST)
         vals = lstate[:, LS_VAL]
         # host add_bias only fires for |init| > eps; skip the +0.0 too
         # so a -0.0 leaf keeps its sign exactly like the host path
         vals = jnp.where(bias != 0.0, vals + bias.astype(ST), vals)
-        key = jnp.where(live, starts, jnp.inf)
         order = jnp.argsort(key)
         sstart = key[order]
         svals = vals[order]
         slive = live[order]
-        pos = jnp.arange(NP, dtype=I32).astype(ST)
         idx = jnp.clip(jnp.searchsorted(sstart, pos, side="right") - 1,
                        0, L - 1)
         upd = jnp.where(slive[idx], svals[idx], 0.0)
@@ -2060,6 +2181,9 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
     # past 56 payload words the chunk follows from the row's width
     # (_payload_geometry): wide_payload_trees
     gr.wide_payload = WPA > 56
+    # row counts at or past 2^24 ride i32 beside the f32 state:
+    # large_count_trees
+    gr.large_counts = big
     gr.use_level = use_level
     gr.S_MAXL = S_MAXL
     gr.health = health
@@ -2071,6 +2195,7 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
     gr.reduced_feature_frac = (N_WIN / max(F, 1) if voting else 1.0)
     gr.grad_health = grad_health
     gr._root_hist = root_hist
+    gr._root_totals = root_totals
     gr._pad_meta = pad_meta
     # the kernels as built for this geometry (tests/test_chip_compile.py
     # compiles exactly these for the described chip); None where the

@@ -1165,44 +1165,37 @@ def make_seg_hist(WPA: int, NP: int, G: int, plan, nbw: int,
 def make_root_hist(WPA: int, NP: int, G: int, plan, nbw: int, n: int,
                    C: int = 16384, interpret: bool = False,
                    _loop_groups=None):
-    """One streaming pass: padded root histogram + grad/hess totals.
+    """One streaming pass: the padded root histogram.
 
-    Returns fn(pay) -> (hist [G*256, 2] f32, sums [2] f32).
-    Totals are f32 chunk-partial sums (deterministic order).
+    Returns fn(pay) -> (gh [G*256], hh [G*256]) f32. The root's sums are
+    the caller's to read off a group's plane (grow_persist.root_totals): a
+    running f32 total kept here, one add a chunk, drifts with one sign
+    where every hessian is the same number (PERF.md section 7 row 0b).
     """
     assert WPA % 8 == 0
     looped = hist_loops_groups(G, plan, _loop_groups)
     nch = (n + C - 1) // C
     assert NP >= nch * C, "payload lanes must cover whole root chunks"
 
-    def kernel(pay_hbm, hist_ref, sums_ref, wbuf, acc, sem_r):
+    def kernel(pay_hbm, hist_ref, wbuf, sem_r):
         i = pl.program_id(0)
 
         @pl.when(i == 0)
         def _init():
             hist_ref[...] = jnp.zeros_like(hist_ref)
-            acc[0] = 0.0
-            acc[1] = 0.0
 
         cp = pltpu.make_async_copy(
             pay_hbm.at[:, pl.ds(i * C, C)], wbuf, sem_r)
         cp.start()
         cp.wait()
-        grad, hess = _chunk_hist(hist_ref, wbuf, None, lambda: n - i * C,
-                                 plan, nbw, G, looped)
-        acc[0] = acc[0] + jnp.sum(grad)
-        acc[1] = acc[1] + jnp.sum(hess)
-
-        @pl.when(i == nch - 1)
-        def _fin():
-            sums_ref[0] = acc[0]
-            sums_ref[1] = acc[1]
+        _chunk_hist(hist_ref, wbuf, None, lambda: n - i * C, plan, nbw, G,
+                    looped)
 
     @jax.jit
     def root_hist(pay):
         with enable_x64(False):
-            hist, sums = _call(pay)
-        return _unpack_hist(hist), sums
+            hist = _call(pay)[0]
+        return _unpack_hist(hist)
 
     # the streaming chunk buffer alone (WPA*C u32) outgrows the 16MB
     # Mosaic default on wide unbundled payloads (~180 words at C=16384)
@@ -1218,16 +1211,10 @@ def make_root_hist(WPA: int, NP: int, G: int, plan, nbw: int, n: int,
             out_specs=[
                 pl.BlockSpec((G, 16, 64),
                              lambda i: (i * 0, i * 0, i * 0)),
-                pl.BlockSpec((2,), lambda i: (i * 0,),
-                             memory_space=pltpu.SMEM),
             ],
-            out_shape=[
-                jax.ShapeDtypeStruct((G, 16, 64), F32),
-                jax.ShapeDtypeStruct((2,), F32),
-            ],
+            out_shape=[jax.ShapeDtypeStruct((G, 16, 64), F32)],
             scratch_shapes=[
                 pltpu.VMEM((WPA, C), U32),
-                pltpu.SMEM((2,), F32),
                 pltpu.SemaphoreType.DMA,
             ],
             interpret=interpret,

@@ -208,12 +208,31 @@ class DataParallelTreeLearner(SerialTreeLearner):
         # data-parallel AND voting-parallel ride the sharded persist
         # driver (voting = local planes + in-eval vote, grow_persist);
         # feature-parallel replicates rows and keeps the v1 path
-        return (self.grow_config.parallel_mode != "feature"
-                and self.dataset.num_data % self.num_shards == 0)
+        if self.grow_config.parallel_mode == "feature":
+            return False
+        n, S = self.dataset.num_data, self.num_shards
+        if n % S == 0:
+            return True
+        if not getattr(self, "_uneven_shards_said", False):
+            # every other gate of can_persist_scan has passed when this one
+            # is asked: say once that the per-tree v1 grower takes the job
+            self._uneven_shards_said = True
+            telemetry.count("tree_learner::sharded_v1_fallback",
+                            category="tree_learner")
+            Log.warning(
+                "tree_learner=%s: %d rows do not divide into %d equal "
+                "shards, so the sharded persistent-payload fast path is "
+                "off and the per-tree v1 grower trains this run (pad or "
+                "trim the rows to a multiple of %d to get it back)"
+                % (self.grow_config.parallel_mode, n, S, S))
+        return False
 
     def _persist_rows_ok(self) -> bool:
-        # 32-bit row ids / lane pointers bound the TOTAL rows; counts
-        # above 2^24 ride f64 leaf state (state_dtype below)
+        # 32-bit row ids bound the TOTAL rows. From 2^24 rows on the
+        # global counts (psum'd partition counts) and the shard's segment
+        # positions are exact in i32 (large_counts below); the scan's
+        # hessian-derived counts, which gate min_data_in_leaf, stay f32
+        # estimates (ops/grow_persist.py:make_persist_grower)
         return self.dataset.num_data < (1 << 31) - (1 << 16)
 
     def _persist_obj_ok(self, objective) -> bool:
@@ -245,8 +264,14 @@ class DataParallelTreeLearner(SerialTreeLearner):
         if assets is None:
             assets = build_assets(self.dataset, self.dataset.metadata.label,
                                   num_shards=S, score64=score64)
-            assets = assets._replace(pay0=jax.device_put(
-                assets.pay0, NamedSharding(mesh, pay_spec)))
+            # run record: each chip's lanes of the host payload onto the
+            # mesh; blocked, so that the span holds the copy and not its
+            # dispatch alone
+            with telemetry.scope("tree_learner::ShardPayload(device_put)",
+                                 category="setup", always=True):
+                assets = assets._replace(pay0=jax.block_until_ready(
+                    jax.device_put(assets.pay0,
+                                   NamedSharding(mesh, pay_spec))))
             cache[akey] = assets
         stat_from_scan = bag_spec[0] != "none"
         gc = self.grow_config
@@ -260,12 +285,9 @@ class DataParallelTreeLearner(SerialTreeLearner):
                 kernel_impl=kernel_impl, stat_from_scan=stat_from_scan,
                 fix=self.fix, level_mode=level_mode, health=health,
                 quant=self.hist_quant, comm_overlap=self.comm_overlap,
-                # GLOBAL counts live in the leaf state: pick exactness by
-                # the total row count, not the per-shard one (the widened
-                # xla mode overrides to f64 internally)
-                state_dtype=(jnp.float32
-                             if self.dataset.num_data < EXACT_F32_ROWS
-                             else jnp.float64))
+                # GLOBAL counts live in the leaf state: the total row
+                # count decides whether they need i32, not the shard's
+                large_counts=self.dataset.num_data >= EXACT_F32_ROWS)
 
             class _ShardedGrower:
                 pass
@@ -287,6 +309,8 @@ class DataParallelTreeLearner(SerialTreeLearner):
             wrapper.block_scan = inner.block_scan
             wrapper.inpass_hist = inner.inpass_hist
             wrapper.wide_payload = inner.wide_payload
+            wrapper.large_counts = inner.large_counts
+            wrapper.num_shards = S
             wrapper.init_carry = jax.jit(jax.shard_map(
                 inner.init_carry, mesh=mesh,
                 in_specs=(pay_spec, P(AXIS)), out_specs=pay_spec,

@@ -461,7 +461,10 @@ class SerialTreeLearner:
 
     def _persist_rows_ok(self) -> bool:
         """Row-count bound for one payload: lane pointers and row ids are
-        32-bit (counts above 2^24 ride f64 leaf state automatically)."""
+        32-bit. From 2^24 rows on, row counts and segment positions are
+        exact in i32 beside the f32 leaf state; the scan's
+        hessian-derived counts, which gate min_data_in_leaf, stay f32
+        estimates (ops/grow_persist.py:make_persist_grower)."""
         return self.dataset.num_data < (1 << 31) - (1 << 16)
 
     def _persist_obj_ok(self, objective) -> bool:
@@ -481,8 +484,8 @@ class SerialTreeLearner:
         transposed payload (fused split kernel, no per-row gathers).
         Requirements beyond the Pallas-scan fast path: numerical features
         only, a payload pack plan (<= 256 bins per group — narrow groups
-        nibble-pack, device_packed v1 storage is fine), per-payload rows
-        < 2^24; sample weights ride as a payload row and EFB bundles
+        nibble-pack, device_packed v1 storage is fine), rows under 2^31
+        less 2^16 (_persist_rows_ok); sample weights ride as a payload row and EFB bundles
         decode in the split kernel. Single device or the data/voting-
         parallel learners (sharded persist). tpu_persist_scan=force
         engages the XLA kernel emulation off-TPU (tests)."""
@@ -650,7 +653,8 @@ class SerialTreeLearner:
         """Run record: k trees on the persist path, and by which of the
         grower's mechanisms (split scan over the bundled group planes;
         smaller-child histogram built inside split_pass; a payload row
-        wide enough for its width to size the kernels' chunks)."""
+        wide enough for its width to size the kernels' chunks; row counts
+        in i32 past 2^24 rows; the sharded grower)."""
         telemetry.count("tree_learner::persist_scan_trees", float(k),
                         category="tree_learner")
         if gr.block_scan:
@@ -661,6 +665,18 @@ class SerialTreeLearner:
                             category="tree_learner")
         if gr.wide_payload:
             telemetry.count("tree_learner::wide_payload_trees", float(k),
+                            category="tree_learner")
+        if gr.large_counts:
+            telemetry.count("tree_learner::large_count_trees", float(k),
+                            category="tree_learner")
+        shards = getattr(gr, "num_shards", 0)
+        if shards:
+            # the sharded grower (parallel/learners.py): its trees, and
+            # over how many shards the newest launch ran (set, not summed)
+            telemetry.count("tree_learner::sharded_persist_trees", float(k),
+                            category="tree_learner")
+            telemetry.clear_counts_prefix("tree_learner::shards")
+            telemetry.count("tree_learner::shards", float(shards),
                             category="tree_learner")
 
     @staticmethod

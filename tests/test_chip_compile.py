@@ -446,3 +446,62 @@ def test_compiles_for_v5e(case, one_chip, higgs, expo, request):
         # by an index vector out of the [L, G x 256] planes is lowered
         # through whole-plane slices (2 GB a split at 2,000 columns)
         assert "mini-gather" not in text
+
+
+CRITEO_SHARD_ROWS = 10_000_000   # benchmark/configs/criteo.json: 40M over 4
+CRITEO_FEATURES = 67
+
+
+def test_sharded_fused_driver_compiles_for_four_v5e(topo, one_chip):
+    """criteo.train_data4's launch: the fused k=16 driver under shard_map
+    over all four devices of the described v5e:2x2, at the cell's geometry
+    (67 groups, so the histogram kernels loop over word rows; 17 bin
+    words, WPA 24, a 96 B payload row; 10,000,000 rows a shard) and with
+    the state 40M rows in all take (row counts in i32). The planes'
+    all-reduce inside the grow loop has to be in the compiled program."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from lightgbm_tpu.parallel.learners import AXIS, _tree_arrays_spec
+    rng = np.random.default_rng(38)
+    X = rng.normal(size=(SAMPLE_ROWS, CRITEO_FEATURES)).astype(np.float32)
+    y = (X[:, :4].sum(axis=1) > 1.5).astype(np.float64)
+    built = _Built(X, y, CRITEO_SHARD_ROWS, (-1,), enable_bundle=False)
+    WPA, NP, G, plan = built.assets.geometry[:4]
+    assert (WPA, G, built.wp_live) == (24, CRITEO_FEATURES, 22)
+    assert hist_loops_groups(G, plan)
+    learner = built.learners[-1]
+    gc = learner.grow_config._replace(parallel_mode="data")
+    gr = gp.make_persist_grower(built.assets, learner.meta, gc,
+                                interpret=False, kernel_impl="pallas",
+                                axis_name=AXIS, fix=learner.fix,
+                                large_counts=True)
+    assert gr.large_counts
+    k, F = 16, built.ds.num_features
+    mode, grad_fn = built.objective.device_gradients()
+    assert mode == "payload"
+    run = gp.make_scan_driver(gr, gc, k, grad_fn, wrap_jit=False)
+    mesh = Mesh(np.asarray(topo.devices).reshape(-1), (AXIS,))
+    assert mesh.devices.size == 4
+    pay_spec = P(None, AXIS)
+    smapped = jax.shard_map(
+        run, mesh=mesh,
+        in_specs=(pay_spec, P(), P(), P(), P(), P(), P()),
+        out_specs=(pay_spec, _tree_arrays_spec(gc, row_sharded=False), P()),
+        check_vma=False)
+
+    def S(shape, dtype, spec=P()):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+    like = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: S(np.shape(a), jnp.asarray(a).dtype), tree)
+    args = (S((WPA, 4 * NP), jnp.uint32, pay_spec), S((k, F), jnp.bool_),
+            S((k, 2), jnp.uint32), S((k,), jnp.int32),
+            like(learner.params), S((), jnp.float64),
+            like(built.objective.persist_grad_args()))
+    compiled = jax.jit(smapped, donate_argnums=(0,)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "all-reduce" in text
+    assert "mini-gather" not in text
+    mem = compiled.memory_analysis()
+    per_device = (mem.argument_size_in_bytes + mem.temp_size_in_bytes)
+    # a shard's payload (96 B x 10M rows) and its temporaries fit a chip
+    assert per_device < 8 * 2 ** 30, per_device
