@@ -37,13 +37,17 @@ Mosaic kernels over a single transposed payload matrix:
         FIFO slot at the offset the drain will write them (word moves only,
         bit-exact, no sort, no scratch matmul),
       - unless ``_skip_hist``, accumulates the SMALLER child's histogram
-        as radix-16 one-hot MXU contractions (the GPU histogram kernel
-        analog, src/treelearner/ocl/histogram256.cl, re-derived for the
-        MXU) over the rows just compacted into that child's slot, block
-        by block, so its cost follows the smaller child and not the chunk
-        (_slot_hist). The persist grower skips it and runs seg_hist after
-        the pass instead, which read 2-3% faster on the chip at 16.5M
-        rows (PERF.md, PR 35),
+        as radix-16 MXU contractions (the GPU histogram kernel analog,
+        src/treelearner/ocl/histogram256.cl, re-derived for the MXU: a
+        bin is hi * 16 + lo, the [16, E] one-hot of hi is contracted over
+        the lanes with a [64, E] operand that holds the four bf16 value
+        rows where the lo mask is on and 0 elsewhere, SELECTED under the
+        mask and not multiplied by a one-hot, _hist_group) over the rows
+        just compacted into that child's slot, block by block, so its
+        cost follows the smaller child and not the chunk (_slot_hist).
+        The persist grower skips it and runs seg_hist after the pass
+        instead, which read 2-3% faster on the chip at 16.5M rows
+        (PERF.md, PR 35),
       - partitions the payload IN PLACE: a two-ended writeback with a
         2-chunk FIFO. Chunks are read from whichever end has the smaller
         write-space gap and drained two steps later, so reads always lead
@@ -150,8 +154,9 @@ def seg_hist_vmem_bytes(WPA: int, E: int, G: int, looped: bool = False,
     """seg_hist / level_seg_hist / root_hist: one streaming chunk buffer
     (+1 working copy) + the radix hist accumulator + what the group loop
     holds at once. Unrolled, that is the [G, E] decoded group-bin planes
-    `_hist_accum` is handed and its one-hot rhs: at G=700, E=8320 (a
-    700-group unbundled shape) they are 24MB. ``looped``
+    `_hist_accum` is handed and one group's [64, E] operand, a float32
+    value (_hist_group selects it in f32 and casts it once): at G=700,
+    E=8320 (a 700-group unbundled shape) they are 25MB. ``looped``
     (hist_loops_groups) the kernels decode one sublane tile of word rows
     at a time (_hist_accum_words) and the [G, E] plane never exists, but
     the accumulator is counted as VMEM holds it: [16, 64] tiles padded to
@@ -159,10 +164,10 @@ def seg_hist_vmem_bytes(WPA: int, E: int, G: int, looped: bool = False,
     each at 2,000 groups). ``cap`` as in split_pass_vmem_bytes."""
     if looped:
         need = (2 * WPA * E * 4 + 2 * G * 16 * 128 * 4
-                + 8 * E * 4 + 64 * E * 2 + (20 << 20))
+                + 8 * E * 4 + 64 * E * 4 + (20 << 20))
     else:
         need = (2 * WPA * E * 4 + G * 16 * 64 * 4
-                + G * E * 4 + 64 * E * 2 + (20 << 20))
+                + G * E * 4 + 64 * E * 4 + (20 << 20))
     return int(need if cap is None else min(cap, need))
 
 
@@ -181,7 +186,8 @@ def grow_input_contract(NP: int, w: int = 256) -> dict:
 
 
 # the grow kernels reuse the histogram kernel's exact bf16 hi/lo trick
-# for their in-payload radix contractions (_hist_accum) — same blessing
+# for their in-payload radix contractions (_hist_values; _hist_group's one
+# cast of the selected [64, E] operand) — same blessing
 NARROW_OK = (("float32", "bfloat16"),)
 
 
@@ -348,24 +354,30 @@ def _unpack_group_bins(pay_block, plan):
 
 
 def _hist_values(grad, hess):
-    """The four bf16 value rows of the radix contraction: (grad_hi,
-    hess_hi, grad_lo, hess_lo), hi + lo exact to f32."""
-    g_hi = grad.astype(jnp.bfloat16)
-    h_hi = hess.astype(jnp.bfloat16)
-    g_lo = (grad - g_hi.astype(F32)).astype(jnp.bfloat16)
-    h_lo = (hess - h_hi.astype(F32)).astype(jnp.bfloat16)
+    """The four value rows of the radix contraction, (grad_hi, hess_hi,
+    grad_lo, hess_lo), as f32 rows that hold bf16-representable values:
+    hi + lo exact to f32, and the one cast to bf16 that _hist_group makes
+    of them loses nothing."""
+    g_hi = grad.astype(jnp.bfloat16).astype(F32)
+    h_hi = hess.astype(jnp.bfloat16).astype(F32)
+    g_lo = (grad - g_hi).astype(jnp.bfloat16).astype(F32)
+    h_lo = (hess - h_hi).astype(jnp.bfloat16).astype(F32)
     return (g_hi, h_hi, g_lo, h_lo)
 
 
 def _hist_group(hist_ref, g, b, n16, vt):
     """hist_ref[g] += one group's contraction; b: [E] i32 group-local
-    bins, g static or traced."""
+    bins, g static or traced, vt: _hist_values' rows."""
     oh_hi = (n16 == (b >> 4)[None, :]).astype(jnp.bfloat16)   # [16, E]
-    oh_lo = (n16 == (b & 15)[None, :]).astype(jnp.bfloat16)
-    # 64-sublane one-hots can't be built directly (i1 relayout at 64
-    # rows breaks Mosaic); concatenating four known-good [16, E]
-    # scaled one-hots gives the same [64, E] rhs
-    bv = jnp.concatenate([oh_lo * v[None, :] for v in vt], axis=0)
+    lo = n16 == (b & 15)[None, :]
+    # the value-carrying operand is SELECTED, not multiplied: the v5e has
+    # no bf16 VALU, so a [16, E] bf16 product is two unpacks, two f32
+    # multiplies and a pack a vreg; a select of f32 rows under the [16, E]
+    # mask, cast once at [64, E], Mosaic folds into the MXU push
+    # (vmatpush...msk). A value or 0 where the product had 1 x value or
+    # 0 x value: the same operand but for the sign of a zero
+    bv = jnp.concatenate([jnp.where(lo, v[None, :], 0.0) for v in vt],
+                         axis=0).astype(jnp.bfloat16)         # [64, E]
     # lanes [0, 64) only: the level kernels' accumulators carry
     # HIST_LANES_PAD lanes (see below), the others exactly 64
     hist_ref[g, :, 0:64] = hist_ref[g, :, 0:64] + jax.lax.dot_general(
@@ -374,13 +386,16 @@ def _hist_group(hist_ref, g, b, n16, vt):
 
 
 def _hist_accum(hist_ref, bins_g, grad, hess, G: int):
-    """hist_ref[g] += radix-16 one-hot MXU contraction of one chunk.
+    """hist_ref[g] += radix-16 MXU contraction of one chunk, group by
+    group (_hist_group): the [16, E] one-hot of a bin's high nibble
+    against a [64, E] operand that holds, in row v*16+lo, value row v
+    where the bin's low nibble is lo and 0 elsewhere.
 
     bins_g: [G, E] i32; grad/hess: [E] f32 already masked to valid rows.
     hist_ref: [G, 16, >=64] f32 VMEM ref holding RAW accumulator columns
     v*16+lo for v in (grad_hi, hess_hi, grad_lo, hess_lo) — the bf16 hi/lo
     pairs that make the contraction exact to f32 (ops/pallas_histogram
-    docs). The 4 value columns ride ONE [64, E] rhs so each group costs one
+    docs). The 4 value rows ride ONE [64, E] rhs so each group costs one
     [16,E]x[E,64] MXU issue instead of four [16,E]x[E,16]: same FLOPs, 4x
     the N-utilization. Callers unpack hi/lo planes OUTSIDE the kernel
     (_unpack_hist).

@@ -93,11 +93,12 @@ def wide():
 
 
 def _numpy_hist(bins, gh, rows):
-    out = np.zeros((2, F * 256))
-    flat = bins[rows].astype(np.int64) + np.arange(F) * 256
+    G = bins.shape[1]
+    out = np.zeros((2, G * 256))
+    flat = bins[rows].astype(np.int64) + np.arange(G) * 256
     for k in range(2):
         np.add.at(out[k], flat.ravel(),
-                  np.repeat(gh[k, rows].astype(np.float64), F))
+                  np.repeat(gh[k, rows].astype(np.float64), G))
     return out
 
 
@@ -148,6 +149,210 @@ def test_the_looped_group_decode_is_the_unrolled_one_bit_for_bit(wide,
                 jnp.asarray(pay), jnp.int32(133), jnp.int32(1500))])
     for a, b in zip(*out):
         assert a.tobytes() == b.tobytes()
+
+
+# -- the [64, E] operand is selected, not multiplied --------------------------
+
+BF16 = jnp.bfloat16
+# histogram payloads of a few hundred rows: 28 byte groups (unrolled, as
+# HIGGS), 67 (past HIST_UNROLL_MAX_GROUPS: looped over word rows, as
+# Criteo), and the Expo cell's sixteen widths, one of them a 4-bit slot
+HIST_WIDTHS = {"bytes28": [256] * 28, "looped67": [256] * 67,
+               "nibble16": [256, 256, 8, 13, 23, 32, 128, 128,
+                            28, 32, 36, 40, 44, 48, 52, 56]}
+HN, HNP, HC = 900, 2048, 256
+
+
+def _hist_plan(name):
+    widths = HIST_WIDTHS[name]
+    plan, nbw = gp._payload_plan(widths)
+    wpa = gp._payload_geometry(HN, nbw, len(widths))[0]
+    assert pg.hist_loops_groups(len(widths), plan) == (name == "looped67")
+    assert any(mk == 15 for _, _, mk in plan) == (name == "nibble16")
+    return widths, plan, nbw, wpa
+
+
+def _hist_kernel(kernel, name):
+    widths, plan, nbw, wpa = _hist_plan(name)
+    if kernel == "root_hist":
+        return pg.make_root_hist(wpa, HNP, len(widths), plan, nbw, HN, C=HC,
+                                 interpret=True)
+    return pg.make_seg_hist(wpa, HNP, len(widths), plan, nbw, C=HC,
+                            interpret=True)
+
+
+@pytest.mark.parametrize("name", list(HIST_WIDTHS))
+@pytest.mark.parametrize("kernel", ["root_hist", "seg_hist"])
+def test_no_bf16_multiply_builds_the_histogram_operand(kernel, name):
+    """The v5e has no bf16 VALU: a [16, E] bf16 product is two unpacks, two
+    f32 multiplies and a pack a vreg, 25 of the 38-46 VALU ops a (group,
+    lane tile) the kernels issued (PERF.md, PR 39). Each group's [64, E]
+    operand is a select of f32 rows under the lo mask, cast to bf16 once,
+    which Mosaic folds into the MXU push; the hi one-hot is cast from its
+    mask. Read off the kernel's own jaxpr, loop bodies included."""
+    from lightgbm_tpu.analysis.dataflow import iter_eqns
+    widths, plan, _, wpa = _hist_plan(name)
+    G = len(widths)
+    fn = _hist_kernel(kernel, name)
+    S = jax.ShapeDtypeStruct
+    args = [S((wpa, HNP), jnp.uint32)] + (
+        [] if kernel == "root_hist" else [S((), jnp.int32)] * 2)
+    calls = [e for e, _ in iter_eqns(jax.make_jaxpr(fn)(*args).jaxpr)
+             if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    E = HC if kernel == "root_hist" else HC + 128
+    # group bodies in the kernel's text: every group unrolled, or one
+    # sublane tile of 32 in the loop and the rest in the static tail
+    bodies = 32 + G % 32 if name == "looped67" else G
+    operand, one_hot, dots = [], [], []
+    for eqn, _ in iter_eqns(calls[0].params["jaxpr"]):
+        prim = eqn.primitive.name
+        if prim not in ("mul", "convert_element_type", "dot_general"):
+            continue
+        out = eqn.outvars[0].aval
+        if prim == "mul":
+            assert out.dtype != BF16, eqn
+        if prim == "convert_element_type" and out.dtype == BF16 \
+                and len(out.shape) == 2:
+            src = eqn.invars[0].aval.dtype
+            {(64, E): operand, (16, E): one_hot}[out.shape].append(src)
+        if prim == "dot_general":
+            dots.append(tuple(v.aval.shape for v in eqn.invars))
+    assert operand == [jnp.float32] * bodies, operand
+    assert one_hot == [jnp.bool_] * bodies, one_hot
+    assert dots == [((16, E), (64, E))] * bodies, dots
+
+
+def _multiply_form_hist(pay, plan, nbw, chunks, E):
+    """The contraction as the kernels built it before PR 39, in plain
+    jax.numpy: per chunk and group a bf16 hi one-hot against four [16, E]
+    bf16 PRODUCTS of the lo one-hot and a value row, f32 accumulation.
+    chunks: (first lane read, lane of the first row in it, rows)."""
+    n16 = jax.lax.broadcasted_iota(jnp.int32, (16, E), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, E), 1)[0]
+
+    @jax.jit
+    def chunk(hist, w, d, m):
+        w = jnp.roll(w, E - d, axis=1)
+        valid = (lane < m).astype(jnp.float32)
+        grad = jax.lax.bitcast_convert_type(w[nbw + 2], jnp.float32) * valid
+        hess = jax.lax.bitcast_convert_type(w[nbw + 3], jnp.float32) * valid
+        g_hi, h_hi = grad.astype(BF16), hess.astype(BF16)
+        vt = (g_hi, h_hi,
+              (grad - g_hi.astype(jnp.float32)).astype(BF16),
+              (hess - h_hi.astype(jnp.float32)).astype(BF16))
+        out = []
+        for g, (wr, sh, mk) in enumerate(plan):
+            b = ((w[wr] >> jnp.uint32(sh)) & jnp.uint32(mk)).astype(jnp.int32)
+            oh_hi = (n16 == (b >> 4)[None, :]).astype(BF16)
+            oh_lo = (n16 == (b & 15)[None, :]).astype(BF16)
+            bv = jnp.concatenate([oh_lo * v[None, :] for v in vt], axis=0)
+            out.append(hist[g] + jax.lax.dot_general(
+                oh_hi, bv, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32))
+        return jnp.stack(out), bv
+
+    hist = jnp.zeros((len(plan), 16, 64), jnp.float32)
+    for a, d, m in chunks:
+        hist, bv = chunk(hist, jnp.asarray(pay[:, a:a + E]), d, m)
+    planes = [np.asarray(p) for p in pg._unpack_hist(hist)]
+    return planes, np.asarray(bv).astype(np.float32)
+
+
+def _hist_payload(name, gh_of):
+    widths, plan, nbw, wpa = _hist_plan(name)
+    rng = np.random.default_rng(39)
+    bins = np.stack([rng.integers(0, w, HN) for w in widths],
+                    axis=1).astype(np.uint8)
+    pay = gp._pack_payload(bins, rng.integers(0, 2, HN), HN, wpa, HNP, nbw,
+                           0, HN, plan=plan)
+    gh = gh_of(rng)
+    pay[nbw + 2:nbw + 4, :HN] = gh.view(np.uint32)
+    # finite everywhere: the multiply form turns a masked-out infinity
+    # into NaN (0 x inf), the select into 0
+    pay[nbw + 2:nbw + 4, HN:] = np.float32(7.0).view(np.uint32)
+    return bins, gh, pay, plan, nbw
+
+
+def _both_signs_and_zeros(rng):
+    gh = rng.normal(size=(2, HN)).astype(np.float32)
+    gh[1] = np.abs(gh[1])
+    gh[:, rng.random(HN) < 0.2] = 0.0       # rows a bag left out: exactly 0
+    return gh
+
+
+def _chunks(kernel, start, length):
+    if kernel == "root_hist":
+        return [(i * HC, 0, HN - i * HC) for i in range(-(-HN // HC))], HC
+    ptrs = range(start, start + length, HC)
+    return [(p // 128 * 128, p % 128, min(HC, start + length - p))
+            for p in ptrs], HC + 128
+
+
+@pytest.mark.parametrize("name", list(HIST_WIDTHS))
+@pytest.mark.parametrize("kernel,start,length", [
+    ("root_hist", 0, HN), ("seg_hist", 133, 700), ("seg_hist", 256, 512)])
+def test_the_selected_operand_gives_the_multiplied_ones_histogram(
+        kernel, start, length, name):
+    """Planes equal, value for value, to the multiply form's: byte and
+    nibble slots, the unrolled and the looped group loop, a segment that
+    starts inside a lane tile and one on a boundary, gradients of both
+    signs and rows of exactly 0.0."""
+    bins, gh, pay, plan, nbw = _hist_payload(name, _both_signs_and_zeros)
+    fn = _hist_kernel(kernel, name)
+    got = fn(jnp.asarray(pay)) if kernel == "root_hist" else fn(
+        jnp.asarray(pay), jnp.int32(start), jnp.int32(length))
+    chunks, E = _chunks(kernel, start, length)
+    want, _ = _multiply_form_hist(pay, plan, nbw, chunks, E)
+    for a, b in zip(got, want):
+        assert np.array_equal(np.asarray(a), b)
+    # and they are the rows' own sums, bin for bin
+    _close(got, _numpy_hist(bins, gh, np.arange(start, start + length)))
+
+
+def test_the_two_operands_differ_in_the_sign_of_zero_alone():
+    """0 x -v is -0.0 and a select gives +0.0: with every gradient
+    negative the multiply form's [64, E] operand holds -0.0 wherever the lo
+    mask is off, the selected one +0.0, and nothing else differs. A sum
+    takes no notice of either zero unless every term is one, so the planes
+    can differ in bits only where no row of the segment lies (an all-zero
+    bin), and there in the sign alone."""
+    def negative(rng):
+        return -np.abs(rng.normal(size=(2, HN))).astype(np.float32) - 0.5
+    # from a tile boundary to the last row: every lane outside the segment
+    # holds the filler, and the masked value there is +0.0 whether the
+    # valid mask multiplies (the chip) or XLA makes a select of it (here)
+    name, start, length = "bytes28", 128, HN - 128
+    bins, _, pay, plan, nbw = _hist_payload(name, negative)
+    got = _hist_kernel("seg_hist", name)(
+        jnp.asarray(pay), jnp.int32(start), jnp.int32(length))
+    chunks, E = _chunks("seg_hist", start, length)
+    want, bv_mul = _multiply_form_hist(pay, plan, nbw, chunks, E)
+    # the last chunk's operand of the last group, both ways
+    a, d, m = chunks[-1]
+    w = np.roll(pay[:, a:a + E], E - d, axis=1)
+    wr, sh, mk = plan[-1]
+    lo = ((w[wr] >> sh) & mk & 15)[None, :] == np.arange(16)[:, None]
+    valid = (np.arange(E) < m).astype(np.float32)
+    vt = pg._hist_values(*(jnp.asarray(w[r].view(np.float32) * valid)
+                           for r in (nbw + 2, nbw + 3)))
+    bv_sel = np.concatenate([np.where(lo, np.asarray(v)[None, :],
+                                      np.float32(0.0)) for v in vt], axis=0)
+    assert np.array_equal(bv_sel, bv_mul)
+    differ = bv_sel.view(np.uint32) != bv_mul.view(np.uint32)
+    assert differ.any() and not bv_mul[differ].any()
+    assert np.signbit(bv_mul[differ]).all()
+    assert not np.signbit(bv_sel[differ]).any()
+    # the planes: equal values; bits differ, if anywhere, in empty bins
+    occupied = np.zeros(len(plan) * 256, bool)
+    occupied[(bins[start:start + length].astype(np.int64)
+              + np.arange(len(plan)) * 256).ravel()] = True
+    for p, q in zip(got, want):
+        p = np.asarray(p)
+        assert np.array_equal(p, q)
+        bits = p.view(np.uint32) != q.view(np.uint32)
+        assert not (bits & occupied).any()
+        assert not p[bits].any() and not q[bits].any()
 
 
 @pytest.mark.parametrize("feature,thr", [(0, 30), (519, 5), (261, 61)])
