@@ -85,6 +85,9 @@ class GBDT:
         self._tpu_predictors: Dict[tuple, object] = {}
 
     # ------------------------------------------------------------------
+    # run record: the learner (the binned layout onto the device, under a
+    # span of its own), the score updater and the bag state
+    @telemetry.timed("boosting::Init", category="setup", always=True)
     def init(self, config: Config, train_data, objective,
              training_metrics=()) -> None:
         telemetry.configure_from_config(config)

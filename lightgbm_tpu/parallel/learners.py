@@ -278,71 +278,76 @@ class DataParallelTreeLearner(SerialTreeLearner):
         health = self._persist_health_mode()
         gkey = ("grower_sharded", S, gc, stat_from_scan, kernel_impl,
                 level_mode, health, self.hist_quant, self.comm_overlap)
-        wrapper = cache.get(gkey)
-        if wrapper is None:
-            inner = make_persist_grower(
-                assets, self.meta, gc, interpret=interpret, axis_name=AXIS,
-                kernel_impl=kernel_impl, stat_from_scan=stat_from_scan,
-                fix=self.fix, level_mode=level_mode, health=health,
-                quant=self.hist_quant, comm_overlap=self.comm_overlap,
-                # GLOBAL counts live in the leaf state: the total row
-                # count decides whether they need i32, not the shard's
-                large_counts=self.dataset.num_data >= EXACT_F32_ROWS)
-
-            class _ShardedGrower:
-                pass
-
-            wrapper = _ShardedGrower()
-            wrapper.inner = inner
-            # surface the comm-accounting facts the flush-time wire-byte
-            # telemetry reads (treelearner/serial.flush_level_stats);
-            # K included — the pending-tree tally multiplies by it
-            wrapper.K = inner.K
-            wrapper.axis_name = AXIS
-            wrapper.quant = inner.quant
-            wrapper.voting = inner.voting
-            wrapper.comm_overlap = inner.comm_overlap
-            wrapper.wire_bytes_model = inner.wire_bytes_model
-            wrapper.reduced_feature_frac = inner.reduced_feature_frac
-            # and the mechanisms the run record counts trees by
-            # (treelearner/serial._count_persist_trees)
-            wrapper.block_scan = inner.block_scan
-            wrapper.inpass_hist = inner.inpass_hist
-            wrapper.wide_payload = inner.wide_payload
-            wrapper.large_counts = inner.large_counts
-            wrapper.num_shards = S
-            wrapper.init_carry = jax.jit(jax.shard_map(
-                inner.init_carry, mesh=mesh,
-                in_specs=(pay_spec, P(AXIS)), out_specs=pay_spec,
-                check_vma=False))
-            wrapper.finalize_scores = jax.jit(jax.shard_map(
-                inner.finalize_scores, mesh=mesh,
-                in_specs=(pay_spec,), out_specs=P(AXIS),
-                check_vma=False))
-            cache[gkey] = wrapper
         dkey = ("driver_sharded", S, k, gc, objective.static_fingerprint(),
                 bag_spec, kernel_impl, level_mode, health,
                 self.hist_quant, self.comm_overlap)
-        driver = cache.get(dkey)
-        if driver is None:
-            bag_fn = (make_bag_transform(bag_spec, assets.geometry,
-                                         axis_name=AXIS, num_shards=S)
-                      if stat_from_scan else None)
-            raw = make_scan_driver(wrapper.inner, gc, k,
-                                   objective.payload_grad_fn(),
-                                   wrap_jit=False, bag_fn=bag_fn)
-            smapped = jax.shard_map(
-                raw, mesh=mesh,
-                in_specs=(pay_spec, P(), P(), P(), P(), P(), P()),
-                out_specs=(pay_spec,
-                           _tree_arrays_spec(gc, row_sharded=False),
-                           P()),
-                check_vma=False)
-            driver = telemetry.launch_wrapper(
-                jax.jit(smapped, donate_argnums=(0,)),
-                "collective::persist_scan(launch)", category="collective",
-                always=True, shards=S, mode=gc.parallel_mode, k=k)
-            cache[dkey] = driver
+        wrapper, driver = cache.get(gkey), cache.get(dkey)
+        if wrapper is not None and driver is not None:
+            return assets, wrapper, driver
+        # run record: the grower, the two jit(shard_map) wrappers and the
+        # driver, under the serial path's name so that one metric reads both
+        with telemetry.scope("tree_learner::PersistBuild(trace)",
+                             category="setup", always=True):
+            if wrapper is None:
+                inner = make_persist_grower(
+                    assets, self.meta, gc, interpret=interpret, axis_name=AXIS,
+                    kernel_impl=kernel_impl, stat_from_scan=stat_from_scan,
+                    fix=self.fix, level_mode=level_mode, health=health,
+                    quant=self.hist_quant, comm_overlap=self.comm_overlap,
+                    # GLOBAL counts live in the leaf state: the total row
+                    # count decides whether they need i32, not the shard's
+                    large_counts=self.dataset.num_data >= EXACT_F32_ROWS)
+
+                class _ShardedGrower:
+                    pass
+
+                wrapper = _ShardedGrower()
+                wrapper.inner = inner
+                # surface the comm-accounting facts the flush-time wire-byte
+                # telemetry reads (treelearner/serial.flush_level_stats);
+                # K included — the pending-tree tally multiplies by it
+                wrapper.K = inner.K
+                wrapper.axis_name = AXIS
+                wrapper.quant = inner.quant
+                wrapper.voting = inner.voting
+                wrapper.comm_overlap = inner.comm_overlap
+                wrapper.wire_bytes_model = inner.wire_bytes_model
+                wrapper.reduced_feature_frac = inner.reduced_feature_frac
+                # and the mechanisms the run record counts trees by
+                # (treelearner/serial._count_persist_trees)
+                wrapper.block_scan = inner.block_scan
+                wrapper.inpass_hist = inner.inpass_hist
+                wrapper.wide_payload = inner.wide_payload
+                wrapper.large_counts = inner.large_counts
+                wrapper.num_shards = S
+                wrapper.init_carry = jax.jit(jax.shard_map(
+                    inner.init_carry, mesh=mesh,
+                    in_specs=(pay_spec, P(AXIS)), out_specs=pay_spec,
+                    check_vma=False))
+                wrapper.finalize_scores = jax.jit(jax.shard_map(
+                    inner.finalize_scores, mesh=mesh,
+                    in_specs=(pay_spec,), out_specs=P(AXIS),
+                    check_vma=False))
+                cache[gkey] = wrapper
+            if driver is None:
+                bag_fn = (make_bag_transform(bag_spec, assets.geometry,
+                                             axis_name=AXIS, num_shards=S)
+                          if stat_from_scan else None)
+                raw = make_scan_driver(wrapper.inner, gc, k,
+                                       objective.payload_grad_fn(),
+                                       wrap_jit=False, bag_fn=bag_fn)
+                smapped = jax.shard_map(
+                    raw, mesh=mesh,
+                    in_specs=(pay_spec, P(), P(), P(), P(), P(), P()),
+                    out_specs=(pay_spec,
+                               _tree_arrays_spec(gc, row_sharded=False),
+                               P()),
+                    check_vma=False)
+                driver = telemetry.launch_wrapper(
+                    jax.jit(smapped, donate_argnums=(0,)),
+                    "collective::persist_scan(launch)", category="collective",
+                    always=True, shards=S, mode=gc.parallel_mode, k=k)
+                cache[dkey] = driver
         return assets, wrapper, driver
 
 

@@ -18,10 +18,13 @@ Three modes:
     dispatches, compile events). Each such span accumulates seconds, self
     seconds and hits like any other and leaves one entry in a bounded ring
     (:data:`RING_EVENTS`, :func:`ring_snapshot`) carrying the identifiers
-    of the ``engine.train`` call and of the fused launch it belongs to.
-    Every other span is a no-op behind one int compare, nothing prints at
-    exit, nothing blocks (no ``block_until_ready``, ``sync_value`` and
-    :func:`device_wait` do nothing) and no compiled program changes.
+    of the ``engine.train`` call and of the fused launch it belongs to,
+    and what each local device's HBM allocator read when the span opened
+    and closed (:func:`device_memory_stats`; absent where the backend
+    keeps no such statistics). Every other span is a no-op behind one int
+    compare, nothing prints at exit, nothing blocks (no
+    ``block_until_ready``, ``sync_value`` and :func:`device_wait` do
+    nothing) and no compiled program changes.
   * ``TIMERS`` — every span: per-name accumulated seconds + hit counts
     (the TIMETAG-style report), no per-event storage beyond the ring.
   * ``TRACE``  — plus a bounded in-memory timeline of span events
@@ -50,6 +53,7 @@ from collections import defaultdict, deque
 from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
+from jax._src import xla_bridge
 
 OFF, TIMERS, TRACE = 0, 1, 2
 _MODE_NAMES = {"off": OFF, "timers": TIMERS, "trace": TRACE,
@@ -258,6 +262,36 @@ def clear_counts_prefix(prefixes) -> None:
             _count_cat.pop(k, None)
 
 
+def device_memory_stats() -> Optional[List[Dict[str, int]]]:
+    """``bytes_in_use`` / ``peak_bytes_in_use`` / ``bytes_limit`` of every
+    local device's allocator, one dict a device, or None where the backend
+    keeps no such statistics (the CPU). Blocks on nothing: it is the
+    allocator's state now, and PJRT allocates a program's outputs and
+    temporaries when the program is dispatched."""
+    out = []
+    for dev in jax.local_devices():
+        stats = dev.memory_stats()
+        if not stats:
+            return None
+        out.append({key: int(stats[key]) for key in
+                    ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")
+                    if key in stats})
+    return out
+
+
+def _hbm_now() -> Optional[List[List[int]]]:
+    """[bytes_in_use, peak_bytes_in_use] a local device, or None. Never the
+    call that starts the backend: a ``Dataset.construct`` before any device
+    use must not initialise the chip from inside ``io::Construct``."""
+    if not xla_bridge.backends_are_initialized():
+        return None
+    stats = device_memory_stats()
+    if not stats:
+        return None
+    return [[s.get("bytes_in_use", 0), s.get("peak_bytes_in_use", 0)]
+            for s in stats]
+
+
 def _stack() -> list:
     st = getattr(_tls, "stack", None)
     if st is None:
@@ -320,7 +354,7 @@ def _span_ids(st: list, tags: dict) -> Tuple[int, Optional[int]]:
 def _record_span(name: str, category: str, t0: float, elapsed: float,
                  self_s: float, parent: Optional[str], train: int,
                  launch: Optional[int], tags: Optional[dict],
-                 always: bool) -> None:
+                 always: bool, hbm: Optional[dict] = None) -> None:
     """Fold one finished span into the tables, the ring (`always` spans)
     and the TRACE timeline."""
     global _dropped
@@ -340,6 +374,8 @@ def _record_span(name: str, category: str, t0: float, elapsed: float,
             ev["parent"] = parent
         if tags:
             ev["args"] = tags
+        if hbm is not None:
+            ev["hbm"] = hbm
         if always:
             _ring.append(ev)
         if _mode == TRACE:
@@ -349,14 +385,43 @@ def _record_span(name: str, category: str, t0: float, elapsed: float,
                 _dropped += 1
 
 
+def _hand_up_rise(st: list, rise: Optional[List[int]]) -> None:
+    """Add a finished span's rise of the peak to what the children of the
+    enclosing span have raised it by."""
+    if st and rise is not None:
+        up = st[-1][4]
+        st[-1][4] = rise if up is None else [a + b for a, b in zip(up, rise)]
+
+
+def _hbm_closed(opened: List[List[int]], kids: Optional[List[int]],
+                st: list) -> Optional[dict]:
+    """The ``hbm`` field of a ring entry: the allocator's reading a local
+    device when the span opened and now that it closes, and ``rise``, what
+    the span itself raised each device's peak by. ``peak_bytes_in_use`` is
+    a watermark over the life of the process, so a span's own rise is its
+    close less its open less its child spans' rises, the rule of self
+    seconds."""
+    closed = _hbm_now()
+    if closed is None:
+        _hand_up_rise(st, kids)
+        return None
+    rise = [c[1] - o[1] for o, c in zip(opened, closed)]
+    _hand_up_rise(st, rise)
+    if kids is not None:
+        rise = [r - k for r, k in zip(rise, kids)]
+    return {"open": opened, "close": closed, "rise": rise}
+
+
 @contextlib.contextmanager
 def scope(name: str, category: str = "misc", sync_value=None,
           always: bool = False, **tags):
     """Accumulate the wall time of the enclosed block under `name`.
 
     ``always=True`` puts the span in the run record: it is recorded with
-    telemetry OFF too and leaves a ring entry. Only for spans that occur
-    O(1) times per ``lgb.train`` or per fused launch.
+    telemetry OFF too and leaves a ring entry, which carries the HBM
+    allocator's reading at both ends (``hbm``, see :func:`_hbm_closed`).
+    Only for spans that occur O(1) times per ``lgb.train`` or per fused
+    launch.
 
     When `sync_value` is a callable, it is invoked on exit and its result
     passed to jax.block_until_ready before the clock stops — use for
@@ -370,11 +435,13 @@ def scope(name: str, category: str = "misc", sync_value=None,
     st = _stack()
     parent = st[-1][0] if st else None
     train, launch = _span_ids(st, tags)
-    # [name, accumulated child-span seconds, train, launch]
-    st.append([name, 0.0, train, launch])
+    # [name, accumulated child-span seconds, train, launch,
+    #  accumulated child-span rise of the HBM peak a device]
+    st.append([name, 0.0, train, launch, None])
     note = {"train": train} if launch is None \
         else {"train": train, "launch": launch}
     t0 = time.perf_counter()
+    hbm_open = _hbm_now() if always else None
     try:
         with jax.profiler.TraceAnnotation("lgbm:" + name, **note):
             yield
@@ -384,13 +451,18 @@ def scope(name: str, category: str = "misc", sync_value=None,
                 jax.block_until_ready(sync_value())
             except Exception:
                 pass
-        t1 = time.perf_counter()
         entry = st.pop()
+        if hbm_open is None:
+            hbm = None
+            _hand_up_rise(st, entry[4])
+        else:
+            hbm = _hbm_closed(hbm_open, entry[4], st)
+        t1 = time.perf_counter()
         elapsed = t1 - t0
         if st:
             st[-1][1] += elapsed
         _record_span(name, category, t0, elapsed, elapsed - entry[1],
-                     parent, train, launch, tags or None, always)
+                     parent, train, launch, tags or None, always, hbm)
         # same single-snapshot discipline as count(): never two reads
         # of the global sink around a call
         sink = _flight_span       # guarded-by: GIL

@@ -15,7 +15,8 @@ dict per boosting iteration:
   * ``trees_materialized`` / ``last_num_leaves`` — model growth (pending
     async trees show up once a sync point materializes them);
   * ``compiles`` — XLA backend recompiles observed during the iteration;
-  * ``memory`` — ``device.memory_stats()`` bytes_in_use / peak watermark
+  * ``memory`` — every local device's ``memory_stats()`` bytes_in_use /
+    peak watermark, one dict a device (``events.device_memory_stats``),
     when the backend reports them (TPU does; CPU returns nothing).
 
 Attach it explicitly via ``callbacks=[TrainingMonitor()]`` or let
@@ -29,23 +30,6 @@ import time
 from typing import Dict, List, Optional
 
 from . import events
-
-
-def device_memory_stats() -> Optional[Dict[str, int]]:
-    """bytes_in_use / peak_bytes_in_use of device 0, or None when the
-    backend has no allocator stats (CPU)."""
-    try:
-        import jax
-        stats = jax.local_devices()[0].memory_stats()
-    except Exception:
-        return None
-    if not stats:
-        return None
-    out = {}
-    for key in ("bytes_in_use", "peak_bytes_in_use", "bytes_limit"):
-        if key in stats:
-            out[key] = int(stats[key])
-    return out or None
 
 
 class TrainingMonitor:
@@ -100,7 +84,7 @@ class TrainingMonitor:
                "trees_materialized": trees, "compiles": compiles}
         if leaves is not None:
             rec["last_num_leaves"] = int(leaves)
-        mem = device_memory_stats()
+        mem = events.device_memory_stats()
         if mem is not None:
             rec["memory"] = mem
         if evals:

@@ -304,7 +304,11 @@ class SerialTreeLearner:
     def __init__(self, config: Config, dataset):
         self.config = config
         self.dataset = dataset
-        self.layout, self.meta = dataset.to_device(config)
+        # run record: the dispatch's wall and the bytes it leaves on the
+        # device; not blocked, the payload pack may overlap the copy
+        with telemetry.scope("tree_learner::ToDevice(layout H2D)",
+                             category="setup", always=True):
+            self.layout, self.meta = dataset.to_device(config)
         self.fix = dataset.fix_info()
         self.params = SplitParams.from_config(config)
         cat_bins = dataset.bin_end[dataset.is_categorical] - \
@@ -624,10 +628,6 @@ class SerialTreeLearner:
                                          fix=self.fix,
                                          level_mode=level_mode,
                                          health=health)
-                if assets.efb[5]:          # bundled: block-scan fast path
-                    telemetry.count(
-                        "tree_learner::persist_bundle_blockscan",
-                        category="tree_learner")
                 cache[gkey] = gr
             if driver is None:
                 bag_fn = (make_bag_transform(bag_spec, assets.geometry)

@@ -175,6 +175,49 @@ def test_sharded_persist_agrees_with_the_plain_reference(shards):
     assert spans and spans[-1]["dur"] > 0
 
 
+def test_run_record_of_a_sharded_train():
+    """The sharded path's set-up under the serial path's span names, as
+    tests/test_telemetry.py::test_run_record_of_an_off_mode_train holds
+    the serial path to: the learner and the layout before any launch, the
+    grower, the shard_map wrappers and the driver built inside launch 0;
+    the dispatch keeps its own name; parents form one tree."""
+    X, y = _data()
+    _train(X, y, tree_learner="data", tpu_num_devices=4)
+    ring = telemetry.ring_snapshot()
+    root = [e for e in ring if e["name"] == "engine::train"][-1]
+    ours = [e for e in ring if e["train"] == root["train"]]
+    by_name = {}
+    for e in ours:
+        by_name.setdefault(e["name"], []).append(e)
+    container = "boosting::TrainMultiIterFast(launch)"
+    for name in ("boosting::Init", "tree_learner::ToDevice(layout H2D)",
+                 "tree_learner::PersistBuild(trace)",
+                 "tree_learner::ShardPayload(device_put)",
+                 "collective::persist_scan(launch)", container):
+        assert len(by_name[name]) == 1, (name, sorted(by_name))
+    assert "ops::persist_scan(launch)" not in by_name
+    init, put, build, launch = (by_name[n][0] for n in (
+        "boosting::Init", "tree_learner::ToDevice(layout H2D)",
+        "tree_learner::PersistBuild(trace)",
+        "collective::persist_scan(launch)"))
+    assert "launch" not in init and "launch" not in put
+    assert init["parent"] == "engine::train"
+    assert put["parent"] == "boosting::Init"
+    assert build["launch"] == 0 and build["parent"] == container
+    assert launch["launch"] == 0 and launch["parent"] == container
+    assert by_name[container][0]["parent"] == "engine::train"
+    assert {init["cat"], put["cat"], build["cat"]} == {"setup"}
+    # the build closes before the dispatch opens, and both lie in launch 0
+    assert build["ts"] + build["dur"] <= launch["ts"] + 1e-3
+    names = set(by_name)
+    for e in ours:
+        if e["name"] != "engine::train":
+            assert e["parent"] in names, e
+            assert e["ts"] >= root["ts"] - 1e-3
+            assert e["ts"] + e["dur"] <= root["ts"] + root["dur"] + 1e-3
+    assert not [e for e in ours if "hbm" in e]      # the CPU keeps none
+
+
 # ---------------------------------------------------------------------------
 # the state past 2^24 rows, forced small: Mosaic kernels in the interpreter
 # ---------------------------------------------------------------------------

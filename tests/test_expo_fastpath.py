@@ -23,8 +23,13 @@ def _expo_small(n=6144):
     return X, y
 
 
-@pytest.mark.slow  # persist-driver compile (XLA kernel emulation)
-def test_expo_bundle_fast_path_engages_and_matches_v1():
+@pytest.mark.slow  # persist-driver compile (Mosaic kernels, interpreted)
+def test_expo_bundle_fast_path_engages_and_matches_v1(monkeypatch):
+    # off the TPU the persist path's XLA emulation scans the flat layout;
+    # the block scan is the Mosaic kernels' (tests/test_expo_config.py)
+    from lightgbm_tpu.treelearner.serial import SerialTreeLearner
+    monkeypatch.setattr(SerialTreeLearner, "_persist_kernel_mode",
+                        staticmethod(lambda: ("pallas", True)))
     X, y = _expo_small()
     base = {"objective": "binary", "num_leaves": 15, "verbosity": -1,
             "min_data_in_leaf": 10, "max_bin": 63, "learning_rate": 0.2}
@@ -44,8 +49,7 @@ def test_expo_bundle_fast_path_engages_and_matches_v1():
     # the telemetry counters prove WHICH path trained: all 16 trees on the
     # persist driver, the bundle block-scan grower built, zero v1 trees
     assert counts.get("tree_learner::persist_scan_trees", 0) >= 16, counts
-    assert counts.get("tree_learner::persist_bundle_blockscan", 0) >= 1, \
-        counts
+    assert counts.get("tree_learner::blockscan_trees", 0) >= 16, counts
     assert counts.get("tree_learner::v1_grow_trees", 0) == 0, counts
 
     bst_v1 = lgb.train({**base, "tpu_persist_scan": "off"},

@@ -195,8 +195,18 @@ def test_run_record_of_an_off_mode_train():
     for e in ring:
         by_name.setdefault(e["name"], []).append(e)
     for name in ("engine::train", "ops::BuildPersistPayload(pack)",
-                 "tree_learner::InitCarry(H2D launch)"):
+                 "tree_learner::InitCarry(H2D launch)", "boosting::Init",
+                 "tree_learner::ToDevice(layout H2D)"):
         assert len(by_name[name]) == 1, (name, sorted(by_name))
+    # the learner is built, and the layout copied, before any launch
+    init, put = (by_name[n][0] for n in
+                 ("boosting::Init", "tree_learner::ToDevice(layout H2D)"))
+    assert "launch" not in init and "launch" not in put
+    assert init["parent"] == "engine::train"
+    assert put["parent"] == "boosting::Init"
+    assert init["cat"] == put["cat"] == "setup"
+    # the CPU backend keeps no allocator statistics: absent, not zero
+    assert not [e for e in ring if "hbm" in e]
     train = by_name["engine::train"][0]["train"]
     assert train >= 1
     ours = [e for e in ring if not e["name"].startswith("jax::")]
@@ -278,6 +288,139 @@ def test_compile_event_is_a_child_of_the_open_span():
     for e in comp:
         assert holder["ts"] <= e["ts"] + 1e-3
         assert e["ts"] + e["dur"] <= holder["ts"] + holder["dur"] + 1e-3
+
+
+class _Allocator:
+    """A scripted HBM allocator in ``events.device_memory_stats``'s place:
+    every reading is the state left by the ``alloc`` / ``free`` calls so
+    far, one dict a device, and ``reads`` counts the readings."""
+
+    def __init__(self, devices=2):
+        self.in_use = [0] * devices
+        self.peak = [0] * devices
+        self.reads = 0
+
+    def alloc(self, dev, nbytes):
+        self.in_use[dev] += nbytes
+        self.peak[dev] = max(self.peak[dev], self.in_use[dev])
+
+    def free(self, dev, nbytes):
+        self.in_use[dev] -= nbytes
+
+    def __call__(self):
+        self.reads += 1
+        return [{"bytes_in_use": b, "peak_bytes_in_use": p,
+                 "bytes_limit": 1 << 34}
+                for b, p in zip(self.in_use, self.peak)]
+
+
+@pytest.fixture
+def allocator(monkeypatch):
+    script = _Allocator()
+    monkeypatch.setattr(events, "device_memory_stats", script)
+    monkeypatch.setattr(events.xla_bridge, "backends_are_initialized",
+                        lambda: True)
+    return script
+
+
+@pytest.mark.parametrize("mode", ["off", "timers", "trace"])
+def test_ring_entry_carries_the_allocators_reading(allocator, mode):
+    """A span of the run record reads every local device's allocator when
+    it opens and when it closes, in every mode (``off`` records them as it
+    records the spans); what a span itself raised the peak by is its rise
+    less its children's, the rule of self seconds. A span that is not of
+    the run record reads nothing and hands its children's rise up."""
+    if mode != "off":
+        events.enable(mode)
+    allocator.alloc(0, 100)                     # before any span
+    with events.scope("outer", category="setup", always=True):
+        allocator.alloc(0, 50)                  # outer's own: peak 150
+        allocator.free(0, 50)
+        with events.scope("between", category="misc"):
+            with events.scope("inner", category="setup", always=True):
+                allocator.alloc(0, 400)         # inner's: peak 500
+                allocator.alloc(1, 7)
+                allocator.free(0, 300)
+        allocator.alloc(0, 350)                 # outer's own: peak 550
+    by_name = {e["name"]: e for e in events.ring_snapshot()}
+    assert sorted(by_name) == ["inner", "outer"]
+    assert by_name["inner"]["hbm"] == {
+        "open": [[100, 150], [0, 0]], "close": [[200, 500], [7, 7]],
+        "rise": [350, 7]}
+    assert by_name["outer"]["hbm"] == {
+        "open": [[100, 100], [0, 0]], "close": [[550, 550], [7, 7]],
+        "rise": [100, 0]}
+    assert allocator.reads == 4                 # two a recorded span
+    if mode == "trace":
+        timeline = {e["name"]: e for e in events.events_snapshot()}
+        assert "hbm" not in timeline["between"]
+        assert timeline["inner"]["hbm"] == by_name["inner"]["hbm"]
+
+
+def test_jax_entries_carry_no_reading(allocator):
+    """Hundreds of ``jax::`` events a set-up, none allocates on the
+    device: they are recorded without a reading, and none is taken."""
+    import jax
+    import jax.numpy as jnp
+    x = jnp.arange(5.0)
+    events.reset()
+    with events.scope("holder", category="setup", always=True):
+        jax.block_until_ready(jax.jit(lambda v: jnp.tanh(v) * 1.75 - 3)(x))
+    ring = events.ring_snapshot()
+    compiles = [e for e in ring if e["name"].startswith("jax::")]
+    assert compiles and not [e for e in compiles if "hbm" in e]
+    assert [e["name"] for e in ring if "hbm" in e] == ["holder"]
+    assert allocator.reads == 2
+
+
+def test_reading_is_not_what_starts_the_backend(monkeypatch):
+    """Before a backend exists the helper is not called at all: asking
+    the devices is what would initialise the chip."""
+    def helper():
+        raise AssertionError("read before a backend exists")
+    monkeypatch.setattr(events, "device_memory_stats", helper)
+    monkeypatch.setattr(events.xla_bridge, "backends_are_initialized",
+                        lambda: False)
+    with events.scope("early", category="io", always=True):
+        pass
+    (entry,) = events.ring_snapshot()
+    assert entry["name"] == "early" and "hbm" not in entry
+
+
+def test_construct_alone_starts_no_backend():
+    """``Dataset.construct`` in a fresh process records its spans, reads
+    no allocator and leaves JAX's backends uninitialised."""
+    import os
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(lgb.__file__)))
+    code = (
+        "import numpy as np\n"
+        "import lightgbm_tpu as lgb\n"
+        "from lightgbm_tpu import telemetry\n"
+        "from jax._src import xla_bridge\n"
+        "X = np.random.default_rng(0).normal(size=(500, 4))\n"
+        "lgb.Dataset(X, (X[:, 0] > 0).astype(float)).construct()\n"
+        "ring = telemetry.ring_snapshot()\n"
+        "assert [e for e in ring if e['name'] == 'io::Construct'], ring\n"
+        "assert not [e for e in ring if 'hbm' in e], ring\n"
+        "assert not xla_bridge.backends_are_initialized()\n"
+        "print('construct-ok')\n")
+    done = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                          text=True, timeout=300, cwd=root)
+    assert done.returncode == 0, done.stderr[-2000:]
+    assert "construct-ok" in done.stdout
+
+
+def test_monitor_memory_is_every_local_device(allocator):
+    """TrainingMonitor's ``memory`` field is the same helper's reading:
+    one dict a local device, not device 0 alone."""
+    from lightgbm_tpu.telemetry.monitor import TrainingMonitor
+    events.enable("timers")
+    allocator.alloc(1, 64)
+    rec = TrainingMonitor().record(0)
+    assert [m["peak_bytes_in_use"] for m in rec["memory"]] == [0, 64]
+    assert not hasattr(telemetry.monitor, "device_memory_stats")
 
 
 def test_fast_path_counters_readable_with_telemetry_off():
