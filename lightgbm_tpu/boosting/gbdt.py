@@ -85,8 +85,8 @@ class GBDT:
         self._tpu_predictors: Dict[tuple, object] = {}
 
     # ------------------------------------------------------------------
-    # run record: the learner (the binned layout onto the device, under a
-    # span of its own), the score updater and the bag state
+    # run record: the learner (the per-feature tables onto the device,
+    # under a span of its own), the score updater and the bag state
     @telemetry.timed("boosting::Init", category="setup", always=True)
     def init(self, config: Config, train_data, objective,
              training_metrics=()) -> None:

@@ -885,6 +885,8 @@ class BinnedDataset:
             self._mm_scan_cache = {}
         if hasattr(self, "_device_layout_cache"):
             self._device_layout_cache = {}
+        if hasattr(self, "_device_meta_cache"):
+            self._device_meta_cache = {}
         self._group_default_cache = None
 
     # ------------------------------------------------------------------
@@ -1192,46 +1194,66 @@ class BinnedDataset:
         mask = np.where(widths <= 16, 15, 0x7FFFFFFF).astype(np.int32)
         return storage_of, shift, sc, mask
 
-    def to_device(self, config: Config):
-        """Produce (DataLayout, FeatureMeta) jnp structures. Sets
-        self.device_packed for the learner's GrowConfig.
+    @staticmethod
+    def _device_key(config: Config):
+        # the only config knobs the device layout depends on
+        return (str(getattr(config, "tpu_multival", "auto")).lower(),
+                bool(config.tpu_4bit_packing))
 
-        Cached per (tpu_multival, tpu_4bit_packing) — the only config
-        knobs the layout depends on — so B boosters sweeping over one
-        Dataset share a single HBM-resident copy of the binned matrix
-        instead of re-uploading it per member."""
-        key = (str(getattr(config, "tpu_multival", "auto")).lower(),
-               bool(config.tpu_4bit_packing))
+    def device_meta(self, config: Config):
+        """The host part of the device layout: the tpu_multival=force
+        conversion, the nibble-packing plan (sets self.device_packed for
+        the learner's GrowConfig) and the per-feature FeatureMeta, a few KB
+        on the device. The binned rows stay on the host.
+
+        Cached per (tpu_multival, tpu_4bit_packing), as to_device."""
+        key = self._device_key(config)
+        cache = getattr(self, "_device_meta_cache", None)
+        if cache is None:
+            cache = self._device_meta_cache = {}
+        hit = cache.get(key)
+        if hit is None:
+            if (not self.is_multival and self.binned is not None
+                    and key[0] == "force"):
+                self.to_multival()
+            plan = None if self.is_multival else self.device_pack_plan(config)
+            # sentinel bins (bundled group bin 0) belong to no feature; they
+            # are assigned feature 0, which is safe: they lie outside every
+            # feature's [bin_start, bin_end) so the scan's range masks
+            # exclude them.
+            owner = np.full(self.total_bins, -1, dtype=np.int32)
+            for i in range(self.num_features):
+                owner[self.bin_start[i]:self.bin_end[i]] = i
+            feat_id = np.where(owner < 0, 0, owner).astype(np.int32)
+            hit = cache[key] = (self._feature_meta(feat_id), plan)
+        self.device_packed = hit[1] is not None
+        return hit[0]
+
+    def to_device(self, config: Config):
+        """Produce (DataLayout, FeatureMeta) jnp structures: the binned rows
+        on the device, for the v1 growers, and device_meta(config).
+
+        Cached per (tpu_multival, tpu_4bit_packing) so B boosters sweeping
+        over one Dataset share a single HBM-resident copy of the binned
+        matrix instead of re-uploading it per member."""
+        meta = self.device_meta(config)
+        key = self._device_key(config)
         cache = getattr(self, "_device_layout_cache", None)
         if cache is None:
             cache = self._device_layout_cache = {}
-        hit = cache.get(key)
-        if hit is not None:
-            self.device_packed = hit[2]
-            return hit[0], hit[1]
-        layout, meta = self._build_device_layout(config)
-        cache[key] = (layout, meta, self.device_packed)
+        layout = cache.get(key)
+        if layout is None:
+            telemetry_events.count("tree_learner::layout_placements",
+                                   category="tree_learner")
+            layout = cache[key] = self._build_device_layout(
+                self._device_meta_cache[key][1])
         return layout, meta
 
-    def _build_device_layout(self, config: Config):
+    def _build_device_layout(self, plan):
         import jax.numpy as jnp
         from ..ops.grow import DataLayout
-        from ..ops.split import FeatureMeta
-        # sentinel bins (bundled group bin 0) belong to no feature; they are
-        # assigned feature 0, which is safe: they lie outside every feature's
-        # [bin_start, bin_end) so the scan's range masks exclude them.
-        owner = np.full(self.total_bins, -1, dtype=np.int32)
-        for i in range(self.num_features):
-            owner[self.bin_start[i]:self.bin_end[i]] = i
-        feat_id = np.where(owner < 0, 0, owner).astype(np.int32)
-
-        if (not self.is_multival and self.binned is not None
-                and str(getattr(config, "tpu_multival", "auto")).lower()
-                == "force"):
-            self.to_multival()
         if self.is_multival:
-            self.device_packed = False
-            layout = DataLayout(
+            return DataLayout(
                 # placeholder dense matrix: the multival grower never
                 # reads it, but downstream sharding specs expect 2D
                 bins=jnp.zeros((self.num_data, 1), jnp.uint8),
@@ -1242,9 +1264,6 @@ class BinnedDataset:
                 ell_bin=jnp.asarray(self.ell_bin),
                 group_default=jnp.asarray(self.group_default_bins()),
             )
-            return layout, self._feature_meta(feat_id)
-        plan = self.device_pack_plan(config)
-        self.device_packed = plan is not None
         if plan is not None:
             storage_of, shift, n_storage, mask = plan
             storage = np.zeros((self.num_data, n_storage),
@@ -1255,7 +1274,7 @@ class BinnedDataset:
                     (self.binned[:, g].astype(np.int64)
                      << int(shift[g])).astype(self.binned.dtype),
                     out=storage[:, storage_of[g]])
-            layout = DataLayout(
+            return DataLayout(
                 bins=jnp.asarray(storage),
                 group_offset=jnp.asarray(self.group_offset),
                 group_of=jnp.asarray(self.group_of),
@@ -1264,14 +1283,12 @@ class BinnedDataset:
                 unpack_shift=jnp.asarray(shift),
                 unpack_mask=jnp.asarray(mask),
             )
-        else:
-            layout = DataLayout(
-                bins=jnp.asarray(self.binned),
-                group_offset=jnp.asarray(self.group_offset),
-                group_of=jnp.asarray(self.group_of),
-                most_freq_bin=jnp.asarray(self.most_freq_bin),
-            )
-        return layout, self._feature_meta(feat_id)
+        return DataLayout(
+            bins=jnp.asarray(self.binned),
+            group_offset=jnp.asarray(self.group_offset),
+            group_of=jnp.asarray(self.group_of),
+            most_freq_bin=jnp.asarray(self.most_freq_bin),
+        )
 
     def _feature_meta(self, feat_id):
         import jax.numpy as jnp

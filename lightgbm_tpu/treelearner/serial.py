@@ -304,11 +304,15 @@ class SerialTreeLearner:
     def __init__(self, config: Config, dataset):
         self.config = config
         self.dataset = dataset
-        # run record: the dispatch's wall and the bytes it leaves on the
-        # device; not blocked, the payload pack may overlap the copy
+        # run record: the host part of the layout (the multi-value and
+        # nibble-packing decisions the GrowConfig reads) and the per-feature
+        # tables' copy; the binned rows go on the device at the first read
+        # of self.layout, which the persist path never makes
         with telemetry.scope("tree_learner::ToDevice(layout H2D)",
                              category="setup", always=True):
-            self.layout, self.meta = dataset.to_device(config)
+            self.meta = dataset.device_meta(config)
+        self._layout = None
+        self._layout_config = config     # the key GrowConfig was built on
         self.fix = dataset.fix_info()
         self.params = SplitParams.from_config(config)
         cat_bins = dataset.bin_end[dataset.is_categorical] - \
@@ -386,6 +390,17 @@ class SerialTreeLearner:
                                 and not self.grow_config.multival)
         self.gw_global = build_gw_global(dataset)
         self._axis_name = None   # set by parallel learners
+
+    @property
+    def layout(self):
+        """The binned rows on the device (DataLayout) that the v1 growers
+        read, placed at the first read and shared through the dataset's
+        cache; not blocked, as the grower's launch that follows waits."""
+        if self._layout is None:
+            with telemetry.scope("tree_learner::ToDevice(bins H2D)",
+                                 category="setup", always=True):
+                self._layout, _ = self.dataset.to_device(self._layout_config)
+        return self._layout
 
     def refresh_config(self, config: Config) -> bool:
         """SerialTreeLearner::ResetConfig
