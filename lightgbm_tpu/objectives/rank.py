@@ -19,14 +19,13 @@ stochastic gradients too.
 """
 from __future__ import annotations
 
-import functools
-
 import numpy as np
 
 import jax
 import jax.numpy as jnp
 
 from ..metrics.dcg import (cal_max_dcg_at_k, check_label, default_label_gain)
+from ..telemetry import events as telemetry
 from ..utils.log import Log
 from .base import K_EPSILON, ObjectiveFunction, register
 
@@ -251,25 +250,29 @@ class LambdarankNDCG(RankingObjective):
             Q, P = lab_pad.shape
             QP = Q * P
             NP = score.shape[0]
-            bc32 = functools.partial(jax.lax.bitcast_convert_type,
-                                     new_dtype=jnp.float32)
             rid_c = jnp.minimum(rid, n - 1)
             # pos_of_rid is None when the row->slot map is the identity
             # (all queries the same length, no padding): skip the gather
             pos = rid_c if pos_of_rid is None else pos_of_rid[rid_c]
             pos = jnp.where(live, pos, QP)
-            # ONE scatter plants both the padded scores and the inverse
-            # slot->lane map (lane ids bitcast to ride the f32 scatter);
-            # dead slots keep lane NP so the return scatter drops them
+            # ONE int32 scatter plants both the padded scores and the
+            # inverse slot->lane map. The scores ride as their own bits (one
+            # word a float32, two a float64), never the lane ids as floats:
+            # an int32 under 2^23 read as a float32 is a denormal, which
+            # XLA flushes to zero on the CPU and the TPU. Dead slots keep
+            # lane NP so the return scatter drops them
             lane = jnp.arange(NP, dtype=jnp.int32)
-            init = jnp.stack([
-                jnp.zeros((QP,), jnp.float32),
-                jnp.broadcast_to(bc32(jnp.asarray(NP, jnp.int32)), (QP,))])
+            bits = jax.lax.bitcast_convert_type(score, jnp.int32) \
+                .reshape(NP, -1).T                      # [W, NP]
+            W = bits.shape[0]
+            init = jnp.concatenate([jnp.zeros((W, QP), jnp.int32),
+                                    jnp.full((1, QP), NP, jnp.int32)])
             spl = init.at[:, pos].set(
-                jnp.stack([score, bc32(lane)]), mode="drop",
+                jnp.concatenate([bits, lane[None]]), mode="drop",
                 unique_indices=True)
-            sp = spl[0]
-            inv = jax.lax.bitcast_convert_type(spl[1], jnp.int32)
+            sp = jax.lax.bitcast_convert_type(spl[:W].T, score.dtype) \
+                .reshape(QP)
+            inv = spl[W]
             lam, hes = core(sp.reshape(Q, P), lab_pad, qvalid, inv_max_dcgs,
                             gains_pad, discounts)
             lam = lam[:QP]
@@ -302,6 +305,14 @@ class LambdarankNDCG(RankingObjective):
             # pass None and the pos fn skips that [n]-sized gather
             identity = bool(np.array_equal(
                 self._inv_pos, np.arange(self.num_data, dtype=np.int32)))
+            # run record: the padded query layout the fill works on (set,
+            # not summed); slots over rows is the padding's cost
+            telemetry.clear_counts_prefix(("objective::rank_queries",
+                                           "objective::rank_query_slots"))
+            telemetry.count("objective::rank_queries",
+                            float(self.num_queries), category="objective")
+            telemetry.count("objective::rank_query_slots",
+                            float(self._qidx.size), category="objective")
             cached = self._pos_args_dev = (
                 jnp.asarray(self._lab_pad), jnp.asarray(self._qvalid),
                 jnp.asarray(self.inverse_max_dcgs),

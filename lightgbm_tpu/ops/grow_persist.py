@@ -1839,13 +1839,21 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
                 & (jnp.arange(L, dtype=I32) < num_leaves))
         return jnp.where(live, lint[:, LI_START], NP), live
 
-    def apply_scores(pay, lstate, num_leaves, shrink, cls=0):
+    def apply_scores(pay, lstate, num_leaves, shrink, cls=0, exact=False):
         """score-row of class `cls` += shrink * leaf_value[leaf_of_position]
         via segment deltas: leaves partition positions into contiguous
         runs. The widened mode gathers the per-leaf f64 product directly
         (leaf of a position by searchsorted over live segment starts) so
         each row's update is the same leaf_value * shrink product — and
-        the same single f64 add — as the v1 score updater."""
+        the same single f64 add — as the v1 score updater.
+
+        ``exact`` (f32 scores): each row adds its leaf's output bit for
+        bit as the model text holds it, the host's f64 Shrinkage product
+        rounded once to f32, carried down the segments as int32 bits (a
+        telescoping sum of f32 deltas rounds each row's update by a few
+        ulps). The ranking fill orders a query's rows by score, and a
+        score an ulp off orders a near-tie otherwise than a walk of the
+        model does."""
         if big:
             lstate, lint = lstate
         else:
@@ -1870,7 +1878,12 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
             sc = _read_score(pay, cls)
             sc = sc + jnp.where(num_leaves > 1, upd, 0.0)
             return _write_score(pay, sc, cls)
-        vals = (lstate[:, LS_VAL] * shrink.astype(ST)).astype(F32)
+        if exact:
+            vals = jax.lax.bitcast_convert_type(
+                (lstate[:, LS_VAL].astype(jnp.float64) * shrink)
+                .astype(F32), I32)
+        else:
+            vals = (lstate[:, LS_VAL] * shrink.astype(ST)).astype(F32)
         if big:
             starts, live = _int_segments(lint, num_leaves)
             key = starts
@@ -1879,11 +1892,14 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
         order = jnp.argsort(key)
         sv = vals[order]
         live_o = live[order]
-        prev = jnp.concatenate([jnp.zeros((1,), F32), sv[:-1]])
-        delta = jnp.where(live_o, sv - prev, 0.0)
+        prev = jnp.concatenate([jnp.zeros((1,), sv.dtype), sv[:-1]])
+        # int32 differences and sums wrap, and telescope back exactly
+        delta = jnp.where(live_o, sv - prev, jnp.zeros((), sv.dtype))
         pos = jnp.where(live_o, starts[order].astype(I32), NP)
-        upd = jnp.zeros((NP,), F32).at[pos].add(delta, mode="drop")
+        upd = jnp.zeros((NP,), sv.dtype).at[pos].add(delta, mode="drop")
         cum = jnp.cumsum(upd)
+        if exact:
+            cum = jax.lax.bitcast_convert_type(cum, F32)
         sc = _read_score(pay, cls)
         sc = sc + jnp.where(num_leaves > 1, cum, 0.0)
         return _write_score(pay, sc, cls)
@@ -2345,7 +2361,8 @@ def make_scan_driver(gr, gc, k: int, grad_fn, grad_mode: str = "payload",
                 stats = stats.at[STAT_HEALTH0 + H_NAN_GRAD].add(gh2[0]) \
                              .at[STAT_HEALTH0 + H_NAN_HESS].add(gh2[1])
             with jax.named_scope("apply_scores"):
-                pay = gr.apply_scores(pay, lstate, nl, shrink)
+                pay = gr.apply_scores(pay, lstate, nl, shrink,
+                                      exact=grad_mode == "pos")
             with jax.named_scope("to_tree_arrays"):
                 out = gr.to_tree_arrays(lstate, tree, nl)
             return pay, (out, stats)

@@ -45,7 +45,8 @@ timeline.
 from . import events, flight, histo
 from .events import (OFF, TIMERS, TRACE, add, configure, configure_from_config,
                      count, counts_snapshot, device_wait, disable, enable,
-                     enabled, events_snapshot, iteration_records, mode, reset,
+                     enabled, events_snapshot, iteration_records, keep_program,
+                     mode, program_scopes, reset,
                      ring_snapshot, scope, snapshot, timed, tracing)
 from .export import (format_report, maybe_export, print_report,
                      rank_suffixed, write_chrome_trace, write_metrics_jsonl)
@@ -57,8 +58,9 @@ __all__ = [
     "configure", "configure_from_config", "count", "counts_snapshot",
     "device_wait", "disable", "enable", "enabled", "events",
     "events_snapshot", "flight", "format_report", "histo",
-    "histograms_snapshot", "iteration_records", "maybe_export", "mode",
-    "observe", "print_report", "rank_suffixed", "reset", "ring_snapshot",
+    "histograms_snapshot", "iteration_records", "keep_program",
+    "maybe_export", "mode", "observe", "print_report", "program_scopes",
+    "rank_suffixed", "reset", "ring_snapshot",
     "scope",
     "snapshot", "timed", "tracing", "write_chrome_trace",
     "write_metrics_jsonl",

@@ -47,6 +47,7 @@ import atexit
 import contextlib
 import functools
 import os
+import re
 import threading
 import time
 from collections import defaultdict, deque
@@ -82,6 +83,7 @@ _train_seq = 0
 _cur_train = 0
 _cur_launch: Optional[int] = None
 _iter_records: List[dict] = []
+_programs: Dict[str, object] = {}   # span name -> its lowered program
 _tls = threading.local()
 _out_path: Optional[str] = None
 _exported = False
@@ -212,6 +214,7 @@ def reset() -> None:
         del _events[:]
         _ring.clear()
         del _iter_records[:]
+        _programs.clear()
         _dropped = 0
         _exported = False
     # the histogram registry and the flight ring are part of the same
@@ -533,6 +536,54 @@ def launch_wrapper(fn, name: str, category: str = "ops",
                 histo.observe(histogram, time.perf_counter() - t0,
                               unit="s", category=category)
     return wrapper
+
+
+def keep_program(name: str, fn, args: tuple) -> None:
+    """Run record, trace mode only (a no-op in off and timers): the
+    program the jitted ``fn`` launches under span ``name`` on one device,
+    lowered for the abstract values of ``args`` (taken before the launch
+    donates them; no sharding, as the launch's uncommitted arguments have
+    none, so that the jit's caches match and nothing is traced or lowered
+    again). The lowered module is kept, not ``fn`` and what its closures
+    hold; ``program_scopes`` compiles it only when asked, from the same
+    caches. The newest program a name replaces the one before it."""
+    if _mode != TRACE:
+        return
+
+    def spec(a):
+        aval = jax.typeof(a)
+        return jax.ShapeDtypeStruct(aval.shape, aval.dtype,
+                                    weak_type=aval.weak_type)
+    lowered = fn.lower(*jax.tree.map(spec, args))
+    with _lock:
+        _programs[name] = lowered
+
+
+_OP_NAME = re.compile(r'^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*'
+                      r'metadata=\{op_name="([^"]*)"', re.M)
+
+
+def program_scopes(name: str, scopes) -> Dict[str, str]:
+    """{HLO instruction name: scope} for the instructions of the program
+    kept under ``name`` whose ``op_name`` metadata holds one of ``scopes``
+    (``jax.named_scope`` names) as a path component, the outermost if
+    several: what a device trace's per-instruction seconds need to be
+    summed by scope (an "XLA Ops" event carries no scope of its own).
+    Empty where nothing is kept under ``name`` (no launch under it in
+    trace mode since the last :func:`reset`). Lowered and compiled
+    through the jit's own caches, so it compiles nothing while JAX still
+    holds the launched program."""
+    with _lock:
+        lowered = _programs.get(name)
+    if lowered is None:
+        return {}
+    text = lowered.compile().as_text()
+    out = {}
+    for instr, op_name in _OP_NAME.findall(text):
+        hit = [part for part in op_name.split("/") if part in scopes]
+        if hit:
+            out[instr] = hit[0]
+    return out
 
 
 def device_wait(name: str, value, **tags):

@@ -664,14 +664,19 @@ class SerialTreeLearner:
         return assets, gr, driver
 
     @staticmethod
-    def _count_persist_trees(gr, k: int):
+    def _count_persist_trees(gr, k: int, grad_mode: str = "payload"):
         """Run record: k trees on the persist path, and by which of the
         grower's mechanisms (split scan over the bundled group planes;
         smaller-child histogram built inside split_pass; a payload row
         wide enough for its width to size the kernels' chunks; row counts
-        in i32 past 2^24 rows; the sharded grower)."""
+        in i32 past 2^24 rows; the sharded grower) and of the fused
+        scan's gradient fills (the per-query ranking fill, grad_mode
+        'pos')."""
         telemetry.count("tree_learner::persist_scan_trees", float(k),
                         category="tree_learner")
+        if grad_mode == "pos":
+            telemetry.count("tree_learner::rank_pos_trees", float(k),
+                            category="tree_learner")
         if gr.block_scan:
             telemetry.count("tree_learner::blockscan_trees", float(k),
                             category="tree_learner")
@@ -713,16 +718,21 @@ class SerialTreeLearner:
         as a device carry on this learner; scores return to row order only
         in persist_finalize_scores()."""
         assets, gr, driver = self._persist_cached(objective, k, bag_spec)
-        self._count_persist_trees(gr, k)
+        self._count_persist_trees(gr, k, objective.persist_grad_mode())
         pay = getattr(self, "_persist_carry", None)
-        if pay is None:
+        first = pay is None
+        if first:
             pay = self._persist_init_carry(gr, assets, score0)
-        pay, stacked, stats = driver(pay, jnp.asarray(fmasks),
-                                     jnp.asarray(wkeys, jnp.uint32),
-                                     jnp.asarray(iters, jnp.int32),
-                                     self.params,
-                                     jnp.asarray(shrink, jnp.float64),
-                                     objective.persist_grad_args())
+        args = (pay, jnp.asarray(fmasks), jnp.asarray(wkeys, jnp.uint32),
+                jnp.asarray(iters, jnp.int32), self.params,
+                jnp.asarray(shrink, jnp.float64),
+                objective.persist_grad_args())
+        if first:
+            # run record, trace mode only: the fused program's HLO, and so
+            # which of its instructions each named scope holds, on request
+            telemetry.keep_program("ops::persist_scan(launch)",
+                                   driver.__wrapped__, args)
+        pay, stacked, stats = driver(*args)
         # level-program stats stay a DEVICE array until finalize: the
         # fast path must not sync per batch just to bump a counter
         prev = getattr(self, "_level_stats_dev", None)

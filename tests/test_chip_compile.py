@@ -19,7 +19,9 @@ scan at 137 groups), the Epsilon payload (benchmark/configs/epsilon.json:
 chunks `_payload_geometry` sizes from that width (C 2048, CR 8192),
 split_pass at 64 sublane tiles, seg_hist / root_hist over 2,000 groups,
 scan_pair over [2000, 256] planes and the fused k=16 driver with its
-1.04 GB of per-leaf planes), and the HIGGS rows under
+1.04 GB of per-leaf planes), the fused driver of benchmark/configs/mslr.json
+(11.52M rows in 96,000 queries of 120, its per-query ranking fill beside
+the kernels at 137 groups), and the HIGGS rows under
 the other static shapes every persist configuration can take: a weight row
 (13 live rows), three classes (17 live rows in 24), ``max_bin=15`` (every
 group a nibble, 9 live rows in 16) — plus the whole fused k=16 scan driver.
@@ -66,6 +68,7 @@ EXPO_ROWS = 11_000_000      # docs/Experiments.rst: Expo
 MSLTR_ROWS = 2_270_296      # docs/Experiments.rst: MS LTR
 EXPO_CELL_ROWS = 16_500_000  # benchmark/configs/expo.json: 1.5 x Expo
 EPSILON_ROWS = 400_000      # docs/GPU-Performance.rst: Epsilon
+MSLR_QUERIES, MSLR_DOCS = 96_000, 120   # benchmark/configs/mslr.json
 EPSILON_FEATURES = 2_000
 LEVEL_DEPTH = 8             # max_depth; with num_leaves = 2^8 the level
 #                             phase engages
@@ -191,6 +194,40 @@ def epsilon():
     G, plan, C, CR = (built.assets.geometry[i] for i in (2, 3, 6, 7))
     assert (built.wp_live, built.pay[0], G) == (505, 512, EPSILON_FEATURES)
     assert (C, CR) == (2048, 8192) and hist_loops_groups(G, plan)
+    return built
+
+
+@pytest.fixture(scope="module")
+def mslr():
+    """benchmark/configs/mslr.json: the cell's own generator's columns
+    (some whole-count columns take a nibble: 34 bin words, 39 live rows)
+    at 11.52M rows, and a lambdarank objective over its 96,000 queries of
+    120 built as the chip builds it (its pair planes in float32)."""
+    import importlib.util
+    import types
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "generators",
+        "mslr_like.py")
+    spec = importlib.util.spec_from_file_location("mslr_like", path)
+    gen = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(gen)
+    key = jax.random.wrap_key_data(np.asarray([0, 42], np.uint32))
+    x, y = (np.asarray(a) for a in gen.make_block(key, 0, SAMPLE_ROWS // 120
+                                                  * 120))
+    rows = MSLR_QUERIES * MSLR_DOCS
+    built = _Built(x.astype(np.float64), (y > 1).astype(np.float64), rows,
+                   (-1,), enable_bundle=False)
+    assert (built.pay[0], built.wp_live, len(built.ds.groups)) == (40, 39,
+                                                                  137)
+    label = np.resize(y.astype(np.float64), rows)
+    built.objective = create_objective("lambdarank", lgb.Config(
+        {"objective": "lambdarank"}))
+    built.objective.init(types.SimpleNamespace(
+        label=label, weight=None, num_queries=MSLR_QUERIES,
+        query_boundaries=np.arange(MSLR_QUERIES + 1) * MSLR_DOCS), rows)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        built.grad = built.objective.device_gradients()
     return built
 
 
@@ -397,7 +434,8 @@ def _level_seg_hist(higgs, expo, S):
 def _fused_driver_of(built, S):
     k, F = 16, built.ds.num_features
     learner, gr = built.learners[-1], built.growers[-1]
-    mode, grad_fn = built.objective.device_gradients()
+    mode, grad_fn = getattr(built, "grad", None) or \
+        built.objective.device_gradients()
     run = gp.make_scan_driver(gr, learner.grow_config, k, grad_fn,
                               grad_mode=mode, wrap_jit=False)
     like = lambda tree: jax.tree.map(  # noqa: E731
@@ -420,6 +458,15 @@ def _fused_driver_epsilon(higgs, expo, S, epsilon):
     return _fused_driver_of(epsilon, S)
 
 
+def _fused_driver_mslr(higgs, expo, S, mslr):
+    """mslr.train_steady's launch: the fused driver with the per-query
+    ranking fill (grad_mode 'pos': one int32 scatter into [96000, 120]
+    slots, the pair planes in 21 chunks, one scatter back) beside the
+    kernels at 137 groups."""
+    assert mslr.grad[0] == "pos"
+    return _fused_driver_of(mslr, S)
+
+
 @pytest.mark.parametrize("case", [
     _hist_window, _scan_pair, _scan_pair_msltr, _scan_blocks, _split_pass,
     _split_pass_msltr, _split_pass_expo, _split_pass_weighted,
@@ -427,7 +474,7 @@ def _fused_driver_epsilon(higgs, expo, S, epsilon):
     _level_seg_hist, _seg_hist, _seg_hist_msltr, _seg_hist_expo,
     _root_hist, _root_hist_msltr, _fused_driver, _split_pass_epsilon,
     _seg_hist_epsilon, _root_hist_epsilon, _scan_pair_epsilon,
-    _fused_driver_epsilon,
+    _fused_driver_epsilon, _fused_driver_mslr,
 ], ids=lambda f: f.__name__.lstrip("_"))
 def test_compiles_for_v5e(case, one_chip, higgs, expo, request):
     def S(shape, dtype):
@@ -441,7 +488,7 @@ def test_compiles_for_v5e(case, one_chip, higgs, expo, request):
     assert fn is not None, "the grower built no such kernel here"
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
-    if case in (_fused_driver, _fused_driver_epsilon):
+    if case in (_fused_driver, _fused_driver_epsilon, _fused_driver_mslr):
         # the split scan is handed the children's rows: a gather of them
         # by an index vector out of the [L, G x 256] planes is lowered
         # through whole-plane slices (2 GB a split at 2,000 columns)

@@ -23,8 +23,9 @@ import lightgbm_tpu as lgb
 # here shard them over the 8-virtual-device mesh): 7-140s each on the
 # 2-core CPU CI host, ~14 min for the file — slow tier, not tier-1, but
 # for the four fastest cases of the data-parallel and voting learners
-# (17 s together here, PR 38), which stay unmarked so that tier-1 holds the
-# sharded persist path whatever the benchmark's cells run
+# (17 s together), which stay unmarked so that tier-1 holds the sharded
+# persist path whatever the benchmark's cells run, and the ranking fill's
+# pos mode against row mode (8 s)
 slow = pytest.mark.slow
 
 
@@ -266,7 +267,6 @@ def _data_rank(seed=71, docs=48):
     return X, lab.reshape(-1).astype(float), group
 
 
-@slow
 def test_persist_lambdarank_pos_mode_matches_row_mode(monkeypatch):
     """Payload-position lambdarank gradients (one scatter through the
     row-id map, ops/grow_persist.fill_grad_pos) see exactly the score
