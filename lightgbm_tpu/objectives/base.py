@@ -66,8 +66,8 @@ class ObjectiveFunction:
         device-side gradient kernel as (mode, fn), or None when this
         objective is host-only. mode selects the scan driver's fill
         contract — 'payload' (label-only, fastest; also the
-        K-tree-per-iteration snapshot fill), 'pos' (payload-order with
-        row-id scatter, lambdarank), 'row' (full row-order round trip
+        K-tree-per-iteration snapshot fill), 'pos' (payload-order through
+        the row-id row, lambdarank), 'row' (full row-order round trip
         through the objective's standard grad_fn). Objectives whose
         gradients need fresh per-iteration HOST inputs (rank_xendcg's
         randomization) override this to return None — the traced
@@ -125,9 +125,9 @@ class ObjectiveFunction:
         """Pure (score, rid, live, *pos_args) -> (grad, hess) ALL in
         payload order, for objectives whose gradients need global row
         structure (lambdarank's query groups) but can reach it through the
-        carried row-id payload row with one scatter instead of a full
-        row-order round trip. None when unsupported (the persist driver
-        then falls back to row-order mode)."""
+        carried row-id payload row instead of a full row-order round trip.
+        None when unsupported (the persist driver then falls back to
+        row-order mode)."""
         return None
 
     def persist_grad_mode(self) -> str:

@@ -88,14 +88,19 @@ class LambdarankNDCG(RankingObjective):
             inv[q] = 1.0 / m if m > 0.0 else 0.0
         self.inverse_max_dcgs = inv
         self._qidx, self._qvalid = _pack_queries(qb)
-        # row -> padded position (q*P + offset): the padded [Q, P] lambdas
-        # return to row order with one gather (TPU scatters serialize;
-        # queries are contiguous row ranges so this map is static)
+        # row -> padded position (q*P + offset): the row-order grad_fn
+        # returns the padded [Q, P] lambdas to row order with one gather
+        # (queries are contiguous row ranges so this map is static)
         P = self._qidx.shape[1]
         counts = np.diff(qb)
         qid = np.repeat(np.arange(self.num_queries, dtype=np.int64), counts)
         self._inv_pos = (qid * P + (np.arange(self.num_data, dtype=np.int64)
                                     - qb[qid])).astype(np.int32)
+        # the payload fill's sort keys of the padding: row r sorts at 2r,
+        # and the P - count slots of query q at 2 * qb[q+1] - 1, right
+        # after its last row (none when every query is P long)
+        self._fill_key = np.repeat(2 * qb[1:].astype(np.int64) - 1,
+                                   P - counts).astype(np.int32)
         # padded per-slot statics for the payload-position gradient mode:
         # labels never change, so the [Q, P] label/gain/weight planes are
         # computed once and only SCORES move per iteration
@@ -155,7 +160,10 @@ class LambdarankNDCG(RankingObjective):
                                    num_keys=1, is_stable=True)
             n_valid = jnp.sum(valid_q.astype(jnp.int32))
             best_score = -neg_s[0]
-            worst_score = -neg_s[jnp.maximum(n_valid - 1, 0)]
+            # the last valid rank's score picked by a masked max, not an
+            # index: vmapped, a traced index is a gather
+            worst_score = -jnp.max(jnp.where(
+                iota == jnp.maximum(n_valid - 1, 0), neg_s, -jnp.inf))
 
             # pairwise [P, P]: i = high row, j = low row
             lab = labels_q.astype(jnp.int32)
@@ -235,44 +243,52 @@ class LambdarankNDCG(RankingObjective):
             return g.astype(jnp.float32), h.astype(jnp.float32)
         return fn
 
+    # the payload fill sorts on 2 * row id in int32
+    POS_FILL_MAX_ROWS = 1 << 30
+
     def payload_pos_fn(self):
         """Payload-order gradient mode for the persist fast path: scores
-        arrive in PAYLOAD order with their global row ids; the padded
-        [Q, P] slots are filled with ONE scatter through the static
-        row->slot map and the lambdas return with one gather — no
-        row-order round trip (the reference has no analog: its gradient
-        buffer is always row-ordered, rank_objective.hpp:98-137)."""
-        core = self._pairwise_flat()
+        arrive in PAYLOAD order with their global row ids, and the rows
+        move between lane order and the padded [Q, P] slots by two sorts,
+        with no scatter and no gather (on a TPU v5e both serialize: a
+        [2, n] scatter took 61 ns a row). The first sorts (row key, score
+        bits, lane) into slot order, the second sorts (slot lane, lambda,
+        hessian) back to lane order — no row-order round trip (the
+        reference has no analog: its gradient buffer is always
+        row-ordered, rank_objective.hpp:98-137). The driver keeps the
+        live rows in lanes 0..n-1, so `live` is not read. None past
+        POS_FILL_MAX_ROWS rows, whose doubled row ids overflow int32: the
+        driver then takes the row-order fill."""
         n = self.num_data
+        if n >= self.POS_FILL_MAX_ROWS:
+            return None
+        core = self._pairwise_flat()
 
         def fn(score, rid, live, lab_pad, qvalid, inv_max_dcgs, gains_pad,
-               discounts, pos_of_rid, w_pad):
+               discounts, fill_key, w_pad):
             Q, P = lab_pad.shape
             QP = Q * P
             NP = score.shape[0]
-            rid_c = jnp.minimum(rid, n - 1)
-            # pos_of_rid is None when the row->slot map is the identity
-            # (all queries the same length, no padding): skip the gather
-            pos = rid_c if pos_of_rid is None else pos_of_rid[rid_c]
-            pos = jnp.where(live, pos, QP)
-            # ONE int32 scatter plants both the padded scores and the
-            # inverse slot->lane map. The scores ride as their own bits (one
-            # word a float32, two a float64), never the lane ids as floats:
-            # an int32 under 2^23 read as a float32 is a denormal, which
-            # XLA flushes to zero on the CPU and the TPU. Dead slots keep
-            # lane NP so the return scatter drops them
-            lane = jnp.arange(NP, dtype=jnp.int32)
-            bits = jax.lax.bitcast_convert_type(score, jnp.int32) \
-                .reshape(NP, -1).T                      # [W, NP]
+            F = fill_key.shape[0]                   # QP - n padding slots
+            # into slot order: row r sorts at key 2r and the padding of
+            # query q right after its last row, each slot carrying its
+            # score and its lane. The scores ride as their own bits (one
+            # word a float32, two a float64), never the lane ids as
+            # floats: an int32 under 2^23 read as a float32 is a denormal,
+            # which XLA flushes to zero on the CPU and the TPU. Padding
+            # slots score 0 from lane NP; the keys are distinct but for
+            # the padding's, whose operands are equal, so no tie-break
+            key = jnp.concatenate([2 * rid[:n], fill_key])
+            bits = jax.lax.bitcast_convert_type(score[:n], jnp.int32) \
+                .reshape(n, -1).T                       # [W, n]
             W = bits.shape[0]
-            init = jnp.concatenate([jnp.zeros((W, QP), jnp.int32),
-                                    jnp.full((1, QP), NP, jnp.int32)])
-            spl = init.at[:, pos].set(
-                jnp.concatenate([bits, lane[None]]), mode="drop",
-                unique_indices=True)
-            sp = jax.lax.bitcast_convert_type(spl[:W].T, score.dtype) \
-                .reshape(QP)
-            inv = spl[W]
+            bits = jnp.concatenate([bits, jnp.zeros((W, F), jnp.int32)], 1)
+            lane = jnp.concatenate([jnp.arange(n, dtype=jnp.int32),
+                                    jnp.full((F,), NP, jnp.int32)])
+            _, *words, slot_lane = jax.lax.sort(
+                (key, *bits, lane), num_keys=1, is_stable=False)
+            sp = jax.lax.bitcast_convert_type(jnp.stack(words, 1),
+                                              score.dtype).reshape(QP)
             lam, hes = core(sp.reshape(Q, P), lab_pad, qvalid, inv_max_dcgs,
                             gains_pad, discounts)
             lam = lam[:QP]
@@ -284,14 +300,13 @@ class LambdarankNDCG(RankingObjective):
                 # weight row is not applied in pos mode
                 lam = lam * w_pad.reshape(-1)
                 hes = hes * w_pad.reshape(-1)
-            # return via ONE scatter through the inverse map, not gathers:
-            # on TPU an [NP]-sized gather serializes while the scatter of
-            # a [2, n] block costs about the same as a [n] one
-            out = jnp.zeros((2, NP), jnp.float32).at[:, inv].set(
-                jnp.stack([lam.astype(jnp.float32),
-                           hes.astype(jnp.float32)]),
-                mode="drop", unique_indices=True)
-            return out[0], out[1]
+            # back to lane order: lanes 0..n-1 come first, the padding
+            # (lane NP) last; the dead lanes past n read 0
+            _, g, h = jax.lax.sort(
+                (slot_lane, lam.astype(jnp.float32), hes.astype(jnp.float32)),
+                num_keys=1, is_stable=False)
+            return (jnp.pad(g[:n], (0, NP - n)),
+                    jnp.pad(h[:n], (0, NP - n)))
         return fn
 
     def _pos_grad_args(self):
@@ -301,10 +316,6 @@ class LambdarankNDCG(RankingObjective):
         if cached is None:
             P = self._qidx.shape[1]
             from ..metrics.dcg import _DISCOUNT_CACHE
-            # equal-length queries make the row->slot map the identity;
-            # pass None and the pos fn skips that [n]-sized gather
-            identity = bool(np.array_equal(
-                self._inv_pos, np.arange(self.num_data, dtype=np.int32)))
             # run record: the padded query layout the fill works on (set,
             # not summed); slots over rows is the padding's cost
             telemetry.clear_counts_prefix(("objective::rank_queries",
@@ -318,7 +329,7 @@ class LambdarankNDCG(RankingObjective):
                 jnp.asarray(self.inverse_max_dcgs),
                 jnp.asarray(self._gains_pad),
                 jnp.asarray(_DISCOUNT_CACHE[:P]),
-                (None if identity else jnp.asarray(self._inv_pos)),
+                jnp.asarray(self._fill_key),
                 (jnp.asarray(self._w_pad) if self._w_pad is not None
                  else None))
         return cached

@@ -2109,9 +2109,9 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
     def fill_grad_pos(pay, pos_grad_fn, gargs):
         """Payload-position gradient mode: the objective computes (g, h)
         directly in PAYLOAD order from (score, rid, live) — lambdarank
-        scatters scores into its padded query slots through the row-id
-        map and gathers the lambdas straight back, skipping the row-order
-        round trip of fill_grad_row."""
+        sorts the scores into its padded query slots by row id and sorts
+        the lambdas straight back by lane, skipping the row-order round
+        trip of fill_grad_row. The live rows are lanes 0..n-1."""
         rid = pay[nbw + 1].astype(I32)
         score = _read_score(pay)
         live = jnp.arange(NP, dtype=I32) < n

@@ -460,9 +460,9 @@ def _fused_driver_epsilon(higgs, expo, S, epsilon):
 
 def _fused_driver_mslr(higgs, expo, S, mslr):
     """mslr.train_steady's launch: the fused driver with the per-query
-    ranking fill (grad_mode 'pos': one int32 scatter into [96000, 120]
-    slots, the pair planes in 21 chunks, one scatter back) beside the
-    kernels at 137 groups."""
+    ranking fill (grad_mode 'pos': one sort of the rows into [96000, 120]
+    slots, the pair planes in 21 chunks, one sort back to the lanes)
+    beside the kernels at 137 groups."""
     assert mslr.grad[0] == "pos"
     return _fused_driver_of(mslr, S)
 

@@ -1,13 +1,14 @@
 """Ranking on the persist path: the per-query gradient fill inside the fused
 driver (objectives/rank.py:payload_pos_fn, ops/grow_persist.fill_grad_pos).
 
-The fill plants each lane's score in its query's padded slot and each slot's
-lane number beside it, in one scatter, and returns the lambdas through that
-slot-to-lane map. The lane numbers must stay integers: an int32 under 2^23
-read as a float32 is a denormal, which XLA flushes to zero on the CPU and the
-TPU, and every lambda of such a lane then lands on lane 0 (a model of stumps,
-every row's gradient 0). These tests hold the fill to the row-order
-gradients lane for lane, and the trees it grows to a plain LambdaRank-NDCG
+The fill sorts each lane's score into its query's padded slot with the lane
+number beside it, and sorts the lambdas back to the lanes by that
+slot-to-lane map: no scatter and no gather. The lane numbers must stay
+integers: an int32 under 2^23 read as a float32 is a denormal, which XLA
+flushes to zero on the CPU and the TPU, and every lambda of such a lane then
+lands on lane 0 (a model of stumps, every row's gradient 0). These tests hold
+the fill to the row-order gradients lane for lane and, bit for bit, to a fill
+that scatters, and the trees it grows to a plain LambdaRank-NDCG
 written here from rank_objective.hpp; XE-NDCG, which has no device fill (its
 draws are fresh host inputs every iteration), to a plain XE-NDCG on the
 grower it takes. They also read the run record the fill leaves: the trees it
@@ -31,9 +32,9 @@ DOCS, QUERIES, FEATURES = 73, 1400, 12       # 102,200 rows
 ROUNDS = 16                                  # one fused launch
 
 
-def _objective(name, label, counts):
+def _objective(name, label, counts, weight=None):
     qb = np.concatenate([[0], np.cumsum(counts)])
-    meta = types.SimpleNamespace(label=label, weight=None,
+    meta = types.SimpleNamespace(label=label, weight=weight,
                                  num_queries=len(counts),
                                  query_boundaries=qb)
     obj = create_objective(name, Config({"objective": name}))
@@ -73,6 +74,72 @@ def test_pos_fill_puts_every_lambda_on_its_own_lane(dtype, lengths):
     np.testing.assert_allclose(h[live], np.asarray(want_h)[rid[live]],
                                rtol=1e-5, atol=1e-7)
     assert not g[~live].any() and not h[~live].any()
+
+
+def _two_scatter_fill(obj, score, rid, live, *gargs):
+    """The fill by scatters: each live lane's score scattered to its
+    query slot (q * P + offset, from the query boundaries) with the lane
+    beside it, the pairwise core run on the slots, and the lambdas
+    scattered back to the lanes through that slot-to-lane map."""
+    lab_pad, qvalid, inv_max, gains, disc, _fill, w_pad = gargs
+    Q, P = lab_pad.shape
+    NP, n = score.shape[0], obj.num_data
+    qb = obj.query_boundaries
+    q = np.repeat(np.arange(len(qb) - 1), np.diff(qb))
+    slot_of_row = q * P + np.arange(n) - qb[q]
+    pos = jnp.where(live, jnp.asarray(slot_of_row)[jnp.minimum(rid, n - 1)],
+                    Q * P)
+    sp = jnp.zeros(Q * P, score.dtype).at[pos].set(score, mode="drop")
+    lane = jnp.full(Q * P, NP, jnp.int32).at[pos].set(
+        jnp.arange(NP, dtype=jnp.int32), mode="drop")
+    lam, hes = obj._pairwise_flat()(sp.reshape(Q, P), lab_pad, qvalid,
+                                    inv_max, gains, disc)
+    lam, hes = lam[:Q * P], hes[:Q * P]
+    if w_pad is not None:
+        lam, hes = lam * w_pad.reshape(-1), hes * w_pad.reshape(-1)
+    out = jnp.zeros((2, NP), jnp.float32).at[:, lane].set(
+        jnp.stack([lam.astype(jnp.float32), hes.astype(jnp.float32)]),
+        mode="drop")
+    return out[0], out[1]
+
+
+@pytest.mark.parametrize("weighted", [False, True], ids=["plain", "weighted"])
+@pytest.mark.parametrize("lengths", ["equal", "unequal"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.float64],
+                         ids=["f32", "f64"])
+def test_pos_fill_moves_rows_by_sorts_as_the_scatters_did(dtype, lengths,
+                                                          weighted):
+    """The fill carries rows between lane order and query-slot order by
+    sorts: its program holds no scatter and no gather, and on shuffled
+    lanes with dead lanes at the tail it gives, bit for bit on every lane,
+    the (g, h) of a fill that scatters the scores into the slots and the
+    lambdas back."""
+    from lightgbm_tpu.analysis.dataflow import iter_eqns
+    rng = np.random.default_rng(17)
+    counts = (np.full(36, 25) if lengths == "equal"
+              else rng.integers(3, 50, 36))
+    n = int(counts.sum())
+    label = rng.integers(0, 5, n).astype(np.float64)
+    weight = (np.repeat(rng.uniform(0.5, 2.0, len(counts)), counts)
+              if weighted else None)
+    obj = _objective("lambdarank", label, counts, weight)
+    _, fn = obj.device_gradients()
+    lanes = n + 200
+    rid = np.concatenate([rng.permutation(n), np.full(lanes - n, n)])
+    score = np.where(rid < n, rng.normal(size=lanes), 0.0)
+    args = (jnp.asarray(score, dtype), jnp.asarray(rid, jnp.int32),
+            jnp.asarray(np.arange(lanes) < n), *obj.persist_grad_args())
+    prims = {e.primitive.name
+             for e, _ in iter_eqns(jax.make_jaxpr(fn)(*args).jaxpr)}
+    assert "sort" in prims
+    assert not prims & {"scatter", "scatter-add", "gather"}, prims
+    got = jax.jit(fn)(*args)
+    want = jax.jit(lambda *a: _two_scatter_fill(obj, *a))(*args)
+    assert np.abs(np.asarray(want[0])).max() > 0.01
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype == jnp.float32
+        np.testing.assert_array_equal(np.asarray(a).view(np.int32),
+                                      np.asarray(b).view(np.int32))
 
 
 def _rank_data():
@@ -323,7 +390,9 @@ def test_the_run_record_names_the_ranking_fill(monkeypatch, tmp_path):
         telemetry.configure("off", None)
     assert set(scopes.values()) == {"fill_grad", "grow"}
     fill = [k for k, v in scopes.items() if v == "fill_grad"]
-    assert any(k.startswith("scatter") for k in fill), fill
+    # the fill moves its rows by sorts: no scatter is left under the scope
+    assert any(k.startswith("sort") for k in fill), fill
+    assert not any(k.startswith("scatter") for k in fill), fill
     assert telemetry.program_scopes("no such span", ("fill_grad",)) == {}
     # a binary objective's launch fills elementwise: no ranking trees
     was = telemetry.counts_snapshot().get("tree_learner::rank_pos_trees")
