@@ -55,6 +55,7 @@ DOMAINS: Dict[str, Tuple[int, str]] = {
     "do_fix": (2, "bundled datasets run both fix modes"),
     "w": (2, "per-dataset max width; <=2 pad ladder stops (128/256)"),
     "max_w": (1, "per-dataset categorical width"),
+    "steps": (1, "config constant (max_cat_threshold)"),
     "use_dp": (1, "config constant"),
     "use_mc": (1, "per-dataset monotone flag"),
     "num_features": (1, "dataset geometry"),

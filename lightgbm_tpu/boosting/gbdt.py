@@ -21,7 +21,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from ..config import Config
-from ..models.tree import Tree
+from ..models.tree import Tree, kCategoricalMask
 from ..objectives import parse_objective_string
 from ..telemetry import events as telemetry
 from ..treelearner import create_tree_learner
@@ -659,7 +659,7 @@ class GBDT:
         import jax
         ntpi = self.num_tree_per_iteration
         kb = int(host_b.num_leaves.shape[0])
-        parent_rows = small_rows = 0
+        parent_rows = small_rows = splits = cat_splits = 0
         for i in range(kb):
             cls = i % ntpi
             ha = jax.tree.map(lambda a, i=i: a[i], host_b)
@@ -674,6 +674,10 @@ class GBDT:
                         for c in (tree.left_child[:ni], tree.right_child[:ni])]
                 parent_rows += int(tree.internal_count[:ni].sum(dtype=np.int64))
                 small_rows += int(np.minimum(*kids).sum(dtype=np.int64))
+                # and how many of the splits are categorical
+                splits += ni
+                cat_splits += int(np.count_nonzero(
+                    np.asarray(tree.decision_type[:ni]) & kCategoricalMask))
                 if bmode == "rf":
                     # rf.hpp:103-160: no shrinkage, EVERY tree gets
                     # the constant init-score bias (the device dance
@@ -695,6 +699,10 @@ class GBDT:
                         float(parent_rows), category="tree_learner")
         telemetry.count("tree_learner::split_small_rows",
                         float(small_rows), category="tree_learner")
+        telemetry.count("tree_learner::splits", float(splits),
+                        category="tree_learner")
+        telemetry.count("tree_learner::cat_splits", float(cat_splits),
+                        category="tree_learner")
 
     def _truncate_if_stopped(self) -> None:
         """Batch entries can contain a 1-leaf tree (no-split stop

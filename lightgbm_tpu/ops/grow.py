@@ -92,6 +92,7 @@ class GrowConfig(NamedTuple):
     packed_4bit: bool = False    # layout.bins nibble-packs <=16-bin groups
     n_forced: int = 0            # forcedsplits_filename node count
     multival: bool = False       # layout is ELL row-sparse (masked grower)
+    max_cat_threshold: int = 32  # bound of the sorted categorical scan
 
 
 class GrowExtras(NamedTuple):
@@ -494,7 +495,7 @@ def _make_eval_leaf(meta, params, feature_mask, cat, gc: GrowConfig,
             cat_cand = find_best_split_categorical(
                 hist, sg, sh, cnt, cat, meta, params, cmin, cmax,
                 fmask, use_mc=gc.use_mc, use_dp=gc.use_dp,
-                gain_penalty=gain_penalty)
+                gain_penalty=gain_penalty, steps=gc.max_cat_threshold)
             cand = merge_candidates(cand, cat_cand)
         if gc.max_depth > 0:
             blocked = depth >= gc.max_depth
@@ -686,7 +687,8 @@ def _make_eval_pair_fused(meta, params, feature_mask, cat, gc: GrowConfig,
                 lambda h, a, b, c: find_best_split_categorical(
                     h, a, b, c, cat, meta, params,
                     jnp.asarray(-jnp.inf, f32), jnp.asarray(jnp.inf, f32),
-                    feature_mask, use_mc=False, use_dp=gc.use_dp))(
+                    feature_mask, use_mc=False, use_dp=gc.use_dp,
+                    steps=gc.max_cat_threshold))(
                 hist2, sg, sh, jnp.stack([left_cnt, right_cnt]))
             if gc.max_depth > 0:
                 cat_pair = cat_pair._replace(gain=jnp.where(

@@ -30,9 +30,12 @@ row-ordered score/gradient plumbing around it:
 Numerics: f32 accumulation everywhere (the reference GPU learner's
 gpu_use_dp=false trade); trees match the v1 f32 grower up to f32 summation
 order. Gated by treelearner.serial.can_persist_scan — anything outside the
-fast path (categoricals, monotone, f64) takes the v1 path; sample weights
-ride as a payload row, EFB bundles decode in the split kernel with an
-in-eval FixHistogram, and lambdarank computes payload-position gradients.
+fast path (monotone, f64, numerical groups over 256 bins) takes the v1
+path; sample weights ride as a payload row, EFB bundles decode in the
+split kernel with an in-eval FixHistogram, lambdarank computes
+payload-position gradients, and categorical columns (up to 512 bins, in
+16-bit slots) split by a left-set bitset that the eval's cat_scan finds
+(ops/split.find_best_split_categorical) and split_pass reads.
 Bagging and GOSS run INSIDE the scan as payload transforms
 (make_bag_transform), and the whole driver also runs sharded under
 shard_map (make_persist_grower's axis_name) with in-loop histogram psum —
@@ -52,16 +55,17 @@ from ..telemetry.health import (H_INF_HIST, H_NAN_GRAD, H_NAN_HESS,
                                 HEALTH_LEN, NUM_HEALTH)
 from ..utils.log import Log
 from .grow import TreeArrays
-from .pallas_grow import (HIST_UNROLL_MAX_GROUPS, N_SCALARS, S_DB, S_DL,
-                          S_LE, S_LS, S_MASK, S_MF, S_MT, S_NB, S_NCH, S_NL,
-                          S_S0, S_SH, S_SMALL_L, S_THR, S_WG, VMEM_CAP,
-                          hist_loops_groups, make_root_hist, make_split_pass,
-                          plane_health, seg_hist_vmem_bytes,
-                          split_pass_vmem_bytes)
+from .pallas_grow import (HIST_UNROLL_MAX_GROUPS, N_SCALARS, S_CAT, S_CW0,
+                          S_DB, S_DL, S_LE, S_LS, S_MASK, S_MF, S_MT, S_NB,
+                          S_NCH, S_NL, S_S0, S_SH, S_SMALL_L, S_THR, S_WG,
+                          VMEM_CAP, hist_loops_groups, hist_plan,
+                          make_root_hist, make_split_pass, plane_health,
+                          seg_hist_vmem_bytes, split_pass_vmem_bytes)
 from .pallas_scan import (ScanLayout, margin_bucket_index, scan_pair,
                           topk_vote_indices)
 from .quantize import plane_psum, quant_tag, vote_allgather
-from .split import (K_MIN_SCORE, SplitParams, find_best_split_numerical,
+from .split import (K_MIN_SCORE, SplitParams, find_best_split_categorical,
+                    find_best_split_numerical,
                     find_best_split_numerical_batch, fix_histogram)
 
 I32 = jnp.int32
@@ -129,20 +133,48 @@ def _group_widths(dataset) -> np.ndarray:
     return np.asarray(dataset.group_widths(), np.int64)
 
 
+# bins a categorical group may have: a 16-bit slot, two histogram rows
+MAX_CAT_BINS = 512
+
+
+def _cat_groups(dataset) -> np.ndarray:
+    """[G] bool: the group holds one categorical feature and nothing else."""
+    G = len(dataset.group_offset)
+    is_cat = np.asarray(dataset.is_categorical, bool)
+    group_of = np.asarray(dataset.group_of, np.int64)
+    out = np.zeros(G, bool)
+    members = np.bincount(group_of, minlength=G)
+    cats = np.bincount(group_of, weights=is_cat.astype(np.int64),
+                       minlength=G)
+    out[(members == 1) & (cats == 1)] = True
+    return out
+
+
 def persist_pack_ok(dataset):
     """(ok, reason) — can the payload pack plan express this dataset?
 
     The plan covers any dense-binned layout with <= 256 bins per group
-    (byte slots, 4-bit slots for <= 16-bin groups); device_packed v1
-    storage is fine because the payload packs independently from
-    dataset.binned. Multi-value (ELL) layouts and > 256-bin groups are
-    the remaining v1-only geometries."""
+    (byte slots, 4-bit slots for <= 16-bin groups) and categorical groups
+    of up to MAX_CAT_BINS bins (16-bit slots); device_packed v1 storage is
+    fine because the payload packs independently from dataset.binned.
+    Multi-value (ELL) layouts, numerical groups of more than 256 bins
+    (their threshold scan would span two histogram rows) and categorical
+    groups past MAX_CAT_BINS are the remaining v1-only geometries."""
     if getattr(dataset, "is_multival", False) or dataset.binned is None:
         return False, "multi-value (ELL) datasets have no dense payload"
     widths = _group_widths(dataset)
-    if len(widths) and int(widths.max()) > 256:
-        return False, ("group width %d > 256 bins exceeds the payload "
-                       "byte-slot plan" % int(widths.max()))
+    if not len(widths) or int(widths.max()) <= 256:
+        return True, ""
+    cat = _cat_groups(dataset)
+    num_wide = widths[~cat]
+    if len(num_wide) and int(num_wide.max()) > 256:
+        return False, ("numerical group width %d > 256 bins: its threshold "
+                       "scan would span two histogram rows"
+                       % int(num_wide.max()))
+    if int(widths.max()) > MAX_CAT_BINS:
+        return False, ("categorical group width %d > %d bins exceeds the "
+                       "payload's 16-bit slot plan"
+                       % (int(widths.max()), MAX_CAT_BINS))
     return True, ""
 
 
@@ -152,19 +184,26 @@ def _payload_plan(widths):
     plan[g] = (word_row, bit_shift, value_mask): groups whose bin count
     fits 4 bits share a byte slot in nibble pairs (the Dense4bitsBin
     analog, src/io/dense_nbits_bin.hpp, applied to the PERSIST payload),
-    everything else gets a full byte — 4 byte slots per u32 payload word.
-    With no narrow groups this reproduces the historical byte-per-group
-    layout exactly. The split/seg/root kernels and the XLA emulation
-    decode through (word, shift, mask), so the plan is the single source
-    of truth for payload bin storage."""
+    groups of more than 256 bins (categorical ones: persist_pack_ok) take
+    a 16-bit slot, half a word, first, and everything else gets a full
+    byte — 4 byte slots per u32 payload word. With no narrow and no wide
+    groups this reproduces the historical byte-per-group layout exactly.
+    The split/seg/root kernels and the XLA emulation decode through
+    (word, shift, mask), so the plan is the single source of truth for
+    payload bin storage."""
     from ..data.dataset import nibble_slot_partition
     G = len(widths)
     wide, pairs, leftover = nibble_slot_partition(widths)
     plan = [None] * G
     slot = 0                       # byte-slot counter (4 per u32 word)
     for g in wide:
-        plan[g] = (slot // 4, (slot % 4) * 8, 255)
-        slot += 1
+        if widths[g] > 256:        # two byte slots, at shift 0 or 16
+            plan[g] = (slot // 4, (slot % 4) * 8, 0xFFFF)
+            slot += 2
+    for g in wide:
+        if widths[g] <= 256:
+            plan[g] = (slot // 4, (slot % 4) * 8, 255)
+            slot += 1
     for a, b in pairs:
         w, sh = slot // 4, (slot % 4) * 8
         plan[a] = (w, sh, 15)
@@ -326,7 +365,7 @@ def build_assets(dataset, labels: np.ndarray, C: int = 0,
                  score64: bool = False) -> PersistAssets:
     """Host-side payload construction (once per dataset).
 
-    dataset: BinnedDataset with groups == features, widths <= 256.
+    dataset: BinnedDataset whose geometry persist_pack_ok admits.
     Sample weights (metadata.weight) ride as one extra payload row — see
     _payload_geometry.
     With num_shards > 1 the rows are cut into equal contiguous blocks
@@ -358,15 +397,23 @@ def build_assets(dataset, labels: np.ndarray, C: int = 0,
     weight = dataset.metadata.weight if use_weight_row else None
     weight = None if weight is None else np.asarray(weight)
     has_w = weight is not None
+    # the kernels histogram one 256-bin row per group, two per wide one
+    hplan = hist_plan(plan)
     WPA, C, CR, NP = _payload_geometry(
-        n, nbw, G, C, CR, num_scores, has_w, score64,
-        loop_groups=hist_loops_groups(G, plan))
-    # run record: the newest payload's geometry (set, not summed)
+        n, nbw, len(hplan), C, CR, num_scores, has_w, score64,
+        loop_groups=hist_loops_groups(len(hplan), hplan))
+    # run record: the newest payload's geometry (set, not summed):
+    # categorical groups and the wide (16-bit slot) ones among them
     telemetry.clear_counts_prefix(
-        ("ops::payload_words", "ops::chunk_lanes", "ops::root_chunk_lanes"))
+        ("ops::payload_words", "ops::chunk_lanes", "ops::root_chunk_lanes",
+         "ops::cat_groups", "ops::wide_groups"))
     telemetry.count("ops::payload_words", WPA, category="ops")
     telemetry.count("ops::chunk_lanes", C, category="ops")
     telemetry.count("ops::root_chunk_lanes", CR, category="ops")
+    telemetry.count("ops::cat_groups", float(_cat_groups(dataset).sum()),
+                    category="ops")
+    telemetry.count("ops::wide_groups", float(len(hplan) - G),
+                    category="ops")
     K = num_scores
     weight_row = payload_weight_row(nbw, K, score64)
     # every shard packs into its own lanes of the one matrix, the shards
@@ -430,8 +477,30 @@ def build_assets(dataset, labels: np.ndarray, C: int = 0,
 # pure-XLA kernel emulation (CPU fallback + sharding tests)
 # ---------------------------------------------------------------------------
 
+def _xla_planes(pay, plan, G: int, grad, hess):
+    """The emulation's [G * 256] (grad, hess) planes, f64, over the
+    histogram rows of ``plan`` (pallas_grow.hist_plan): a row's bin less
+    its base, where the row holds it; the rest of a wide group's bins
+    belong to its other row and drop."""
+    gh = jnp.zeros(G * 256, jnp.float64)
+    hh = jnp.zeros(G * 256, jnp.float64)
+    for g, p in enumerate(plan):
+        w, sh, mk = p[:3]
+        if mk > 255:
+            b = ((pay[w] >> U32(sh)) & U32(mk)).astype(I32) \
+                - (p[3] if len(p) > 3 else 0)
+            bg = jnp.where((b >= 0) & (b < 256), b + g * 256, G * 256)
+            gh = gh.at[bg].add(grad, mode="drop")
+            hh = hh.at[bg].add(hess, mode="drop")
+        else:
+            bg = ((pay[w] >> U32(sh)) & U32(mk)).astype(I32) + g * 256
+            gh = gh.at[bg].add(grad)
+            hh = hh.at[bg].add(hess)
+    return gh, hh
+
+
 def make_xla_split_pass(WPA: int, NP: int, G: int, plan, nbw: int,
-                        out_dtype=F32):
+                        out_dtype=F32, cat_words: int = 0):
     """jnp reference implementation of the split_pass kernel contract:
     same (pay', (gh, hh), n_left) outputs, with the partitioned segment in
     stable original order (left rows first). Row order within a segment is
@@ -441,7 +510,9 @@ def make_xla_split_pass(WPA: int, NP: int, G: int, plan, nbw: int,
     f64 values through so the grower's gain ordering matches the v1 f64
     scan, out_dtype=f32 rounds like the Mosaic kernels (and keeps
     per-shard partial sums + psum matching a whole-data sum to f32
-    round-off — the sharding equivalence tests rely on this)."""
+    round-off — the sharding equivalence tests rely on this).
+    cat_words: the categorical arm of make_split_pass, on the same
+    scalars."""
     grad_row = nbw + 2
 
     def split_pass(pay, scal):
@@ -459,6 +530,11 @@ def make_xla_split_pass(WPA: int, NP: int, G: int, plan, nbw: int,
         is_zero = (scal[S_MT] == 1) & (b == scal[S_DB])
         gd = is_na | is_zero
         go_left = jnp.where(gd, scal[S_DL] > 0, cmp_left)
+        if cat_words:
+            words = scal[S_CW0:S_CW0 + cat_words]
+            cw = jnp.take(words, jnp.clip(b >> 5, 0, cat_words - 1))
+            in_set = (((cw >> (b & 31)) & 1) == 1) & ((b >> 5) < cat_words)
+            go_left = jnp.where(scal[S_CAT] > 0, in_set, go_left)
         gl = in_seg & go_left
         gr = in_seg & ~go_left
         nL = jnp.sum(gl, dtype=I32)
@@ -472,12 +548,7 @@ def make_xla_split_pass(WPA: int, NP: int, G: int, plan, nbw: int,
         grad = jnp.where(hm, _f32r(pay[grad_row]), 0.0).astype(jnp.float64)
         hess = jnp.where(hm, _f32r(pay[grad_row + 1]), 0.0) \
             .astype(jnp.float64)
-        gh = jnp.zeros(G * 256, jnp.float64)
-        hh = jnp.zeros(G * 256, jnp.float64)
-        for g, (w, sh, mk) in enumerate(plan):
-            bg = ((pay[w] >> U32(sh)) & U32(mk)).astype(I32) + g * 256
-            gh = gh.at[bg].add(grad)
-            hh = hh.at[bg].add(hess)
+        gh, hh = _xla_planes(pay, plan, G, grad, hess)
         return pay2, (gh.astype(out_dtype), hh.astype(out_dtype)), nL
 
     return split_pass
@@ -495,12 +566,7 @@ def make_xla_root_hist(WPA: int, NP: int, G: int, plan, nbw: int, n: int,
             .astype(jnp.float64)
         hess = jnp.where(live, _f32r(pay[grad_row + 1]), 0.0) \
             .astype(jnp.float64)
-        gh = jnp.zeros(G * 256, jnp.float64)
-        hh = jnp.zeros(G * 256, jnp.float64)
-        for g, (w, sh, mk) in enumerate(plan):
-            bg = ((pay[w] >> U32(sh)) & U32(mk)).astype(I32) + g * 256
-            gh = gh.at[bg].add(grad)
-            hh = hh.at[bg].add(hess)
+        gh, hh = _xla_planes(pay, plan, G, grad, hess)
         return gh.astype(out_dtype), hh.astype(out_dtype)
 
     return root_hist
@@ -526,6 +592,10 @@ class _PState(NamedTuple):
     # columns of lstate and tree, exact in i32; None otherwise
     lint: Optional[jnp.ndarray] = None    # [L, 4] i32, LI_* columns
     tint: Optional[jnp.ndarray] = None    # [L] i32 TR_ICNT by split
+    # categorical layouts only: the left-set bitsets of each leaf's best
+    # candidate and of each split (i32 words; None otherwise)
+    bcat: Optional[jnp.ndarray] = None    # [L, NW] by leaf
+    tcat: Optional[jnp.ndarray] = None    # [L, NW] by split
 
 
 # ---------------------------------------------------------------------------
@@ -688,7 +758,8 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
                         large_counts=None, fix=None,
                         level_mode: str = "auto",
                         health: bool = True,
-                        quant=None, comm_overlap: bool = False):
+                        quant=None, comm_overlap: bool = False,
+                        cat=None):
     """Build grow/score/gradient closures for one dataset + grow config.
 
     gc: GrowConfig (num_leaves, max_depth, num_features, scan_width used).
@@ -773,6 +844,20 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
     (the fix for the historical persist-vs-v1 tie-flip on noise-gain
     splits). The Mosaic path keeps the f32 fast-path trade
     (gpu_use_dp=false) unchanged.
+
+    cat: the learner's CatLayout (ops/split.py; the v1 grower's, over the
+    flat [total_bins] layout; None or empty: no categorical feature).
+    With categorical features each candidate leaf also takes its best
+    categorical split
+    (find_best_split_categorical under the ``cat_scan`` scope: the v1
+    grower's own arithmetic, f64 in the widened mode, f32 on the kernel
+    planes) and the better of the two wins as in the v1 merge; the chosen
+    left set rides the best-candidate and split records as a bitset of
+    ceil(cat_width / 32) i32 words, reaches split_pass as scalars
+    (S_CAT, S_CW0) and becomes TreeArrays.is_cat / cat_mask. Single
+    device, unbundled layouts, per-split growth: the learner's gate
+    (can_persist_scan) keeps categorical datasets off the sharded
+    learners and the bundled layouts, and the level phase is off.
     """
     WPA, NP, G, plan, nbw, n, C, CR = assets.geometry[:8]
     K = assets.geometry[8] if len(assets.geometry) > 8 else 1
@@ -788,7 +873,20 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
     F = gc.num_features
     L = gc.num_leaves
     W = 256
-    TBp = G * W
+    # the kernels' histogram rows: one per group, two for a 16-bit group
+    # (pallas_grow.hist_plan); group g's rows start at hrow_np[g]
+    hplan = hist_plan(plan)
+    GH = len(hplan)
+    hrow_np = np.cumsum([0] + [2 if p[2] > 255 else 1 for p in plan])[:-1]
+    TBp = GH * W
+    has_cat = cat is not None and int(cat.cat_feature.shape[0]) > 0
+    if has_cat and axis_name is not None:
+        raise ValueError("categorical features on the persist path run on "
+                         "one device (can_persist_scan refuses the sharded "
+                         "learners)")
+    # the left set's bitset words, scalars of split_pass from S_CW0 on
+    CW = int(cat.gather_idx.shape[1]) if has_cat else 0
+    NW = -(-CW // 32)
     EV = jnp.float64 if wide else F32   # histogram/eval dtype
     # the leaf-state/tree-record matrices carry integer counts and
     # payload positions in float lanes; f32 is integer-exact only to
@@ -822,16 +920,17 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
     # level-parallel phase sizing: up to S_MAXL splitting leaves per
     # level program (the widest frontier a depth-bounded tree can
     # present), 2*S_MAXL children per batched split-find
-    use_level = level_mode != "off" and can_level_grow(gc)
+    use_level = (level_mode != "off" and can_level_grow(gc)
+                 and not has_cat)
     md = int(gc.max_depth)
     S_MAXL = min(1 << max(md - 1, 0), L - 1) if use_level else 1
     T_MAXL = NP // max(C, 1) + 3 * S_MAXL + 4
     level_pass = None
     level_seg = None
     if kernel_impl == "xla":
-        split_pass = make_xla_split_pass(WPA, NP, G, plan, nbw,
-                                         out_dtype=EV)
-        root_hist = make_xla_root_hist(WPA, NP, G, plan, nbw, n,
+        split_pass = make_xla_split_pass(WPA, NP, GH, hplan, nbw,
+                                         out_dtype=EV, cat_words=NW)
+        root_hist = make_xla_root_hist(WPA, NP, GH, hplan, nbw, n,
                                        out_dtype=EV)
         seg_hist = None     # the emulated pass builds the histogram
     else:
@@ -850,12 +949,12 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
         # launches a launch of 16 trees included, and the launches after
         # it 1-3% shorter again. Both feed the same parent-minus-smaller
         # subtraction.
-        split_pass = make_split_pass(WPA, NP, G, plan, nbw, C=C,
+        split_pass = make_split_pass(WPA, NP, GH, hplan, nbw, C=C,
                                      interpret=interpret, wp_live=wp_live,
-                                     _skip_hist=True)
-        seg_hist = make_seg_hist(WPA, NP, G, plan, nbw, C=C,
+                                     _skip_hist=True, cat_words=NW)
+        seg_hist = make_seg_hist(WPA, NP, GH, hplan, nbw, C=C,
                                  interpret=interpret)
-        root_hist = make_root_hist(WPA, NP, G, plan, nbw, n, C=CR,
+        root_hist = make_root_hist(WPA, NP, GH, hplan, nbw, n, C=CR,
                                    interpret=interpret)
         if use_level:
             # built ONCE here, invoked inside the traced level loop —
@@ -910,8 +1009,14 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
     # group_of = identity when nothing is bundled, i.e. flat f*W)
     (group_of_np, ls_np, nb_np, mf_np, needs_fix_np, bundled,
      mt_np, db_np) = assets.efb
-    win_start_np = (group_of_np.astype(np.int64) * W + ls_np).astype(
-        np.int32)
+    win_start_np = (hrow_np[group_of_np] * W + ls_np).astype(np.int32)
+    # feature f's first histogram row where a categorical layout moves it
+    # off row f (a wide group's second row; a group order not the
+    # features'); a cat-free layout keeps the group rows as they are
+    feat_rows = hrow_np[group_of_np] if has_cat else None
+    if feat_rows is not None and GH == G \
+            and np.array_equal(feat_rows, np.arange(F)):
+        feat_rows = None
     pad_meta = meta._replace(
         bin_start=jnp.asarray(win_start_np),
         bin_end=jnp.asarray(win_start_np + nb_np))
@@ -938,6 +1043,60 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
         lane_of_bin = None
         TBe = TBp
     W_scan = max(int(gc.scan_width), 1)
+    if has_cat:
+        cat_ids_np = np.asarray(cat.cat_feature, np.int64)
+        is_cat_np = np.zeros(F, bool)
+        is_cat_np[cat_ids_np] = True
+        is_cat_f = jnp.asarray(is_cat_np)
+        if not wide:
+            # the v1 layout's windows moved onto the kernels' planes
+            cat = cat._replace(gather_idx=jnp.asarray(np.clip(
+                win_start_np[cat_ids_np][:, None] + np.arange(CW), 0,
+                TBp - 1).astype(np.int32)))
+
+    def cat_scan(g2, h2, sgs, shs, cnts, params, fmask):
+        """[B] best categorical candidates (SplitCandidate) of the leaves
+        whose [B, TBe] histogram rows are g2/h2: the v1 grower's
+        find_best_split_categorical, in EV."""
+        with jax.named_scope("cat_scan"):
+            hist = jnp.stack([g2, h2], axis=-1)
+            return jax.vmap(
+                lambda h_, sg_, sh_, nd_: find_best_split_categorical(
+                    h_, sg_, sh_, nd_, cat, meta, params, -jnp.inf,
+                    jnp.inf, fmask, use_mc=False, use_dp=wide,
+                    steps=gc.max_cat_threshold))(
+                hist, sgs.astype(EV), shs.astype(EV), cnts.astype(I32))
+
+    def cat_bits(mask):
+        """[B, CW] bool left set -> [B, NW] i32 bitset words."""
+        B = mask.shape[0]
+        m = jnp.pad(mask, ((0, 0), (0, NW * 32 - CW))) \
+            .reshape(B, NW, 32).astype(U32)
+        w = jnp.sum(m << jnp.arange(32, dtype=U32), axis=-1, dtype=U32)
+        return jax.lax.bitcast_convert_type(w, I32)
+
+    def merge_cat(mat, cc, depths):
+        """The [B, 12] best-candidate matrix with each leaf's categorical
+        candidate merged in (SplitInfo::operator>, as merge_candidates:
+        higher gain, ties to the smaller feature id), and the [B, NW]
+        bitsets of the winners (zeros where a numerical split won)."""
+        neg = jnp.asarray(K_MIN_SCORE, EV)
+        ok = cc.gain > neg
+        if gc.max_depth > 0:
+            ok &= depths.astype(EV) < gc.max_depth
+        row = jnp.stack([
+            jnp.where(ok, cc.gain, neg),
+            jnp.where(ok, cc.feature, -1).astype(EV),
+            jnp.zeros_like(cc.gain), jnp.zeros_like(cc.gain),
+            cc.left_sum_grad, cc.left_sum_hess,
+            cc.right_sum_grad, cc.right_sum_hess,
+            cc.left_count.astype(EV), cc.right_count.astype(EV),
+            cc.left_output, cc.right_output], axis=1).astype(EV)
+        ng, nf = mat[:, BC_GAIN], mat[:, BC_FEAT]
+        cg, cf = row[:, BC_GAIN], row[:, BC_FEAT]
+        win = (cg > ng) | ((cg == ng) & (cf >= 0) & ((nf < 0) | (cf < nf)))
+        return (jnp.where(win[:, None], row, mat),
+                jnp.where(win[:, None], cat_bits(cc.cat_mask), 0))
 
     def to_flat(plane):
         """Kernel-layout [..., G*256] plane -> eval-layout [..., TBe]."""
@@ -1219,6 +1378,12 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
             winp = jnp.pad(winb, ((0, 0), (0, layout.Fp - G)))
             valid_r = valid_r[None] * winp[:, :, None].astype(F32)
             valid_f = valid_f[None] * winp[:, :, None].astype(F32)
+        if feat_rows is not None:
+            # feature f's first histogram row at row f: a wide group's
+            # second row carries no numerical feature, and a layout with
+            # categorical groups need not keep its groups in feature order
+            g2 = jnp.take(g2.reshape(B, GH, W), feat_rows, axis=1)
+            h2 = jnp.take(h2.reshape(B, GH, W), feat_rows, axis=1)
         gb = jnp.pad(g2.reshape(B, G, W), pad_f)
         hb = jnp.pad(h2.reshape(B, G, W), pad_f)
         scal = jnp.stack([
@@ -1248,12 +1413,19 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
         """Eval dispatcher: the widened v1 f64 find in xla mode, the
         fused Mosaic scan kernels otherwise. g2/h2: the [B, TBe]
         histogram rows. ``tag`` seeds the voting winner-window
-        quantization (rank-uniform, per grow stage)."""
+        quantization (rank-uniform, per grow stage). With categorical
+        features: (matrix, [B, NW] left-set bitsets)."""
         if wide:
-            return eval_batch_wide(g2, h2, sgs, shs, cnts, depths,
-                                   params, fmask, tag)
-        return eval_batch(g2, h2, sgs, shs, cnts, depths, params,
-                          layout, tag)
+            mat = eval_batch_wide(g2, h2, sgs, shs, cnts, depths,
+                                  params, fmask, tag)
+        else:
+            mat = eval_batch(g2, h2, sgs, shs, cnts, depths, params,
+                             layout, tag)
+        if not has_cat:
+            return mat
+        pc = params.cast(EV)
+        return merge_cat(mat, cat_scan(g2, h2, sgs, shs, cnts, pc, fmask),
+                         depths)
 
     # quantization-seed stage ids: root 0, level programs 1..md (+1 per
     # level), per-split tail STAGE_SPLIT0 + s — disjoint ranges so every
@@ -1283,7 +1455,8 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
             return jnp.stack([
                 jnp.sum(jnp.where(live, _f32r(pay[grad_row + r]), 0.0)
                         .astype(jnp.float64)) for r in (0, 1)])
-        return jnp.stack([jnp.sum(rhist[0][:W]), jnp.sum(rhist[1][:W])])
+        W0 = W * (2 if plan[0][2] > 255 else 1)   # group 0's rows
+        return jnp.stack([jnp.sum(rhist[0][:W0]), jnp.sum(rhist[1][:W0])])
 
     def grow(pay, params: SplitParams, fmask, bag_cnt=None, it=None):
         """Grow one tree in place; returns (pay', lstate, tree, num_leaves,
@@ -1334,6 +1507,8 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
                       jnp.stack([root_cnt, root_cnt]),
                       jnp.zeros((2,), F32), params, layout, fmask,
                       quant_tag(it_q, STAGE_SPLIT0 - 1))
+        if has_cat:
+            pair0, bits0 = pair0
         best = jnp.full((L, 12), K_MIN_SCORE, EV).at[0].set(pair0[0])
         health0 = jnp.zeros((HEALTH_LEN,), I32)
         if health:
@@ -1360,6 +1535,10 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
                     jnp.zeros((4,), I32).at[LI_CNT].set(root_cnt)
                     .at[LI_NROWS].set(n)),
                 tint=jnp.zeros((L,), I32))
+        if has_cat:
+            state = state._replace(
+                bcat=jnp.zeros((L, NW), I32).at[0].set(bits0[0]),
+                tcat=jnp.zeros((L, NW), I32))
 
         # ---- level-parallel phase: one fused program per tree level ----
         if use_level:
@@ -1643,6 +1822,10 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
                 assets.le[f],                        # S_LE
                 assets.mf[f],                        # S_MF
             ]).astype(I32)
+            if has_cat:
+                # S_CAT, then the left set's words from S_CW0 on
+                scal = jnp.concatenate(
+                    [scal, is_cat_f[f].astype(I32)[None], st.bcat[l]])
             pay, hist_sm, n_left = split_pass(st.pay, scal)
             # n_l == 0 skips the kernel (zero grid steps) and leaves its
             # histogram/count outputs undefined; mask before sums/psum
@@ -1722,6 +1905,15 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
                 jnp.stack([left_cnt, right_cnt]),
                 jnp.stack([depth_child, depth_child]), params, layout,
                 fmask, quant_tag(it_q, STAGE_SPLIT0 + s))
+            cats = {}
+            if has_cat:
+                pair, pbits = pair
+                cats = dict(
+                    bcat=st.bcat.at[l].set(
+                        jnp.where(do, pbits[0], st.bcat[l]))
+                    .at[s].set(jnp.where(do, pbits[1], st.bcat[s])),
+                    tcat=st.tcat.at[s - 1].set(
+                        jnp.where(do, st.bcat[l], st.tcat[s - 1])))
             best = st.best.at[l].set(jnp.where(do, pair[0], st.best[l])) \
                           .at[s].set(jnp.where(do, pair[1], st.best[s]))
 
@@ -1767,7 +1959,7 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
             return st._replace(
                 s=s + do.astype(I32), done=~do, pay=pay,
                 gh=gh, hh=hh, lstate=lstate, best=best, tree=tree,
-                health=hv, **ints)
+                health=hv, **ints, **cats)
 
         final = jax.lax.while_loop(cond, body, state)
         # the iter-launch slot is the DRIVER's (one bump per compiled
@@ -1776,12 +1968,19 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
             [jnp.stack([final.levels, final.s - s_after_level,
                         jnp.zeros((), I32)]),
              final.health])
+        tree = final.tree
+        if big:
+            tree = (tree, final.tint)
+        if has_cat:
+            # the split records' left sets ride beside them, for
+            # to_tree_arrays alone
+            tree = (tree, final.tcat)
         if big:
             # the integer columns ride beside their matrices: the pairs
             # are what apply_scores* and to_tree_arrays take
             return (final.pay, (final.lstate, final.lint),
-                    (final.tree, final.tint), final.s, root_out, stats)
-        return (final.pay, final.lstate, final.tree, final.s, root_out,
+                    tree, final.s, root_out, stats)
+        return (final.pay, final.lstate, tree, final.s, root_out,
                 stats)
 
     def _read_score(pay, cls=0, base_row=None):
@@ -1809,19 +2008,36 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
         The widened mode hands f64 leaf values/gains through (v1 f64
         parity); the Mosaic fast path stays f32 (gpu_use_dp=false)."""
         ft = jnp.float64 if wide else F32
+        if has_cat:
+            tree, tcat = tree
         if big:
             (lstate, lint), (tree, tint) = lstate, tree
+
+        def split_feature():
+            return jnp.where(
+                jnp.arange(L - 1, dtype=I32) < num_leaves - 1,
+                tree[:L - 1, TR_FEAT].astype(I32), -1)
+
+        def split_is_cat():
+            sf = split_feature()
+            return is_cat_f[jnp.maximum(sf, 0)] & (sf >= 0)
+
+        def split_cat_mask():
+            bits = (tcat[:L - 1, :, None]
+                    >> jnp.arange(32, dtype=I32)) & 1     # [L-1, NW, 32]
+            return ((bits.reshape(L - 1, NW * 32)[:, :CW] == 1)
+                    & split_is_cat()[:, None])
         return TreeArrays(
             num_leaves=num_leaves,
             split_leaf=tree[:L - 1, TR_LEAF].astype(I32),
-            split_feature=jnp.where(
-                jnp.arange(L - 1, dtype=I32) < num_leaves - 1,
-                tree[:L - 1, TR_FEAT].astype(I32), -1),
+            split_feature=split_feature(),
             threshold=tree[:L - 1, TR_THR].astype(I32),
             default_left=tree[:L - 1, TR_DL] > 0.5,
             gain=tree[:L - 1, TR_GAIN].astype(ft),
-            is_cat=jnp.zeros((L - 1,), BOOL),
-            cat_mask=jnp.zeros((L - 1, gc.cat_width), BOOL),
+            is_cat=(split_is_cat() if has_cat
+                    else jnp.zeros((L - 1,), BOOL)),
+            cat_mask=(split_cat_mask() if has_cat
+                      else jnp.zeros((L - 1, gc.cat_width), BOOL)),
             internal_value=tree[:L - 1, TR_IVAL].astype(ft),
             internal_count=(tint[:L - 1] if big else
                             tree[:L - 1, TR_ICNT].astype(I32)),
@@ -2200,6 +2416,8 @@ def make_persist_grower(assets: PersistAssets, meta, gc,
     # row counts at or past 2^24 ride i32 beside the f32 state:
     # large_count_trees
     gr.large_counts = big
+    # categorical splits in this grower's eval: cat_persist_trees
+    gr.has_cat = has_cat
     gr.use_level = use_level
     gr.S_MAXL = S_MAXL
     gr.health = health

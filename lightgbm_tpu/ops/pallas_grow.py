@@ -30,7 +30,8 @@ Mosaic kernels over a single transposed payload matrix:
   * split_pass (one call per split, DYNAMIC grid over chunks): streams the
     splitting leaf's contiguous payload segment once, and per chunk
       - decides go_left per row (DenseBin::Split semantics at the bin
-        level, src/io/dense_bin.hpp:112-207; numerical features),
+        level, src/io/dense_bin.hpp:112-207; numerical thresholds or,
+        in a layout with categorical groups, a categorical left set),
       - partitions the chunk tile by tile: one prefix sum gives both sides'
         control words, 7 hole-shift lane stages compact each 128-lane tile
         in registers, and a dynamic roll appends the kept lanes to the
@@ -72,9 +73,11 @@ Both kernels keep the histogram in the PADDED [G, 256] per-group layout
 split scan is a reshape — no gather, no scatter (v1's _hist_acc_finish
 scatter and dense-scan gather cost ~80us per split).
 
-Gated to the fast path: numerical features only, <= 256 bins per feature,
-f32 accumulation; EFB-bundled groups decode in the split kernel via the
-[LS, LE) group-local range scalars. Everything else falls back to
+Gated to the fast path: <= 256 bins per numerical group, <= 512 per
+categorical group (a 16-bit slot, two histogram rows: hist_plan), f32
+accumulation; EFB-bundled groups decode in the split kernel via the
+[LS, LE) group-local range scalars, and a categorical split decides by
+its left set's bitset (S_CAT, S_CW0). Everything else falls back to
 ops/grow.py. Equivalence is tested on CPU against the XLA kernel
 emulation and the v1 growers (tests/test_persist_sharded.py).
 """
@@ -106,6 +109,27 @@ S_LS = 12         # feature's group-local byte range start (EFB bundles)
 S_LE = 13         # range end; bytes outside [LS, LE) read as most_freq
 S_MF = 14         # most_freq (feature-local) bin
 N_SCALARS = 15
+# categorical layouts only (make_split_pass(cat_words > 0)): a flag and
+# the split's left set as a bitset over local bins, cat_words i32 words
+S_CAT = 15        # 1: the split is categorical (go left by set membership)
+S_CW0 = 16        # first bitset word (bin b: bit b % 32 of word b // 32)
+
+
+def hist_plan(plan):
+    """The kernels' histogram rows from a payload plan: one 256-bin row
+    per group, two for a group in a 16-bit slot (more than 256 bins,
+    grow_persist._payload_plan). A row is (word, shift, mask) or, for the
+    upper half of a wide group, (word, shift, mask, 256): the bin less
+    256. A bin outside [0, 256) of its row falls out of the radix
+    contraction by itself (its high nibble matches no row of the [16, E]
+    one-hot), so the lower row of a wide group needs no mask. A plan
+    with no wide group is its own histogram plan."""
+    rows = []
+    for p in plan:
+        rows.append(tuple(p))
+        if p[2] > 255:
+            rows.append(tuple(p) + (256,))
+    return tuple(rows)
 
 
 def _ceil8(x: int) -> int:
@@ -348,8 +372,10 @@ def _unpack_group_bins(pay_block, plan):
     same kernels serve byte and nibble-packed payloads.
     """
     rows = []
-    for (w, sh, mk) in plan:
-        rows.append(((pay_block[w, :] >> U32(sh)) & U32(mk)).astype(I32))
+    for p in plan:
+        w, sh, mk = p[:3]
+        b = ((pay_block[w, :] >> U32(sh)) & U32(mk)).astype(I32)
+        rows.append(b - p[3] if len(p) > 3 else b)
     return jnp.stack(rows, axis=0)
 
 
@@ -579,7 +605,7 @@ def _align128(ptr):
 # ---------------------------------------------------------------------------
 
 def _make_segment_step(C: int, G: int, plan, nbw: int, WP_LIVE: int,
-                       skip_hist: bool, skip_pack: bool):
+                       skip_hist: bool, skip_pack: bool, cat_words: int = 0):
     """One grid step of the in-place partition of one payload segment:
     the body split_pass and level_pass share.
 
@@ -756,6 +782,18 @@ def _make_segment_step(C: int, G: int, plan, nbw: int, WP_LIVE: int,
             dlv = (jnp.zeros_like(b) + sc(S_DL)) > 0
             gd = is_na | is_zero
             go_left = (gd & dlv) | ((~gd) & cmp_left)
+            if cat_words:
+                # categorical split (dense_bin.hpp SplitCategorical): left
+                # when the bin's bit is set. The word is a select over
+                # the cat_words scalars, not a gather; a bin past the
+                # bitset reads 0 and goes right, as the other bin does
+                wi = b >> 5
+                cw = jnp.zeros_like(b)
+                for k in range(cat_words):
+                    cw = jnp.where(wi == k, sc(S_CW0 + k), cw)
+                in_set = ((cw >> (b & 31)) & 1) == 1
+                catv = (jnp.zeros_like(b) + sc(S_CAT)) > 0
+                go_left = (catv & in_set) | ((~catv) & go_left)
 
             gl = valid & go_left
             nL = jnp.sum(gl.astype(F32), dtype=F32).astype(I32)
@@ -831,11 +869,17 @@ def _segment_scratch(WPA: int, E: int, R: int):
 def make_split_pass(WPA: int, NP: int, G: int, plan, nbw: int,
                     C: int = 8192, interpret: bool = False,
                     wp_live: int = 0,
-                    _skip_hist: bool = False, _skip_pack: bool = False):
+                    _skip_hist: bool = False, _skip_pack: bool = False,
+                    cat_words: int = 0):
     """Build the fused per-split kernel for one payload geometry.
 
     plan: tuple of (word_row, shift, mask) per group; rows nbw..nbw+3 are
     label/rowid/grad/hess (nbw = WP - 4).
+
+    cat_words > 0 (a layout with categorical groups): the scalars carry
+    S_CAT and cat_words bitset words from S_CW0 on, and the decision
+    takes the categorical arm where S_CAT is set. 0 compiles the
+    numerical decision alone.
 
     wp_live: how many leading payload rows carry per-row state that must
     PERMUTE with the partition (bins + label/rid/grad/hess + all score and
@@ -850,7 +894,7 @@ def make_split_pass(WPA: int, NP: int, G: int, plan, nbw: int,
     WP_LIVE = wp_live or (nbw + 5)
     assert WP_LIVE <= WPA
     step = _make_segment_step(C, G, plan, nbw, WP_LIVE, _skip_hist,
-                              _skip_pack)
+                              _skip_pack, cat_words)
 
     def kernel(ns, pay_in, pay_out, hist_ref, cnt_ref, *scratch):
         st, sem_r = scratch[-3:-1]

@@ -478,10 +478,18 @@ def _cat_onehot_scan(grad_b, hess_b, cnt_b, used_mask, sum_grad, sum_hess_adj,
 
 
 def _cat_sorted_scan(grad_b, hess_b, cnt_b, used_mask, sum_grad, sum_hess_adj,
-                     num_data, p: SplitParams, cmin, cmax, use_mc: bool):
+                     num_data, p: SplitParams, cmin, cmax, use_mc: bool,
+                     steps: int):
     """Many-vs-many categorical: bins sorted by grad/hess ratio, prefix scans
     in both directions with the reference's stateful min_data_per_group
-    bookkeeping (feature_histogram.hpp:339-432) as a lax.scan."""
+    bookkeeping (feature_histogram.hpp:339-432) as a lax.scan.
+
+    The scan covers the first min(steps, W) sorted positions of each
+    direction, ``steps`` being the config's max_cat_threshold: a position
+    past it is never a candidate (``in_range``; the reference's loop stops
+    at max_num_cat), so the bound changes no result. Under the width the
+    steps are unrolled: a rolled loop of a few small ops an iteration cost
+    the fused persist launch 1.4% on expo_cat.train_steady (TPU v5e)."""
     ft = grad_b.dtype
     W = grad_b.shape[0]
     l2 = p.lambda_l2 + p.cat_l2
@@ -493,6 +501,7 @@ def _cat_sorted_scan(grad_b, hess_b, cnt_b, used_mask, sum_grad, sum_hess_adj,
     order = jnp.argsort(ratio, stable=True)    # ascending
     used_bin_cnt = jnp.sum(part.astype(I32))
     max_num_cat = jnp.minimum(p.max_cat_threshold, (used_bin_cnt + 1) // 2)
+    n_steps = min(int(steps), W)
 
     g_s = grad_b[order]
     h_s = hess_b[order]
@@ -547,8 +556,9 @@ def _cat_sorted_scan(grad_b, hess_b, cnt_b, used_mask, sum_grad, sum_hess_adj,
         init = (jnp.asarray(0.0, ft), jnp.asarray(K_EPSILON, ft),
                 jnp.asarray(0, I32), jnp.asarray(0, I32),
                 jnp.asarray(False), jnp.asarray(0, I32))
+        xs = tuple(x[:n_steps] for x in (gd, hd.astype(ft), cd, vd))
         _, (gains, lgs, lhs, lcs) = jax.lax.scan(
-            step, init, (gd, hd.astype(ft), cd, vd))
+            step, init, xs, unroll=n_steps < W)
         i_best = jnp.argmax(gains)
         return gains[i_best], i_best, lgs[i_best], lhs[i_best], lcs[i_best]
 
@@ -568,19 +578,22 @@ def _cat_sorted_scan(grad_b, hess_b, cnt_b, used_mask, sum_grad, sum_hess_adj,
     return best_gain, cat_mask, lg, lh, lc
 
 
-@functools.partial(jax.jit, static_argnames=("use_mc", "use_dp"))
+@functools.partial(jax.jit, static_argnames=("use_mc", "use_dp", "steps"))
 def find_best_split_categorical(hist, sum_grad, sum_hess, num_data,
                                 cat: CatLayout, meta: FeatureMeta,
                                 p: SplitParams, cmin, cmax, feature_mask,
                                 use_mc: bool = False,
                                 use_dp: bool = True,
-                                gain_penalty=None) -> SplitCandidate:
+                                gain_penalty=None, *,
+                                steps: int) -> SplitCandidate:
     """Best categorical split over all categorical features of one leaf.
 
     Mirrors FindBestThresholdCategoricalInner (feature_histogram.hpp:263-474):
     one-hot when num_bin <= max_cat_to_onehot, else the sorted two-direction
     scan; the l2 used for outputs includes cat_l2 only in sorted mode.
     Returns a scalar SplitCandidate (feature -1 when nothing splits).
+    steps: the sorted scan's bound, GrowConfig.max_cat_threshold (static;
+    p.max_cat_threshold is its traced copy).
     """
     ft = acc_dtype(use_dp)
     C, W = cat.gather_idx.shape
@@ -606,7 +619,8 @@ def find_best_split_categorical(hist, sum_grad, sum_hess, num_data,
         oh = _cat_onehot_scan(grad_b, hess_b, cnt_b, used_mask, sum_grad,
                               sum_hess_adj, num_data, p, cmin, cmax, use_mc)
         so = _cat_sorted_scan(grad_b, hess_b, cnt_b, used_mask, sum_grad,
-                              sum_hess_adj, num_data, p, cmin, cmax, use_mc)
+                              sum_hess_adj, num_data, p, cmin, cmax, use_mc,
+                              steps)
         gain, mask, lg, lh, lc = jax.tree.map(
             lambda a, b: jnp.where(onehot, a, b), oh, so)
         l2_out = jnp.where(onehot, p.lambda_l2, p.lambda_l2 + p.cat_l2)

@@ -69,6 +69,7 @@ def _config_grow_kwargs(config: Config, num_features: int) -> dict:
                   if float(config.feature_fraction_bynode) < 1.0 else 0),
         use_cegb=_cegb_enabled(config),
         use_cegb_lazy=_cegb_lazy_enabled(config),
+        max_cat_threshold=int(config.max_cat_threshold),
     )
 
 
@@ -501,13 +502,15 @@ class SerialTreeLearner:
     def can_persist_scan(self, objective) -> bool:
         """True when the whole K-iteration scan can run on the persistent
         transposed payload (fused split kernel, no per-row gathers).
-        Requirements beyond the Pallas-scan fast path: numerical features
-        only, a payload pack plan (<= 256 bins per group — narrow groups
-        nibble-pack, device_packed v1 storage is fine), rows under 2^31
-        less 2^16 (_persist_rows_ok); sample weights ride as a payload row and EFB bundles
-        decode in the split kernel. Single device or the data/voting-
-        parallel learners (sharded persist). tpu_persist_scan=force
-        engages the XLA kernel emulation off-TPU (tests)."""
+        Requirements beyond the Pallas-scan fast path: a payload pack plan
+        (<= 256 bins per numerical group, <= 512 per categorical one —
+        narrow groups nibble-pack, device_packed v1 storage is fine), rows
+        under 2^31 less 2^16 (_persist_rows_ok), categorical features
+        only where _persist_cat_ok admits them; sample weights ride as a
+        payload row and EFB bundles decode in the split kernel. Single
+        device or the data/voting-parallel learners (sharded persist).
+        tpu_persist_scan=force engages the XLA kernel emulation off-TPU
+        (tests)."""
         from ..ops.grow_persist import persist_pack_ok
         ds = self.dataset
         gc = self.grow_config
@@ -545,7 +548,7 @@ class SerialTreeLearner:
                 and gc.n_forced == 0
                 and not gc.use_cegb_lazy
                 and not gc.multival
-                and self.cat_layout.cat_feature.shape[0] == 0
+                and self._persist_cat_ok(bundled)
                 and ds.num_features > 0
                 # EFB bundles ride the persist path (group-byte decode in
                 # split_pass + bundle-native block scan with in-kernel
@@ -556,6 +559,16 @@ class SerialTreeLearner:
                 and self._persist_axis_ok()
                 and objective is not None
                 and self._persist_obj_ok(objective))
+
+    def _persist_cat_ok(self, bundled: bool) -> bool:
+        """Categorical features ride the persist path on one device over
+        an unbundled layout (one group a feature): the categorical eval
+        reads a feature's window of the group planes, and the sharded
+        learners' eval (the voting window reduce, the psum'd planes) has
+        no categorical arm. The v1 grower takes the rest."""
+        if self.cat_layout.cat_feature.shape[0] == 0:
+            return True
+        return self._axis_name is None and not bundled
 
     @staticmethod
     def _persist_kernel_mode():
@@ -635,14 +648,12 @@ class SerialTreeLearner:
         with telemetry.scope("tree_learner::PersistBuild(trace)",
                              category="setup", always=True):
             if gr is None:
-                gr = make_persist_grower(assets, self.meta,
-                                         self.grow_config,
-                                         interpret=interpret,
-                                         kernel_impl=kernel_impl,
-                                         stat_from_scan=stat_from_scan,
-                                         fix=self.fix,
-                                         level_mode=level_mode,
-                                         health=health)
+                gr = make_persist_grower(
+                    assets, self.meta, self.grow_config,
+                    interpret=interpret, kernel_impl=kernel_impl,
+                    stat_from_scan=stat_from_scan, fix=self.fix,
+                    level_mode=level_mode, health=health,
+                    cat=self.cat_layout)
                 cache[gkey] = gr
             if driver is None:
                 bag_fn = (make_bag_transform(bag_spec, assets.geometry)
@@ -671,7 +682,8 @@ class SerialTreeLearner:
         wide enough for its width to size the kernels' chunks; row counts
         in i32 past 2^24 rows; the sharded grower) and of the fused
         scan's gradient fills (the per-query ranking fill, grad_mode
-        'pos')."""
+        'pos'); a layout with categorical groups, whose eval also finds
+        categorical splits."""
         telemetry.count("tree_learner::persist_scan_trees", float(k),
                         category="tree_learner")
         if grad_mode == "pos":
@@ -688,6 +700,9 @@ class SerialTreeLearner:
                             category="tree_learner")
         if gr.large_counts:
             telemetry.count("tree_learner::large_count_trees", float(k),
+                            category="tree_learner")
+        if getattr(gr, "has_cat", False):
+            telemetry.count("tree_learner::cat_persist_trees", float(k),
                             category="tree_learner")
         shards = getattr(gr, "num_shards", 0)
         if shards:

@@ -21,7 +21,11 @@ split_pass at 64 sublane tiles, seg_hist / root_hist over 2,000 groups,
 scan_pair over [2000, 256] planes and the fused k=16 driver with its
 1.04 GB of per-leaf planes), the fused driver of benchmark/configs/mslr.json
 (11.52M rows in 96,000 queries of 120, its per-query ranking fill beside
-the kernels at 137 groups), and the HIGGS rows under
+the kernels at 137 groups), the payload of benchmark/configs/expo_cat.json
+(36M rows, six categorical columns, two of them in 16-bit slots: 8 live
+rows, ten histogram rows; split_pass with the categorical arm and its ten
+bitset words, seg_hist / root_hist, the fused driver with the eval's
+cat_scan), and the HIGGS rows under
 the other static shapes every persist configuration can take: a weight row
 (13 live rows), three classes (17 live rows in 24), ``max_bin=15`` (every
 group a nibble, 9 live rows in 16) — plus the whole fused k=16 scan driver.
@@ -55,7 +59,8 @@ from lightgbm_tpu.data.synth import (make_expo_like, make_higgs_like,
                                       make_ltr_like)
 from lightgbm_tpu.objectives import create_objective
 from lightgbm_tpu.ops import grow_persist as gp
-from lightgbm_tpu.ops.pallas_grow import (N_SCALARS, hist_loops_groups,
+from lightgbm_tpu.ops.pallas_grow import (N_SCALARS, S_CW0,
+                                          hist_loops_groups, hist_plan,
                                           make_level_pass, make_seg_hist,
                                           make_split_pass)
 from lightgbm_tpu.ops.pallas_histogram import hist_window
@@ -70,6 +75,8 @@ EXPO_CELL_ROWS = 16_500_000  # benchmark/configs/expo.json: 1.5 x Expo
 EPSILON_ROWS = 400_000      # docs/GPU-Performance.rst: Epsilon
 MSLR_QUERIES, MSLR_DOCS = 96_000, 120   # benchmark/configs/mslr.json
 EPSILON_FEATURES = 2_000
+EXPO_CAT_ROWS = 36_000_000  # benchmark/configs/expo_cat.json
+EXPO_CARDS = (12, 31, 7, 22, 313, 313)
 LEVEL_DEPTH = 8             # max_depth; with num_leaves = 2^8 the level
 #                             phase engages
 SAMPLE_ROWS = 20_000        # rows actually binned: the bin structure only
@@ -102,11 +109,13 @@ class _Built:
     GrowConfig on a TPU backend, and persist growers whose payload
     geometry is that of the full row count."""
 
-    def __init__(self, X, y, rows, max_depths, weight=None, **more):
+    def __init__(self, X, y, rows, max_depths, weight=None, categorical=(),
+                 **more):
         params = dict({"objective": "binary", "num_leaves": 255,
                        "max_bin": 255}, **more)
         cfg = lgb.Config(params)
-        self.ds = BinnedDataset.from_matrix(X, cfg, label=y, weight=weight)
+        self.ds = BinnedDataset.from_matrix(X, cfg, label=y, weight=weight,
+                                            categorical_features=categorical)
         self.objective = create_objective(params["objective"], cfg)
         self.objective.init(self.ds.metadata, self.ds.num_data)
         K = self.objective.num_model_per_iteration
@@ -115,9 +124,10 @@ class _Built:
         G, plan, nbw, has_w = (small.geometry[2], small.geometry[3],
                                small.geometry[4], small.geometry[9])
         assert has_w == (weight is not None)
+        rows_plan = hist_plan(plan)
         WPA, C, CR, NP = gp._payload_geometry(
-            rows, nbw, G, 0, 0, K, has_w,
-            loop_groups=hist_loops_groups(G, plan))
+            rows, nbw, len(rows_plan), 0, 0, K, has_w,
+            loop_groups=hist_loops_groups(len(rows_plan), rows_plan))
         self.assets = small._replace(
             pay0=None,
             geometry=(WPA, NP, G, plan, nbw, rows, C, CR, K, has_w, False))
@@ -136,9 +146,10 @@ class _Built:
         for md, learner in self.learners.items():
             gc = learner.grow_config
             assert gc.scan_impl == "pallas" and not gc.use_dp, gc
+            cat = learner.cat_layout if len(categorical) else None
             self.growers[md] = gp.make_persist_grower(
                 self.assets, learner.meta, gc, interpret=False,
-                kernel_impl="pallas", fix=learner.fix)
+                kernel_impl="pallas", fix=learner.fix, cat=cat)
 
 
 @pytest.fixture(scope="module")
@@ -228,6 +239,33 @@ def mslr():
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(jax, "default_backend", lambda: "tpu")
         built.grad = built.objective.device_gradients()
+    return built
+
+
+@pytest.fixture(scope="module")
+def expo_cat():
+    """benchmark/configs/expo_cat.json: the six airline categories at their
+    level counts (Zipf(1) popularity, level 0 the most frequent) as
+    categorical columns, then two numerics, at 36M rows. The two 313-level
+    airports keep more than 256 levels under the 99% rule: 16-bit slots,
+    two histogram rows each; 3 bin words, 8 live rows, a 32 B payload
+    lane."""
+    rng = np.random.default_rng(44)
+    cols = []
+    for card in EXPO_CARDS:
+        p = 1.0 / np.arange(1, card + 1)
+        cols.append(rng.choice(card, SAMPLE_ROWS, p=p / p.sum()))
+    X = np.concatenate([np.stack(cols, 1).astype(np.float64),
+                        rng.normal(size=(SAMPLE_ROWS, 2))], 1)
+    y = (X[:, 6] + (X[:, 3] == 0) > 0.5).astype(np.float64)
+    built = _Built(X, y, EXPO_CAT_ROWS, (-1,), categorical=range(6),
+                   enable_bundle=False)
+    widths = [int(w) for w in built.ds.group_widths()[built.ds.group_of]]
+    # 293 and 293 at 2M rows and more; a 20,000-row sample keeps fewer
+    assert widths[:4] + widths[6:] == [12, 31, 7, 22, 255, 255], widths
+    assert all(256 < w <= 313 for w in widths[4:6]), widths
+    assert (built.pay[0], built.wp_live) == (8, 8)
+    assert built.growers[-1].has_cat and not built.growers[-1].use_level
     return built
 
 
@@ -458,6 +496,33 @@ def _fused_driver_epsilon(higgs, expo, S, epsilon):
     return _fused_driver_of(epsilon, S)
 
 
+def _split_pass_expo_cat(higgs, expo, S, expo_cat):
+    """The categorical arm: S_CAT and the left set's ten bitset words (293
+    bins) after S_MF, a select over the words per lane."""
+    assert (expo_cat.wp_live, expo_cat.pay[0]) == (8, 8)
+    return expo_cat.growers[-1]._split_pass, (
+        S(expo_cat.pay, jnp.uint32), S((S_CW0 + 10,), jnp.int32))
+
+
+def _seg_hist_expo_cat(higgs, expo, S, expo_cat):
+    """Ten histogram rows from eight groups: the two 16-bit groups read as
+    two 256-bin rows each over the same slot."""
+    return expo_cat.growers[-1]._seg_hist, (
+        S(expo_cat.pay, jnp.uint32), S((), jnp.int32), S((), jnp.int32))
+
+
+def _root_hist_expo_cat(higgs, expo, S, expo_cat):
+    return expo_cat.growers[-1]._root_hist, (S(expo_cat.pay, jnp.uint32),)
+
+
+def _fused_driver_expo_cat(higgs, expo, S, expo_cat):
+    """expo_cat.train_steady's launch: the fused driver with cat_scan in
+    the eval and the bitset words through the per-split scalars, counts
+    in i32 past 2^24 rows."""
+    assert expo_cat.growers[-1].large_counts
+    return _fused_driver_of(expo_cat, S)
+
+
 def _fused_driver_mslr(higgs, expo, S, mslr):
     """mslr.train_steady's launch: the fused driver with the per-query
     ranking fill (grad_mode 'pos': one sort of the rows into [96000, 120]
@@ -474,7 +539,8 @@ def _fused_driver_mslr(higgs, expo, S, mslr):
     _level_seg_hist, _seg_hist, _seg_hist_msltr, _seg_hist_expo,
     _root_hist, _root_hist_msltr, _fused_driver, _split_pass_epsilon,
     _seg_hist_epsilon, _root_hist_epsilon, _scan_pair_epsilon,
-    _fused_driver_epsilon, _fused_driver_mslr,
+    _fused_driver_epsilon, _fused_driver_mslr, _split_pass_expo_cat,
+    _seg_hist_expo_cat, _root_hist_expo_cat, _fused_driver_expo_cat,
 ], ids=lambda f: f.__name__.lstrip("_"))
 def test_compiles_for_v5e(case, one_chip, higgs, expo, request):
     def S(shape, dtype):
@@ -488,7 +554,8 @@ def test_compiles_for_v5e(case, one_chip, higgs, expo, request):
     assert fn is not None, "the grower built no such kernel here"
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
-    if case in (_fused_driver, _fused_driver_epsilon, _fused_driver_mslr):
+    if case in (_fused_driver, _fused_driver_epsilon, _fused_driver_mslr,
+                _fused_driver_expo_cat):
         # the split scan is handed the children's rows: a gather of them
         # by an index vector out of the [L, G x 256] planes is lowered
         # through whole-plane slices (2 GB a split at 2,000 columns)

@@ -27,8 +27,8 @@ from lightgbm_tpu.treelearner.serial import create_tree_learner
 ROWS, FEATURES = 200, 6
 
 
-def _dense(rng, features=FEATURES):
-    return rng.normal(size=(ROWS, features))
+def _dense(rng, features=FEATURES, rows=ROWS):
+    return rng.normal(size=(rows, features))
 
 
 def _one_hot(rng):
@@ -68,7 +68,13 @@ GATES = {
     "cegb_lazy": ({"cegb_penalty_feature_lazy": [0.1] * FEATURES}, {},
                   False, False),
     "forced_splits": (_forced_splits, {}, False, True),
-    "categorical_feature": ({}, {"categorical": [1]}, False, True),
+    "categorical_feature": ({}, {"categorical": [1]}, True, True),
+    "categorical_under_mesh": ({"tree_learner": "data"},
+                               {"categorical": [1]}, False, True),
+    "categorical_bundled": ({}, {"bundled": True, "categorical": [1]},
+                            False, True),
+    "numerical_group_past_256_bins": ({"max_bin": 300}, {"rows": 600},
+                                      False, True),
     "ell_layout": ({"tpu_multival": "force"}, {"bundled": True},
                    False, True),
     "bundled_voting": ({"tree_learner": "voting"}, {"bundled": True},
@@ -100,6 +106,17 @@ WHY = {
     "cegb_lazy": lambda l: l.grow_config.use_cegb_lazy,
     "forced_splits": lambda l: l.grow_config.n_forced == 1,
     "categorical_feature": lambda l: l.cat_layout.cat_feature.shape[0] == 1,
+    # the categorical eval runs on one device over one group a feature
+    "categorical_under_mesh": lambda l: (
+        l.cat_layout.cat_feature.shape[0] == 1
+        and l.grow_config.parallel_mode == "data" and l.num_shards > 1),
+    "categorical_bundled": lambda l: (
+        l.cat_layout.cat_feature.shape[0] == 1
+        and len(l.dataset.groups) < l.dataset.num_features),
+    # a numerical threshold scan over two histogram rows: not built
+    "numerical_group_past_256_bins": lambda l: (
+        int(l.dataset.group_widths().max()) > 256
+        and l.cat_layout.cat_feature.shape[0] == 0),
     "ell_layout": lambda l: l.grow_config.multival,
     "bundled_voting": lambda l: l.grow_config.parallel_mode == "voting",
     "wide_past_256k_lanes": lambda l: l.grow_config.num_features == 1030,
@@ -110,24 +127,25 @@ WHY = {
 
 def _build(params, opts):
     rng = np.random.default_rng(7)
+    rows = opts.get("rows", ROWS)
     X = (_one_hot(rng) if opts.get("bundled")
-         else _dense(rng, opts.get("features", FEATURES)))
+         else _dense(rng, opts.get("features", FEATURES), rows))
     if opts.get("categorical"):
-        X[:, opts["categorical"]] = rng.integers(0, 4, (ROWS, 1))
+        X[:, opts["categorical"]] = rng.integers(0, 4, (rows, 1))
     params = dict({"objective": "binary", "verbosity": -1,
                    "min_data_in_bin": 1}, **params)
     if params["objective"] == "multiclass":
-        y = rng.integers(0, 3, ROWS).astype(np.float64)
+        y = rng.integers(0, 3, rows).astype(np.float64)
     elif opts.get("group"):
-        y = rng.integers(0, 4, ROWS).astype(np.float64)
+        y = rng.integers(0, 4, rows).astype(np.float64)
     else:
         y = (X[:, 0] > 0).astype(np.float64)
     cfg = lgb.Config(params)
     ds = BinnedDataset.from_matrix(
         X, cfg, label=y,
         categorical_features=opts.get("categorical", ()),
-        weight=rng.uniform(0.5, 2.0, ROWS) if opts.get("weight") else None,
-        group=[20] * (ROWS // 20) if opts.get("group") else None)
+        weight=rng.uniform(0.5, 2.0, rows) if opts.get("weight") else None,
+        group=[20] * (rows // 20) if opts.get("group") else None)
     learner = create_tree_learner(str(cfg.tree_learner), "cpu", cfg, ds)
     objective = create_objective(params["objective"], cfg)
     objective.init(ds.metadata, ds.num_data)
